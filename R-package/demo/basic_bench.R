@@ -1,5 +1,5 @@
 # Matmul throughput microbench. Reference counterpart: demo/basic_bench.R.
-# NOTE on timing: on remote-attached devices, end the timed region with a
+# NOTE on timing: dispatch is asynchronous — end the timed region with a
 # data-dependent readback (docs/architecture/note_measurement.md).
 require(mxnet.tpu)
 

@@ -277,9 +277,9 @@ def measure(batch=128, steps=20, compute_dtype="bfloat16", img=224):
         params, moms, loss = step(params, moms, Xd, yd)
     barrier()
 
-    # two-window slope, mirroring bench.py: the window-ending readback
-    # costs ~100ms±20 on this transport; differencing two window
-    # lengths cancels it so the slope is the steady-state step time
+    # two-window slope, mirroring bench.py: differencing two window
+    # lengths cancels the window-ending readback's fixed cost, so the
+    # slope is the steady-state step time
     def _window(n):
         nonlocal params, moms
         t0 = time.time()

@@ -73,8 +73,8 @@ def score(network, dev, batch_size, num_batches, batch_group=1,
             return mod.get_outputs()[0]._read()
 
     def barrier(out):
-        # data-dependent 4-byte fetch: on remote-attached TPUs
-        # block_until_ready/wait_to_read can return at enqueue (PERF.md)
+        # data-dependent 4-byte fetch: cannot return before the forward
+        # that produced ``out`` ran
         return float(tiny(out))
 
     # warm up (compile; incl. the barrier program)
@@ -93,10 +93,9 @@ def score(network, dev, batch_size, num_batches, batch_group=1,
         barrier(out)
         return time.time() - tic
 
-    # two-window slope (PERF.md measurement correction): the window-
-    # ending readback costs ~100-137ms on this transport — a single
-    # window understates short scoring runs by double digits. One
-    # shared implementation: bench_timing.two_window_slope.
+    # two-window slope: a single window charges the window-ending
+    # readback's fixed cost to a short scoring run. One shared
+    # implementation: bench_timing.two_window_slope.
     from bench_timing import two_window_slope
     sl = two_window_slope(window, launches, max(1, launches // 4),
                           reps=3)

@@ -279,6 +279,7 @@ def main():
                              "serving gate)")
     args = parser.parse_args()
     logging.basicConfig(level=logging.INFO)
+    mx.serving.enable_persistent_compile_cache()   # before the first compile
     telemetry_on = (args.telemetry_jsonl or args.telemetry_port is not None
                     or args.program_report or args.health_report)
     if telemetry_on:

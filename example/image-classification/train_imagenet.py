@@ -63,6 +63,7 @@ def main():
                         help="rec size when --data-train is absent")
     args = parser.parse_args()
     logging.basicConfig(level=logging.INFO)
+    mx.serving.enable_persistent_compile_cache()   # before the first compile
 
     shape = tuple(int(x) for x in args.image_shape.split(","))
     tmp = None

@@ -59,6 +59,7 @@ def main():
     args = parser.parse_args()
 
     logging.basicConfig(level=logging.INFO)
+    mx.serving.enable_persistent_compile_cache()   # before the first compile
     if args.tpus:
         ctx = [mx.tpu(int(i)) for i in args.tpus.split(",")]
     else:

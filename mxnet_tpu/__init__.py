@@ -15,31 +15,6 @@ from __future__ import annotations
 from . import dist as _dist_bootstrap
 _dist_bootstrap.init_from_env()
 
-# Old jax (< 0.5) keeps shard_map in jax.experimental and spells the
-# replication-check knob `check_rep` instead of `check_vma`; alias a
-# signature-adapting wrapper onto the top-level namespace so every
-# `from jax import shard_map` site (parallel/, executor, ops) works on
-# the baked toolchain.
-import jax as _jax
-if not hasattr(_jax, "shard_map"):
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-    def _shard_map(f, **kwargs):
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        return _exp_shard_map(f, **kwargs)
-
-    _jax.shard_map = _shard_map
-if not hasattr(_jax.lax, "axis_size"):
-    # psum of a python scalar constant-folds to size * 1 at trace time,
-    # so this returns a static int exactly like the modern lax.axis_size
-    def _axis_size(axis_name):
-        from jax import lax
-        return lax.psum(1, axis_name)
-
-    _jax.lax.axis_size = _axis_size
-del _jax
-
 from .base import MXNetError, __version__
 from .context import Context, cpu, gpu, tpu, cpu_pinned, current_context
 from . import base

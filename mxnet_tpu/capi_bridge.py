@@ -7,6 +7,8 @@ the "core" the C API fronts is the mxnet_tpu runtime itself).
 """
 from __future__ import annotations
 
+import os
+
 import numpy as onp
 
 from . import ndarray as nd
@@ -698,7 +700,10 @@ def rtc_create(name, input_names, output_names, inputs, outputs, kernel):
 
 
 def rtc_push(r, inputs, outputs, grid_dims, block_dims):
-    r.push(list(inputs), list(outputs), grid_dims, block_dims)
+    # the C ABI has no interpret argument: the Pallas interpreter runs
+    # only where the environment asked for the CPU by name
+    r.push(list(inputs), list(outputs), grid_dims, block_dims,
+           interpret=os.environ.get("JAX_PLATFORMS") == "cpu")
 
 
 # ---------------------------------------------------------- custom op (C)

@@ -1,9 +1,10 @@
 """mxnet_tpu.data — the async device-feed pipeline.
 
-BENCH_r05 measured the device step at ~2750 img/s while the end-to-end
-fed rate collapsed to a few percent of that: the HOST input path —
-decode, batch assembly, and above all the host->device transfer — sat
-on the step's critical path.  The reference hides decode behind
+The round-5 fed benchmark found the end-to-end fed rate at a few
+percent of the device step's rate: the HOST input path — decode, batch
+assembly, and above all the host->device transfer — sat on the step's
+critical path (measured before PR 1; not measured on the current chip
+path).  The reference hides decode behind
 ``dmlc::ThreadedIter`` double buffering (``PrefetcherIter``,
 iter_prefetcher.h:129; our ``io.PrefetchingIter`` reproduces it as a
 host thread), but a TPU-native stack has a third stage to hide: the

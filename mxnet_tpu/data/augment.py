@@ -1,14 +1,14 @@
 """DeviceAugment — crop/flip/normalize compiled INTO the train program.
 
-BENCH_r02–r05 pinned every fed pipeline as host-bound
+Rounds 2–5 found every fed pipeline host-bound
 (``pipeline_bound_by: "host_cpu_decode"``): the reference's input path
 (mshadow-backed ``io/`` iterators, ``iter_normalize.h``) augments and
 float-converts every batch on the host and ships f32 NCHW — 4x the bytes of
 the decoded uint8 image, plus a host normalize/transpose pass per
 batch.  This module moves the whole augment stage onto the device:
 
-* the iterator delivers **uint8 NHWC** wire batches (4x smaller over
-  PCIe/ICI/tunnel than f32 NCHW) plus tiny per-batch *augment
+* the iterator delivers **uint8 NHWC** wire batches (4x smaller from
+  host to device than f32 NCHW) plus tiny per-batch *augment
   parameter* arrays (crop offsets, mirror flags);
 * the bound :class:`~mxnet_tpu.module.MeshExecutorGroup` compiles
   pad -> per-row crop -> mirror -> u8->f32 cast -> normalize ->
@@ -237,8 +237,8 @@ class DeviceAugment(object):
                           x[:, :, ::-1, :], x)
         # u8 -> f32 via i32: XLA:TPU fuses a direct u8->f32 cast into
         # the downstream transpose as a byte-gather loop ~145x slower
-        # than the i32-routed equivalent (PERF.md "transport
-        # pathologies")
+        # than the i32-routed equivalent (measured before PR 1; not
+        # re-measured on the current chip path)
         xf = x.astype(jnp.int32).astype(jnp.float32)
         xf = (xf - self.mean) * self._norm
         # NOTE: the executor group runs this as its OWN jitted program

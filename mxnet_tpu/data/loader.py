@@ -3,9 +3,8 @@
 The reference overlaps host decode with compute through
 ``PrefetcherIter``'s host-side double buffer (iter_prefetcher.h:129) —
 but on an accelerator the host->device TRANSFER is a third pipeline
-stage the reference never had to hide (BENCH_r05: the fed rate collapsed
-to a few percent of synthetic because every ``device_put`` sat on the
-step's critical path).  The DeviceLoader is the tf.data/infeed design
+stage the reference never had to hide: unhidden, every ``device_put``
+sits on the step's critical path.  The DeviceLoader is the tf.data/infeed design
 for this stack: a background stager thread pulls host batches from any
 ``DataIter`` and dispatches ``jax.device_put`` for batch i+1/i+2 while
 the device still computes batch i, keeping a bounded ring (depth 2-3)

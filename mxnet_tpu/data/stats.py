@@ -1,7 +1,7 @@
 """PipelineStats — one shared counter block for the async device-feed
 pipeline, drained as an immutable snapshot.
 
-Every number answers the question BENCH_r05 raised ("is the input path
+Every number answers the question the fed benchmark raised ("is the input path
 or XLA the bottleneck?") without adding a readback anywhere: the stats
 are pure host-side clocks and counters, updated by the stager/transform
 threads and read by ``Speedometer``/``fit``/``bench.py``.

@@ -69,17 +69,11 @@ def coordination_env(env=None):
 
 
 def _connect(kwargs, heartbeat):
-    """One jax.distributed.initialize attempt (heartbeat kwarg gated for
-    old jax, which rejects it before creating any client state)."""
+    """One jax.distributed.initialize attempt (the seam the connect-
+    retry tests replace)."""
     import jax
-    try:
-        jax.distributed.initialize(heartbeat_timeout_seconds=heartbeat,
-                                   **kwargs)
-    except TypeError:
-        # the kwarg binding fails before any client state is created, so
-        # retrying without the knob is safe; old jax then uses its
-        # built-in heartbeat/missed-heartbeat env defaults instead
-        jax.distributed.initialize(**kwargs)
+    jax.distributed.initialize(heartbeat_timeout_seconds=heartbeat,
+                               **kwargs)
 
 
 def initialize(coordinator_address=None, num_processes=None,
@@ -134,12 +128,7 @@ def initialize(coordinator_address=None, num_processes=None,
     # elastic mode: survivors keep running when a peer dies. Set via
     # jax.config (an env var would be ignored if jax imported first).
     if os.environ.get("MXNET_KVSTORE_ELASTIC", "0") == "1":
-        try:
-            jax.config.update("jax_enable_recoverability", True)
-        except AttributeError:
-            # jax on the baked toolchain predates the recoverability
-            # flag; survivors then rely on the heartbeat timeout alone
-            pass
+        jax.config.update("jax_enable_recoverability", True)
 
     from jax._src import distributed as _dstate
     # NOTE: probe the coordination client, NOT jax.process_count() — the

@@ -46,13 +46,15 @@ class Engine:
 
     def __init__(self, num_workers=None):
         self._native = None
+        from .runtime._native_build import NativeBuildError
+        from .runtime.core import NativeEngine
         try:
-            from .runtime.core import NativeEngine
-            eng = NativeEngine(num_workers)
-            if eng.available:
-                self._native = eng
-        except Exception:  # pragma: no cover - build env without g++
-            self._native = None
+            self._native = NativeEngine(num_workers)
+        except NativeBuildError as e:  # pragma: no cover - no g++
+            import logging
+            logging.getLogger(__name__).warning(
+                "native engine unavailable, using the single-worker "
+                "FIFO fallback: %s", e)
         if self._native is not None:
             # deterministic teardown: drain and JOIN the C++ worker pool
             # while the interpreter is still fully alive. Relying on

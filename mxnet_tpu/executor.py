@@ -667,21 +667,6 @@ def _build_eval_pipelined(symbol, mesh, n_microbatch, pp_axis="pp",
             jnp.stack([env[plan["stage_param_slots"][s][k]]
                        for s in range(n_stages)])
             for k in range(len(plan["stage_param_slots"][0])))
-        # pin the stacked stage params REPLICATED before shard_map
-        # reshards them to P('pp'): on a multi-axis mesh (dp>1) the
-        # GSPMD partitioner on this toolchain (jax 0.4.37) miscompiles
-        # an in-jit stack flowing straight into a shard_map P('pp')
-        # in_spec — each pp rank silently receives wrong slices and the
-        # pipelined numerics diverge (tests/test_module_pp.py parity
-        # tests; exact with dp=1, eager, or pre-staged inputs). Routing
-        # stack -> replicated -> shard_map's own reshard is compiled
-        # correctly and costs one all-gather of the (small) stage
-        # params per step.
-        from jax.sharding import NamedSharding
-        _repl = NamedSharding(mesh, P())
-        stacked = tuple(jax.lax.with_sharding_constraint(s, _repl)
-                        for s in stacked)
-
         B, M = x0.shape[0], n_microbatch
         if B % M:
             raise MXNetError(

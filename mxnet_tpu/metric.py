@@ -7,10 +7,9 @@ reference, but the bodies are TPU-first redesigns rather than ports:
 * every decomposable builtin also publishes a jax-traceable *fused
   statistic* (:meth:`EvalMetric.fused_stat`) so the mesh Module path can
   accumulate ``(sum, count)`` on device **inside** the fused train step.
-  On this transport a scalar device->host readback costs ~100ms
-  (docs/architecture/note_measurement.md), so the reference's
-  per-batch ``asnumpy`` metric feed (executor_group.py:510) would
-  collapse ``fit`` throughput ~25x; the fused tally is drained with a
+  A device->host readback blocks the host on the device, so the
+  reference's per-batch ``asnumpy`` metric feed (executor_group.py:510)
+  would serialize every step; the fused tally is drained with a
   single readback only when ``get()`` is called (epoch end / Speedometer
   tick). Host and device paths are pinned equal by
   tests/test_device_metric.py.

@@ -84,8 +84,7 @@ def _stack_batch_arrays(arrs):
     grouped predict).  All-host inputs stack into one contiguous numpy
     block, so staging is a single ``device_put``; any device-resident
     input stacks with jnp on device (an ``onp.stack`` there would be K
-    blocking readbacks, poisoning remote-attached transports —
-    PERF.md trap #2)."""
+    blocking readbacks)."""
     import numpy as onp
     vals = [a._read() if hasattr(a, "_read") else a for a in arrs]
     if all(isinstance(v, onp.ndarray) for v in vals):
@@ -145,8 +144,8 @@ class BaseModule(object):
         PROCESS, DEBUG afterwards.  The per-instance set alone was not
         enough: workloads that build a fresh Module per fit (bench
         reps, serving buckets, sweep scripts) re-warned the identical
-        advisory through the root logger on every instance — the
-        BENCH_r05 tail spam.  The process-level set dedupes on the
+        advisory through the root logger on every instance and drowned
+        the bench output's tail.  The process-level set dedupes on the
         RENDERED message, so genuinely different advisories (other
         shapes, other reasons) still warn once each."""
         rendered = (msg % args) if args else msg
@@ -380,7 +379,7 @@ class BaseModule(object):
         fwd+bwd+optimizer steps as one scanned device program
         (``MeshExecutorGroup.step_update_grouped``) — the
         iterations-per-loop pattern that amortizes fixed per-transfer
-        and per-launch costs on slow transports.  Numerics (params,
+        and per-launch costs.  Numerics (params,
         optimizer state, lr schedule, metric values) match per-batch
         training exactly for rng-free nets; nets with rng ops (e.g.
         Dropout) draw independent per-step key streams inside the

@@ -615,7 +615,11 @@ class Module(BaseModule):
             return False
         try:
             devs = [c.jax_device() for c in self._context]
-        except Exception:
+        except ValueError as e:
+            # a context JAX has no device for: say so, because the
+            # classic group this bind falls to hides which device ran
+            self.logger.warning(
+                "bind is not fused-eligible: %s", e)
             return False
         return (len(set(devs)) == len(devs)
                 and len({d.platform for d in devs}) == 1)
@@ -990,9 +994,9 @@ class Module(BaseModule):
               epoch=0):
         """Evaluate; on the fused mesh path with a decomposable metric the
         tally rides the device (one launch per batch, ONE readback —
-        the host loop's per-batch ``asnumpy`` costs ~100ms each on
-        remote transports). Per-batch callbacks need the running host
-        value, so their presence keeps the reference loop."""
+        the host loop's per-batch ``asnumpy`` blocks on the device every
+        batch). Per-batch callbacks need the running host value, so
+        their presence keeps the reference loop."""
         import os
         grp = self._exec_group
         if batch_end_callback is None and getattr(grp, "fused", False) \
@@ -1055,8 +1059,8 @@ class Module(BaseModule):
         if getattr(self._exec_group, "fused", False):
             # device params are the single authority: host mirrors stay
             # lazy (get_params materializes on demand) unless a callback
-            # needs them NOW — saves a ~1s/epoch packed readback on
-            # remote-attached transports
+            # needs them NOW — saves a packed readback of every
+            # parameter per epoch
             self._params_dirty = True
             return self._epoch_end_params() if need_params else None
         return super()._epoch_end_sync(need_params)

@@ -451,8 +451,8 @@ def invoke(op, inputs, raw_attrs, out=None, ctx=None):
         rng=_random.next_key() if op.needs_rng else None)
     # pin input-free ops (zeros/full/random fills) to the op's context:
     # they would otherwise land on the process default device — silently
-    # migrating "cpu" arrays onto the accelerator (and, on remote-attached
-    # TPUs, turning every host-side fill into tunnel traffic). Ops WITH
+    # migrating "cpu" arrays onto the accelerator (every host-side fill
+    # would become a host->device transfer). Ops WITH
     # inputs follow their committed inputs already; skip the config
     # context manager on that hot path.
     out_first = (next((o for o in out if o is not None), None)

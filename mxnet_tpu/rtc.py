@@ -22,7 +22,10 @@ class PallasKernel(object):
     """Compile + run a user Pallas kernel.
 
     kernel_fn(*refs): standard Pallas kernel taking input Refs then output
-    Refs; use jnp ops on ``ref[...]``.
+    Refs; use jnp ops on ``ref[...]``. The kernel compiles for the
+    device (Mosaic on TPU); a caller that wants the Pallas interpreter —
+    a CPU test — passes ``interpret=True``. It is never inferred from
+    the platform: a chip that was not found must fail, not interpret.
     """
 
     def __init__(self, kernel_fn, name="rtc_kernel"):
@@ -30,7 +33,7 @@ class PallasKernel(object):
         self.name = name
         self._compiled = {}
 
-    def __call__(self, inputs, out_shapes, out_dtypes=None, interpret=None):
+    def __call__(self, inputs, out_shapes, out_dtypes=None, interpret=False):
         import jax
         from jax.experimental import pallas as pl
         import jax.numpy as jnp
@@ -39,9 +42,7 @@ class PallasKernel(object):
                 for x in inputs]
         if out_dtypes is None:
             out_dtypes = [vals[0].dtype] * len(out_shapes)
-        if interpret is None:
-            interpret = jax.devices()[0].platform != "tpu"
-        key = tuple((tuple(v.shape), str(v.dtype)) for v in vals) + \
+        key = (bool(interpret),) + tuple((tuple(v.shape), str(v.dtype)) for v in vals) + \
             tuple((tuple(s), str(d)) for s, d in zip(out_shapes, out_dtypes))
         if key not in self._compiled:
             out_struct = [jax.ShapeDtypeStruct(tuple(s), d)
@@ -86,12 +87,15 @@ class Rtc(object):
             raise MXNetError("invalid rtc kernel source: %s" % e)
         self._pk = PallasKernel(scope["_kernel"], name=name)
 
-    def push(self, inputs, outputs, grid_dims=None, block_dims=None):
+    def push(self, inputs, outputs, grid_dims=None, block_dims=None,
+             interpret=False):
         """Run the kernel; grid/block dims accepted for API compat (Pallas
-        grids come from BlockSpecs; simple elementwise kernels need none)."""
+        grids come from BlockSpecs; simple elementwise kernels need none).
+        ``interpret=True`` runs the Pallas interpreter (CPU tests)."""
         out_shapes = [tuple(o.shape) for o in outputs]
         out_dtypes = [onp.dtype(o.dtype) for o in outputs]
-        results = self._pk(inputs, out_shapes, out_dtypes)
+        results = self._pk(inputs, out_shapes, out_dtypes,
+                           interpret=interpret)
         for o, r in zip(outputs, results):
             r.copyto(o)
         return outputs

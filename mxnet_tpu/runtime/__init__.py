@@ -9,8 +9,9 @@ Builds ``librecordio.so`` from runtime/recordio.cpp on first use (g++ -O3
   with mean/std/mirror/crop (the hot inner loop of the reference's
   iter_normalize.h + iter_batchloader.h)
 
-Falls back to pure-python/numpy implementations when no compiler is
-available, so the framework never hard-depends on the native lib.
+The paths that ask for the native lib need it: a build that fails raises
+``NativeBuildError`` (with the compiler's output) instead of falling back
+to a slower implementation without a word.
 """
 from __future__ import annotations
 
@@ -28,16 +29,14 @@ _LOCK = threading.Lock()
 
 
 def get_lib():
-    """Load (building if needed) the native lib; None if unavailable."""
+    """Load (building if needed) the native lib; raises
+    ``NativeBuildError`` when it cannot be built on this host."""
     global _LIB
     with _LOCK:
         if _LIB is not None:
-            return _LIB if _LIB is not False else None
+            return _LIB
         lib = load_native("recordio.cpp", "librecordio.so",
                           extra_flags=("-march=native", "-fopenmp"))
-        if lib is None:
-            _LIB = False
-            return None
         lib.ri_open.restype = ctypes.c_void_p
         lib.ri_open.argtypes = [ctypes.c_char_p]
         lib.ri_count.restype = ctypes.c_int64
@@ -58,18 +57,15 @@ def get_lib():
 
 
 class RecordFile(object):
-    """mmap'd random-access RecordIO reader (native; python fallback)."""
+    """mmap'd random-access RecordIO reader (native; a file the native
+    reader cannot map is scanned in python)."""
 
     def __init__(self, path):
         self.path = path
         self._lib = get_lib()
-        self._handle = None
         self._py_offsets = None
-        if self._lib is not None:
-            self._handle = self._lib.ri_open(path.encode())
-            if not self._handle:
-                self._lib = None
-        if self._lib is None:
+        self._handle = self._lib.ri_open(path.encode())
+        if not self._handle:
             self._scan_python()
 
     def _scan_python(self):
@@ -118,9 +114,9 @@ def assemble_batch(images, mean=None, std=None, mirror=None, crop_yx=None,
                    out_hw=None, out=None):
     """uint8 (n,h,w,c) HWC images -> float32 (n,c,oh,ow) NCHW batch.
 
-    Native OpenMP path when available; numpy fallback otherwise. ``out``
-    lets the caller supply a staging buffer (e.g. a pooled HostPool array,
-    the iter_prefetcher.h double-buffer pattern) instead of allocating.
+    Runs the native OpenMP loop. ``out`` lets the caller supply a
+    staging buffer (e.g. a pooled HostPool array, the iter_prefetcher.h
+    double-buffer pattern) instead of allocating.
     """
     images = onp.ascontiguousarray(images, dtype=onp.uint8)
     n, h, w, c = images.shape
@@ -129,44 +125,27 @@ def assemble_batch(images, mean=None, std=None, mirror=None, crop_yx=None,
         assert out.shape == (n, c, oh, ow) and out.dtype == onp.float32 \
             and out.flags.c_contiguous, "bad staging buffer"
     lib = get_lib()
-    if lib is not None:
-        if out is None:
-            out = onp.empty((n, c, oh, ow), dtype=onp.float32)
-        meanp = stdp = None
-        if mean is not None:
-            mean = onp.ascontiguousarray(mean, dtype=onp.float32)
-            meanp = mean.ctypes.data_as(ctypes.POINTER(ctypes.c_float))
-        if std is not None:
-            std_inv = onp.ascontiguousarray(1.0 / onp.asarray(std),
-                                            dtype=onp.float32)
-            stdp = std_inv.ctypes.data_as(ctypes.POINTER(ctypes.c_float))
-        mirp = cyp = cxp = None
-        if mirror is not None:
-            mirror = onp.ascontiguousarray(mirror, dtype=onp.uint8)
-            mirp = mirror.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8))
-        if crop_yx is not None:
-            cy = onp.ascontiguousarray(crop_yx[0], dtype=onp.int32)
-            cx = onp.ascontiguousarray(crop_yx[1], dtype=onp.int32)
-            cyp = cy.ctypes.data_as(ctypes.POINTER(ctypes.c_int32))
-            cxp = cx.ctypes.data_as(ctypes.POINTER(ctypes.c_int32))
-        lib.assemble_batch(
-            images.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)),
-            n, h, w, c, meanp, stdp, mirp, cyp, cxp, oh, ow,
-            out.ctypes.data_as(ctypes.POINTER(ctypes.c_float)))
-        return out
-    # numpy fallback
     if out is None:
         out = onp.empty((n, c, oh, ow), dtype=onp.float32)
-    for i in range(n):
-        img = images[i]
-        cy = int(crop_yx[0][i]) if crop_yx is not None else 0
-        cx = int(crop_yx[1][i]) if crop_yx is not None else 0
-        patch = img[cy:cy + oh, cx:cx + ow].astype(onp.float32)
-        if mirror is not None and mirror[i]:
-            patch = patch[:, ::-1]
-        if mean is not None:
-            patch = patch - onp.asarray(mean, onp.float32)
-        if std is not None:
-            patch = patch / onp.asarray(std, onp.float32)
-        out[i] = patch.transpose(2, 0, 1)
+    meanp = stdp = None
+    if mean is not None:
+        mean = onp.ascontiguousarray(mean, dtype=onp.float32)
+        meanp = mean.ctypes.data_as(ctypes.POINTER(ctypes.c_float))
+    if std is not None:
+        std_inv = onp.ascontiguousarray(1.0 / onp.asarray(std),
+                                        dtype=onp.float32)
+        stdp = std_inv.ctypes.data_as(ctypes.POINTER(ctypes.c_float))
+    mirp = cyp = cxp = None
+    if mirror is not None:
+        mirror = onp.ascontiguousarray(mirror, dtype=onp.uint8)
+        mirp = mirror.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8))
+    if crop_yx is not None:
+        cy = onp.ascontiguousarray(crop_yx[0], dtype=onp.int32)
+        cx = onp.ascontiguousarray(crop_yx[1], dtype=onp.int32)
+        cyp = cy.ctypes.data_as(ctypes.POINTER(ctypes.c_int32))
+        cxp = cx.ctypes.data_as(ctypes.POINTER(ctypes.c_int32))
+    lib.assemble_batch(
+        images.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)),
+        n, h, w, c, meanp, stdp, mirp, cyp, cxp, oh, ow,
+        out.ctypes.data_as(ctypes.POINTER(ctypes.c_float)))
     return out

@@ -44,11 +44,8 @@ def get_lib():
     global _LIB
     with _LOCK:
         if _LIB is not None:
-            return _LIB if _LIB is not False else None
+            return _LIB
         lib = load_native("engine_core.cpp", "libengine_core.so")
-        if lib is None:
-            _LIB = False
-            return None
         lib.eng_create.restype = ctypes.c_void_p
         lib.eng_create.argtypes = [ctypes.c_int]
         lib.eng_destroy.argtypes = [ctypes.c_void_p]
@@ -85,13 +82,10 @@ def get_lib():
 
 
 class NativeEngine(object):
-    """The C++ dependency engine (None-safe: check ``available``)."""
+    """The C++ dependency engine (``available`` until closed)."""
 
     def __init__(self, num_workers=None):
         self._lib = get_lib()
-        self._h = None
-        if self._lib is None:
-            return
         if num_workers is None:
             if os.environ.get("MXNET_ENGINE_TYPE", "") == "NaiveEngine":
                 num_workers = 0  # synchronous, the race-bisection mode
@@ -191,7 +185,7 @@ class HostPool(object):
 
     def __init__(self):
         self._lib = get_lib()
-        self._h = self._lib.sto_create() if self._lib is not None else None
+        self._h = self._lib.sto_create()
 
     @property
     def available(self):

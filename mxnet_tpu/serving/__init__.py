@@ -22,8 +22,10 @@ mesh Module + durable checkpoints). Three pieces:
   (params digest, precision mode, bucket, backend); a second replica
   warming from the same directory deserializes every bucket with ZERO
   XLA compiles and bitwise-identical served rows.
-  ``MXNET_COMPILE_CACHE_DIR`` wires jax's own persistent compilation
-  cache process-wide and doubles as the default AOT entry store.
+  ``MXNET_COMPILE_CACHE_DIR`` names the default AOT entry store
+  (``<dir>/aot``); jax's own persistent compilation cache is placed
+  by ``JAX_COMPILATION_CACHE_DIR`` or, from entry points, by
+  :func:`enable_persistent_compile_cache`.
 * :class:`DecodeEngine` (:mod:`~mxnet_tpu.serving.decode`) —
   continuous-batching step-wise serving for autoregressive sequence
   models: bucketed-by-length prefill programs, ONE device-resident
@@ -75,10 +77,3 @@ __all__ = ["Predictor", "DynamicBatcher", "ServingStats", "Tenant",
            "ExecutableCache", "enable_persistent_compile_cache",
            "QueueFull", "RequestAbandoned", "RequestTimeout",
            "ServerClosed", "TenantShed", "WorkerCrashed"]
-
-# process-wide persistent compilation cache: MXNET_COMPILE_CACHE_DIR
-# points jax's own cache (and the default AOT entry store Predictor
-# .warmup uses) at a shared directory — a new replica then warms by
-# deserializing instead of recompiling (docs/api/serving.md
-# "Persistent compile cache")
-cache._autowire()

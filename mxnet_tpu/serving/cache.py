@@ -8,12 +8,15 @@ served, which makes elastic autoscale against the ``slo.*`` burn-rate
 gauges useless in practice. This module removes that wall in two
 layers:
 
-* **process-wide jax compilation cache** — ``MXNET_COMPILE_CACHE_DIR``
-  (or :func:`enable_persistent_compile_cache`) points jax's own
-  persistent compilation cache (``jax_compilation_cache_dir``) at a
-  shared directory, so EVERY jit in the process — train step, augment
-  program, serving buckets — reuses compiled artifacts across
-  processes when the backend supports it.
+* **process-wide jax compilation cache** —
+  :func:`enable_persistent_compile_cache`, called by entry points
+  (``chip_smoke.py``, ``bench.py``, the example trainers) before their
+  first compile, makes sure jax's own persistent compilation cache has
+  a directory: ``JAX_COMPILATION_CACHE_DIR`` when the caller's
+  environment sets it (jax reads that itself), else the fixed
+  ``<checkout>/.jax_cache``. EVERY jit in the process — train step,
+  augment program, serving buckets — then reuses compiled artifacts
+  across processes.
 * **explicit AOT executable cache** — ``Predictor.warmup(cache_dir=)``
   serializes each bucket's compiled program via
   ``jax.experimental.serialize_executable`` into an atomic,
@@ -67,45 +70,36 @@ _SUFFIX = ".mxexec"
 
 logger = logging.getLogger("mxnet_tpu.serving")
 
+# <checkout>/.jax_cache (git-ignored): where jax's persistent compile
+# cache goes when JAX_COMPILATION_CACHE_DIR does not place it
+_DEFAULT_JAX_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
 # key fields that must match field-by-field for an entry to load; the
 # order is the order mismatch warnings report them in
 KEY_FIELDS = ("params_digest", "precision_mode", "bucket", "input_sig",
               "backend_sig")
 
 
-def enable_persistent_compile_cache(cache_dir):
-    """Point jax's process-wide persistent compilation cache at
-    ``cache_dir`` (created if missing) and drop the min-compile-time /
-    min-entry-size floors so the small serving-bucket programs qualify.
-    Called automatically at import when ``MXNET_COMPILE_CACHE_DIR`` is
-    set; safe to call again with the same directory. Returns True when
-    the cache was wired, False when this jax build lacks it."""
+def enable_persistent_compile_cache():
+    """Give jax's process-wide persistent compilation cache a directory
+    and return it. Entry points call this before their first compile;
+    package import never does (the CPU test suite would otherwise fill
+    a cache that travels with the tree to a host with another CPU).
+
+    With ``JAX_COMPILATION_CACHE_DIR`` set this does nothing: jax reads
+    that variable itself, and no code here may override a directory
+    given from outside. Unset, jax is pointed at the fixed
+    ``<checkout>/.jax_cache`` — a directory that moves never hits, so
+    the path is never derived from a temporary name, a pid or the
+    time."""
+    given = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if given:
+        return given
     import jax
-    cache_dir = os.path.abspath(str(cache_dir))
-    os.makedirs(cache_dir, exist_ok=True)
-    try:
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-    except Exception as e:  # noqa: BLE001 - optional jax feature
-        logger.warning("persistent compilation cache unavailable in "
-                       "this jax build: %s", e)
-        return False
-    for knob, val in (("jax_persistent_cache_min_compile_time_secs", 0),
-                      ("jax_persistent_cache_min_entry_size_bytes", 0)):
-        try:
-            jax.config.update(knob, val)
-        except Exception:  # noqa: BLE001 - knob name varies by version
-            pass
-    return True
-
-
-def _autowire():
-    """Import-time twin of :func:`enable_persistent_compile_cache`:
-    honor ``MXNET_COMPILE_CACHE_DIR`` process-wide. The SAME directory
-    also serves as the default AOT entry store for
-    ``Predictor.warmup()`` (entries live under ``<dir>/aot/``)."""
-    path = os.environ.get("MXNET_COMPILE_CACHE_DIR")
-    if path:
-        enable_persistent_compile_cache(path)
+    jax.config.update("jax_compilation_cache_dir", _DEFAULT_JAX_CACHE)
+    return _DEFAULT_JAX_CACHE
 
 
 def backend_signature(mesh_axes=None, n_dev=1, device_kind=None,

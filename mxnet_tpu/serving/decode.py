@@ -617,6 +617,9 @@ class DecodeEngine(object):
                     if onp.issubdtype(v.dtype, onp.floating)
                     else jnp.asarray(v))
                 for k, v in host.items()}
+        # where the bare device_put above landed: jax's default device
+        (self._device,) = jax.tree_util.tree_leaves(
+            self._dparams)[0].devices()
 
         # power-of-two length-bucket ladder (Predictor idiom)
         top = max(4, int(max_prefill_len))
@@ -894,13 +897,11 @@ class DecodeEngine(object):
         return specs
 
     def _program_key(self, name, bucket):
-        import jax
         from . import cache as _cache
-        dev = jax.devices()[0]
         backend = _cache.backend_signature(
             mesh_axes=None, n_dev=1,
-            device_kind=getattr(dev, "device_kind", ""),
-            platform=jax.default_backend())
+            device_kind=self._device.device_kind,
+            platform=self._device.platform)
         input_sig = ("decode.%s:model=%s;slots=%d;pb=%d;temp=%g"
                      % (name, self._model.signature(), self._slots,
                         PREFILL_ROWS, self._temperature))
@@ -967,8 +968,11 @@ class DecodeEngine(object):
             try:
                 payload, in_tree, out_tree = store.load(key)
                 from jax.experimental import serialize_executable as _se
-                loaded = _se.deserialize_and_load(payload, in_tree,
-                                                  out_tree)
+                # the engine's one device, not the backend-wide default
+                loaded = _se.deserialize_and_load(
+                    payload, in_tree, out_tree,
+                    backend=self._device.client,
+                    execution_devices=[self._device])
                 source = "deserialized"
             except _cache.CacheMiss as e:
                 log = logger.info if e.reason == "absent" \

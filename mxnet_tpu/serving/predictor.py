@@ -502,8 +502,14 @@ class Predictor:
         try:
             payload, in_tree, out_tree = store.load(key)
             from jax.experimental import serialize_executable as _se
-            loaded = _se.deserialize_and_load(payload, in_tree,
-                                              out_tree)
+            # name the bucket's own devices (and their client): the
+            # defaults are the default backend and EVERY device of it,
+            # which a one-chip replica in a multi-chip process — or a
+            # host-CPU replica on a chip machine — cannot launch on
+            devs = list(grp.mesh.devices.flat)
+            loaded = _se.deserialize_and_load(
+                payload, in_tree, out_tree, backend=devs[0].client,
+                execution_devices=devs)
             source = "deserialized"
         except _cache.CacheMiss as e:
             log = self.logger.info if e.reason == "absent" \

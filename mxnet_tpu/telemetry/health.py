@@ -1,7 +1,7 @@
 """RegressionWatchdog — a live judge over the gauges PRs 5/7 publish.
 
 A step-time or MFU regression against the recorded trajectory
-(PERF.md's BENCH_r01→r05) used to be visible only when a human re-ran
+(the bench records kept round over round) used to be visible only when a human re-ran
 bench.py. The watchdog watches the LIVE run instead: off the step
 path, it compares windows of recent :class:`StepTimeline` records and
 registry gauges against a pinned baseline and emits ONE structured

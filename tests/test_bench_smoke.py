@@ -7,10 +7,12 @@ import os
 import subprocess
 import sys
 
+import pytest
+
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def test_bench_emits_contract_json():
+def _smoke_env(**over):
     sys.path.insert(0, ROOT)
     from __graft_entry__ import virtual_cpu_env  # the one clean-env home
     env = virtual_cpu_env(1)
@@ -19,9 +21,8 @@ def test_bench_emits_contract_json():
     # compile time (100s+ each on this backend) inside the tier-1
     # suite budget, where every second pushes later tests past the
     # 870s cutoff.  The grouped path is pinned by
-    # tests/test_module_grouped.py, and both stages are
-    # try/except-guarded in bench main(), so drift there degrades to a
-    # recorded *_error field on the TPU run, not a crash.
+    # tests/test_module_grouped.py; on the TPU run drift there shows as
+    # a recorded *_error field and a non-zero exit after the JSON line.
     # BENCH_SERVE=0 for the same reason: Predictor warmup compiles one
     # resnet-50 eval program per batch bucket (tests/test_serving.py
     # pins the serving contracts on a small net instead).
@@ -43,9 +44,14 @@ def test_bench_emits_contract_json():
                BENCH_GROUPED="0", BENCH_HANDWRITTEN="0",
                BENCH_SERVE="0", BENCH_PREFETCH="0", BENCH_TELEMETRY="0",
                BENCH_PRECISION="0", BENCH_SHARDED_CACHE="0")
+    env.update(over)
+    return env
+
+
+def test_bench_emits_contract_json():
     proc = subprocess.run([sys.executable, os.path.join(ROOT, "bench.py")],
                           capture_output=True, text=True, timeout=1200,
-                          env=env, cwd=ROOT)
+                          env=_smoke_env(), cwd=ROOT)
     assert proc.returncode == 0, proc.stderr[-2000:]
     line = proc.stdout.strip().splitlines()[-1]
     rec = json.loads(line)
@@ -60,3 +66,35 @@ def test_bench_emits_contract_json():
     assert rec.get("fit_img_per_sec", 0) > 0 or "fit_error" in rec, rec
     if rec.get("fit_img_per_sec"):
         assert rec.get("fit_device_metric") is True, rec
+
+
+@pytest.mark.slow   # one more full resnet-50 train-step compile
+def test_section_that_raises_exits_nonzero_after_the_json_line():
+    code = ("import bench\n"
+            "def boom(mx):\n"
+            "    raise RuntimeError('forced')\n"
+            "bench._bench_autopilot = boom\n"
+            "bench.main()\n")
+    proc = subprocess.run([sys.executable, "-c", code],
+                          capture_output=True, text=True, timeout=1200,
+                          env=_smoke_env(BENCH_FIT="0", BENCH_DECODE="0"),
+                          cwd=ROOT)
+    assert proc.returncode != 0
+    rec = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert rec["autopilot_error"] == "forced" and rec["value"] > 0
+    assert "section(s) raised: autopilot" in proc.stderr
+
+
+@pytest.mark.slow   # a fresh interpreter that waits for the TPU probe
+def test_bench_refuses_a_cpu_nobody_asked_for():
+    """No TPU and no explicit JAX_PLATFORMS=cpu: no metric line, exit
+    non-zero (the BENCH_r06 failure — a CPU number under a device
+    metric's name — made impossible)."""
+    env = _smoke_env()
+    del env["JAX_PLATFORMS"]
+    proc = subprocess.run([sys.executable, os.path.join(ROOT, "bench.py")],
+                          capture_output=True, text=True, timeout=300,
+                          env=env, cwd=ROOT)
+    assert proc.returncode != 0
+    assert "refusing to measure" in proc.stderr
+    assert "resnet50_train_throughput" not in proc.stdout

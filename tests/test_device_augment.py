@@ -16,7 +16,7 @@ The contracts this file pins (ISSUE 9 acceptance):
 * zero post-warmup retraces with augment + cache + prefetch + grouped
   steps enabled;
 * the cache budget falls back to the host path gracefully;
-* the once-per-process warning dedupe (BENCH_r05 tail spam).
+* the once-per-process warning dedupe (the bench output's tail spam).
 """
 import logging
 

@@ -364,3 +364,118 @@ def test_cache_shared_across_checkpoints_of_one_architecture(
     ref = retrained.predict(
         mx.io.NDArrayIter(X, None, batch_size=8)).asnumpy()
     assert np.array_equal(pred.predict(X[:5]), ref[:5])
+
+
+# ---------------------------------------------------------------------
+# a one-device replica in a process that sees eight devices
+# ---------------------------------------------------------------------
+def test_one_device_replicas_warm_and_serve_among_eight(tmp_path, trained):
+    """jax 0.9's ``deserialize_and_load`` defaults ``execution_devices``
+    to EVERY device of the backend; a one-device executable loaded that
+    way fails at first launch ("expected 8 shards"). Both warm paths
+    name their own devices — pinned on devices that are not device 0."""
+    import jax
+    from mxnet_tpu.serving.decode import DecodeEngine, LSTMCharLM
+    devs = jax.devices()
+    assert len(devs) == 8
+    mod, X, ref = trained
+    cache_dir = str(tmp_path / "cache")
+
+    def replica():
+        p = Predictor(mod, max_batch_size=8, context=[mx.tpu(3)])
+        p.warmup(cache_dir=cache_dir)
+        return p
+
+    cold, warm = replica(), replica()
+    assert {r["source"] for r in warm.warmup_report().values()} \
+        == {"deserialized"}
+    grp = warm._modules[8]._exec_group
+    assert list(grp.mesh.devices.flat) == [devs[3]]
+    for n in (1, 5, 8):
+        assert np.array_equal(warm.predict(X[:n]), ref[:n]), n
+    cold.release()
+    warm.release()
+
+    model = LSTMCharLM(vocab_size=16, num_hidden=8, num_embed=4)
+    params = model.init_params(seed=3)
+
+    def engine():
+        # the engine lands where a bare device_put lands: jax's
+        # default device
+        with jax.default_device(devs[5]):
+            eng = DecodeEngine(model, params, slots=2, max_prefill_len=4,
+                               start=False)
+            report = eng.warmup(cache_dir=cache_dir)
+        assert eng._device == devs[5]
+        try:
+            eng.start()
+            return eng.generate([1, 2, 3], max_new_tokens=6,
+                                timeout=120), report
+        finally:
+            eng.shutdown(drain=True)
+            eng.release()
+
+    first, _ = engine()
+    second, report = engine()
+    assert {r["source"] for r in report.values()} == {"deserialized"}
+    assert first == second and len(first) == 6
+
+
+# ---------------------------------------------------------------------
+# the jax compile cache is placed from outside, or at one fixed path
+# ---------------------------------------------------------------------
+def test_jax_compile_cache_dir_is_never_overridden(tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set, jax reads it itself and no
+    code path here sets another directory — not the entry-point
+    function, not MXNET_COMPILE_CACHE_DIR (which names only the AOT
+    store), not a warmup. Unset, the one function points jax at
+    <checkout>/.jax_cache; nothing else in the tree touches the knob."""
+    import re
+    import subprocess
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    setters = []
+    for base in ("mxnet_tpu", "tools", "example"):
+        for dirpath, _, files in os.walk(os.path.join(root, base)):
+            setters += [os.path.join(dirpath, f) for f in files
+                        if f.endswith(".py")]
+    setters += [os.path.join(root, f) for f in
+                ("bench.py", "chip_smoke.py", "__graft_entry__.py")]
+    setters = [os.path.relpath(p, root) for p in setters
+               if re.search(r"update\(\s*[\"']jax_compilation_cache_dir",
+                            open(p).read())]
+    assert setters == [os.path.join("mxnet_tpu", "serving", "cache.py")]
+
+    code = (
+        "import jax, numpy as np\n"
+        "import mxnet_tpu as mx\n"
+        "from mxnet_tpu.serving import enable_persistent_compile_cache\n"
+        "from mxnet_tpu.serving.decode import DecodeEngine, LSTMCharLM\n"
+        "print('import', jax.config.jax_compilation_cache_dir)\n"
+        "print('returned', enable_persistent_compile_cache())\n"
+        "m = LSTMCharLM(vocab_size=8, num_hidden=4, num_embed=2)\n"
+        "e = DecodeEngine(m, m.init_params(seed=0), slots=1,\n"
+        "                 max_prefill_len=4, start=False)\n"
+        "e.warmup()\n"
+        "print('after', jax.config.jax_compilation_cache_dir)\n")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               MXNET_COMPILE_CACHE_DIR=str(tmp_path / "aot_root"))
+
+    def run(extra):
+        out = subprocess.run([sys.executable, "-c", code], cwd=root,
+                             env=dict(env, **extra), text=True,
+                             capture_output=True, timeout=300)
+        assert out.returncode == 0, out.stderr[-2000:]
+        return dict(ln.split(" ", 1) for ln in out.stdout.splitlines()
+                    if ln.split(" ", 1)[0] in ("import", "returned",
+                                               "after"))
+
+    given = str(tmp_path / "given")
+    got = run({"JAX_COMPILATION_CACHE_DIR": given})
+    assert got == {"import": given, "returned": given, "after": given}
+    assert _entries(str(tmp_path / "aot_root"))     # the AOT store only
+
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    fixed = os.path.join(root, ".jax_cache")
+    got = run({})
+    assert got == {"import": "None", "returned": fixed, "after": fixed}

@@ -59,10 +59,7 @@ def measure_psum(size_mb, repeat):
 
     @jax.jit
     def allreduce(x):
-        try:
-            from jax import shard_map
-        except ImportError:  # pre-0.4.31 jax keeps it in experimental
-            from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         def f(s):
             return jax.lax.psum(s, "d")

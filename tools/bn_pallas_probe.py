@@ -244,11 +244,9 @@ def run_shape(shape, steps, relu=True, dtype="bfloat16"):
 
     # timing: dependent chain (previous dx IS the next du — no blend, so
     # no extra traffic and no fusion-barrier asymmetry between paths),
-    # two chain lengths differenced.  The window-ending data-dependent
-    # readback costs ~100ms±20 on this transport (PERF.md "Measurement
-    # integrity"; same methodology as bench.py's two_window_slope), so a
-    # single-window measurement would bury kernels whose true cost is
-    # ~1ms under a fixed cost 100× larger.
+    # two chain lengths differenced (same methodology as bench.py's
+    # two_window_slope): a single window would charge the window-ending
+    # readback's fixed cost to kernels whose true cost is ~1ms.
     tiny = jax.jit(lambda a: jnp.sum(a.astype(f32)))
     L1, L2 = max(4, steps // 4), steps
 
@@ -346,8 +344,7 @@ def main():
     ap.add_argument("--steps", type=int, default=30)
     ap.add_argument("--no-relu", action="store_true")
     ap.add_argument("--shape", type=int, default=-1,
-                    help="index into the shape list (remote compiles are "
-                         "slow; default -1 = all)")
+                    help="index into the shape list (default -1 = all)")
     ap.add_argument("--copy-sweep", action="store_true",
                     help="measure the pallas block-DMA bandwidth ceiling "
                          "instead of the backward kernels")
