@@ -51,7 +51,7 @@ EXIT_LEG_FAILED, EXIT_NO_CHIP, EXIT_REHEARSAL = 1, 2, 3
 REAL = dict(per_chip=128, img=224, classes=1000, steps=10, serve_max=32,
             requests=(1, 3, 8, 17, 32, 5), new_tokens=24)
 # rehearsal: same code, shapes a CPU compiles in minutes
-TINY = dict(per_chip=2, img=64, classes=1000, steps=2, serve_max=4,
+TINY = dict(per_chip=4, img=64, classes=1000, steps=2, serve_max=4,
             requests=(1, 3, 4, 2), new_tokens=8)
 
 # bf16 keeps 8 mantissa bits (ulp 2**-8).  Predictor buckets and
