@@ -1806,8 +1806,9 @@ def _bench_pipeline(mx, mod, recs, step_batch, steps, img, synthetic_img_s,
 
 def _grouped_pipeline_step(mod, group_k, next_batch):
     """One fed grouped step: pull K batches, train them as one staged
-    block through Module._grouped_step (falling back per batch if the
-    grouped program declines, so the window still measures training).
+    block through Module._grouped_stage/_grouped_update (falling back
+    per batch if the grouped program declines, so the window still
+    measures training).
     Returns (run_group, state); ``state["fallbacks"]`` counts declined
     groups — a nonzero count means the recorded rate did NOT exercise
     the grouped program and must be flagged, not reported as grouped."""
@@ -1815,7 +1816,8 @@ def _grouped_pipeline_step(mod, group_k, next_batch):
 
     def run_group():
         group = [next_batch() for _ in range(group_k)]
-        if not mod._grouped_step(group):
+        staged = mod._grouped_stage(group)
+        if staged is None or not mod._grouped_update(staged):
             state["fallbacks"] += 1
             for b in group:
                 mod.forward_backward(b)

@@ -25,13 +25,14 @@ def main():
     args = parser.parse_args()
     logging.basicConfig(level=logging.INFO)
 
+    mx.telemetry.enable()   # spans also reach the Chrome trace's ring
     mx.profiler.profiler_set_config(mode="all", filename=args.output)
     mx.profiler.profiler_set_state("run")
 
     a = mx.nd.array(np.random.rand(args.size, args.size).astype(np.float32))
     b = mx.nd.array(np.random.rand(args.size, args.size).astype(np.float32))
     for i in range(args.iter_num):
-        with mx.profiler.Scope("matmul_%d" % i):
+        with mx.telemetry.span("matmul_%d" % i):
             c = mx.nd.dot(a, b)
             c.wait_to_read()
 

@@ -222,7 +222,7 @@ class DeviceLoader(DataIter):
         """K host batches -> ONE contiguous (K, B, ...) block per input,
         staged through the group's ``stage_stacked`` (one ``device_put``
         per input).  Delivered as per-batch views onto the block, each
-        carrying the staged dict so ``Module._grouped_step`` can hand
+        carrying the staged dict so ``Module._grouped_stage`` can hand
         the block straight to the scanned program."""
         from ..module.base_module import stack_group_inputs
         # default stacking rule: all-host batches form ONE contiguous

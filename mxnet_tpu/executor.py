@@ -43,7 +43,11 @@ def _run_op(n, get, put, rng, is_train, aux_sink=None):
     if n.op.needs_rng:
         rng, sub = jax.random.split(rng)
     octx = OpContext(is_train=is_train, rng=sub)
-    res = n.op.fcompute(n.attrs, ins, octx)
+    # trace-time only: the node's name becomes the `op_name` metadata of
+    # every HLO instruction it lowers to, so a profile's fusions say
+    # which symbol node they came from
+    with jax.named_scope(n.name):
+        res = n.op.fcompute(n.attrs, ins, octx)
     n_out = n.op.num_outputs(n.attrs)
     for oi in range(n_out):
         put(id(n), oi, res[oi])

@@ -597,51 +597,74 @@ class ImageRecordIter(DataIter):
                 idxs = idxs + self.seq[:pad]
             else:
                 pass
-        if self.cache_decoded:
-            if self._cache is None:
-                self._fill_cache()
-            cache, cl = self._cache
-            imgs = cache[idxs]            # fancy-index gather: memcpy-rate
-            labels = cl[idxs]
-        elif self._proc_mode:
-            c, th, tw = self.data_shape
-            ep_seed = self.seed ^ (self._epoch * 0x9e3779b1 & 0xffffffff)
-            work = [(i, self.resize, th, tw, self._decode_rand_crop,
-                     ep_seed) for i in idxs]
-            results = list(self.pool.map(_proc_decode_one, work,
-                                         chunksize=4))
-        else:
-            results = list(self.pool.map(self._decode_one, idxs))
-        if not self.cache_decoded:
-            imgs = onp.stack([r[0] for r in results])
-            labels = onp.stack([r[1] for r in results])
-        label_out = labels if self.label_width > 1 else labels[:, 0]
-        if self._defer:
-            # raw uint8 NHWC wire batch + the spec's per-batch augment
-            # parameter draws, keyed (seed, epoch, batch index) — the
-            # bound program does crop/mirror/normalize in one fused
-            # stage (4x fewer staged bytes than f32 NCHW)
-            spec = self._aug_spec
-            params = spec.draw(self._data_name, self._epoch,
-                               self._batch_seq, imgs.shape[0])
-            self._batch_seq += 1
-            data = [imgs] + [
-                params[d.name]
-                for d in spec.param_descs(self._data_name,
-                                          imgs.shape[0])]
-            return DataBatch(data, [nd.array(label_out)], pad=pad)
-        mirror = None
-        if self.rand_mirror:
-            mirror = onp.array(
-                [self.rng.random() < 0.5 for _ in range(len(idxs))],
-                onp.uint8)
-        if self.device_augment:
-            batch = nd.NDArray(self._device_preprocess(imgs, mirror))
-        else:
-            std = self.std / self.scale
-            batch = nd.array(runtime.assemble_batch(imgs, mean=self.mean,
-                                                    std=std, mirror=mirror))
-        return DataBatch([batch], [nd.array(label_out)], pad=pad)
+        from . import telemetry
+        span = telemetry.span
+        # record read + JPEG decode + resize/crop of the batch: wall time
+        # on this thread while the pool works (or the cache's gather)
+        with span("input.decode"):
+            if self.cache_decoded:
+                if self._cache is None:
+                    self._fill_cache()
+                cache, cl = self._cache
+                imgs = cache[idxs]        # fancy-index gather: memcpy-rate
+                labels = cl[idxs]
+            elif self._proc_mode:
+                c, th, tw = self.data_shape
+                ep_seed = self.seed ^ (self._epoch * 0x9e3779b1
+                                       & 0xffffffff)
+                work = [(i, self.resize, th, tw, self._decode_rand_crop,
+                         ep_seed) for i in idxs]
+                results = list(self.pool.map(_proc_decode_one, work,
+                                             chunksize=4))
+            else:
+                results = list(self.pool.map(self._decode_one, idxs))
+        with span("input.assemble"):
+            if not self.cache_decoded:
+                imgs = onp.stack([r[0] for r in results])
+                labels = onp.stack([r[1] for r in results])
+            label_out = labels if self.label_width > 1 else labels[:, 0]
+            label_out = onp.asarray(label_out, onp.float32)
+            if self._defer:
+                # raw uint8 NHWC wire batch + the spec's per-batch
+                # augment parameter draws, keyed (seed, epoch, batch
+                # index) — the bound program does crop/mirror/normalize
+                # in one fused stage (4x fewer staged bytes than f32
+                # NCHW)
+                spec = self._aug_spec
+                params = spec.draw(self._data_name, self._epoch,
+                                   self._batch_seq, imgs.shape[0])
+                self._batch_seq += 1
+                data = [imgs] + [
+                    params[d.name]
+                    for d in spec.param_descs(self._data_name,
+                                              imgs.shape[0])]
+            else:
+                mirror = None
+                if self.rand_mirror:
+                    mirror = onp.array(
+                        [self.rng.random() < 0.5
+                         for _ in range(len(idxs))], onp.uint8)
+                if not self.device_augment:
+                    imgs = runtime.assemble_batch(
+                        imgs, mean=self.mean, std=self.std / self.scale,
+                        mirror=mirror)
+        # the calls that hand host memory to jax.device_put (the wire
+        # batch of the defer branch goes up in the executor group's
+        # staging instead); the transfers complete later
+        with span("input.put"):
+            label = nd.array(label_out)
+            h2d = label_out.nbytes
+            if not self._defer:
+                h2d += imgs.nbytes
+                if self.device_augment:
+                    if mirror is not None:
+                        h2d += mirror.nbytes
+                    data = [nd.NDArray(self._device_preprocess(imgs,
+                                                               mirror))]
+                else:
+                    data = [nd.array(imgs)]
+            telemetry.count("input.h2d_bytes", h2d)
+        return DataBatch(data, [label], pad=pad)
 
 
 # detection pipeline lives in its own module; re-exported here so the

@@ -61,7 +61,7 @@ def stack_group_inputs(batches, data_names, label_names,
     ONE rule pairing a group's arrays with their bound input names
     (every data input; a label only when every batch in the group
     provides it).  Shared by the grouped train step
-    (``Module._grouped_step``) and the device-feed stager
+    (``Module._grouped_stage``) and the device-feed stager
     (``mxnet_tpu.data.DeviceLoader._stage_block``), so the two can
     never drift on label handling.  ``stack`` defaults to
     :func:`_stack_batch_arrays` (host blocks contiguous, device
@@ -205,31 +205,32 @@ class BaseModule(object):
         JSONL lines), so a served/eval regression is visible to the
         health watchdog on the same wire as a train-step one."""
         from .. import telemetry
+        span = telemetry.span
         eval_metric = metric_mod.create(eval_metric)
         eval_metric.reset()
         seen = 0
         tl = telemetry.timeline() if telemetry.enabled() else None
-        with telemetry.span("score", epoch=epoch):
+        with span("score", epoch=epoch):
             batches = self._eval_batches(eval_data, num_batch, reset)
             while True:
-                t0 = time.perf_counter() if tl is not None else 0.0
                 try:
-                    index, batch = next(batches)
+                    with span("score.next") as s_next:
+                        index, batch = next(batches)
                 except StopIteration:
                     break
-                t1 = time.perf_counter() if tl is not None else 0.0
-                self.forward(batch, is_train=False)
-                t2 = time.perf_counter() if tl is not None else 0.0
-                self.update_metric(eval_metric, batch.label)
-                self._fire(batch_end_callback, epoch, index, eval_metric,
-                           locals())
+                with span("score.forward") as s_forward:
+                    self.forward(batch, is_train=False)
+                with span("score.metric") as s_metric:
+                    self.update_metric(eval_metric, batch.label)
+                    self._fire(batch_end_callback, epoch, index,
+                               eval_metric, locals())
                 seen = index + 1
                 if tl is not None:
                     rec = tl.record(
                         epoch, index,
-                        host_wait_ms=(t1 - t0) * 1000.0,
-                        step_ms=(t2 - t1) * 1000.0,
-                        metric_cb_ms=(time.perf_counter() - t2) * 1000.0,
+                        host_wait_ms=s_next.ns * 1e-6,
+                        dispatch_ms=s_forward.ns * 1e-6,
+                        metric_cb_ms=s_metric.ns * 1e-6,
                         loop="eval")
                     telemetry.log_event("eval_step", rec)
         if telemetry.enabled():
@@ -417,118 +418,128 @@ class BaseModule(object):
         ``PipelineStats.host_wait_ms`` — nonzero means the input
         path, not the device, paced the epoch."""
         assert num_epoch is not None, "please specify number of epochs"
+        from .. import telemetry
+        # the root span `fit` and this call's report: every phase below
+        # lands in it, and it is what `telemetry.last_fit()` returns
+        with telemetry.fit_scope() as report:
+            # u8 device-augment pipelines (mxnet_tpu.data.DeviceAugmentIter
+            # / CachedDataset / ImageRecordIter(device_augment="defer"))
+            # advertise their in-program augment spec; adopt it so the bind
+            # below compiles the augment stage into the step program and
+            # stages the 4x-smaller uint8 wire batches
+            aug_spec = getattr(train_data, "device_augment_spec", None)
+            if aug_spec and not self.binded and \
+                    getattr(self, "_device_augment", None) == {}:
+                self._device_augment = dict(aug_spec)
 
-        # u8 device-augment pipelines (mxnet_tpu.data.DeviceAugmentIter
-        # / CachedDataset / ImageRecordIter(device_augment="defer"))
-        # advertise their in-program augment spec; adopt it so the bind
-        # below compiles the augment stage into the step program and
-        # stages the 4x-smaller uint8 wire batches
-        aug_spec = getattr(train_data, "device_augment_spec", None)
-        if aug_spec and not self.binded and \
-                getattr(self, "_device_augment", None) == {}:
-            self._device_augment = dict(aug_spec)
+            self.bind(data_shapes=train_data.provide_data,
+                      label_shapes=train_data.provide_label,
+                      for_training=True, force_rebind=force_rebind)
+            if monitor is not None:
+                self.install_monitor(monitor)
+            self.init_params(initializer=initializer, arg_params=arg_params,
+                             aux_params=aux_params,
+                             allow_missing=allow_missing,
+                             force_init=force_init)
+            self.init_optimizer(kvstore=kvstore, optimizer=optimizer,
+                                optimizer_params=optimizer_params)
+            # never inherit a previous fit's mid-epoch skip marker: a resume
+            # whose target epoch was outside [begin_epoch, num_epoch) would
+            # otherwise leak it into a LATER fit and silently drop batches
+            self._resume_skip = None
+            if resume_from is not None:
+                begin_epoch = self._resume_from(resume_from, begin_epoch)
 
-        self.bind(data_shapes=train_data.provide_data,
-                  label_shapes=train_data.provide_label,
-                  for_training=True, force_rebind=force_rebind)
-        if monitor is not None:
-            self.install_monitor(monitor)
-        self.init_params(initializer=initializer, arg_params=arg_params,
-                         aux_params=aux_params, allow_missing=allow_missing,
-                         force_init=force_init)
-        self.init_optimizer(kvstore=kvstore, optimizer=optimizer,
-                            optimizer_params=optimizer_params)
-        # never inherit a previous fit's mid-epoch skip marker: a resume
-        # whose target epoch was outside [begin_epoch, num_epoch) would
-        # otherwise leak it into a LATER fit and silently drop batches
-        self._resume_skip = None
-        if resume_from is not None:
-            begin_epoch = self._resume_from(resume_from, begin_epoch)
+            from .. import guardian as guardian_mod
+            guardian = guardian_mod.resolve(guardian)
+            if guardian is not None and \
+                    not guardian.arm(self, begin_epoch):
+                guardian = None     # cannot carry the sentinel; unguarded
 
-        from .. import guardian as guardian_mod
-        guardian = guardian_mod.resolve(guardian)
-        if guardian is not None and \
-                not guardian.arm(self, begin_epoch):
-            guardian = None     # cannot carry the sentinel; unguarded
+            if validation_metric is None:
+                validation_metric = eval_metric
+            # materialize the validation metric ONCE for the whole fit: a
+            # string here used to reach score() every epoch, which created
+            # a FRESH metric object per eval pass — and a fresh metric
+            # means a fresh device-tally token, so every epoch's eval
+            # recompiled its fwd_eval_stat program (a per-epoch XLA compile
+            # the CompileWatch flagged as a post-warmup retrace the moment
+            # the introspection gate ran a multi-epoch eval fit)
+            validation_metric = metric_mod.create(validation_metric)
+            eval_metric = metric_mod.create(eval_metric)
+            # fused mesh modules accumulate the metric on device inside the
+            # train-step program (no per-batch readback; see
+            # MeshExecutorGroup.enable_device_metric). No-op elsewhere.
+            self._install_device_metric(eval_metric)
 
-        if validation_metric is None:
-            validation_metric = eval_metric
-        # materialize the validation metric ONCE for the whole fit: a
-        # string here used to reach score() every epoch, which created
-        # a FRESH metric object per eval pass — and a fresh metric
-        # means a fresh device-tally token, so every epoch's eval
-        # recompiled its fwd_eval_stat program (a per-epoch XLA compile
-        # the CompileWatch flagged as a post-warmup retrace the moment
-        # the introspection gate ran a multi-epoch eval fit)
-        validation_metric = metric_mod.create(validation_metric)
-        eval_metric = metric_mod.create(eval_metric)
-        # fused mesh modules accumulate the metric on device inside the
-        # train-step program (no per-batch readback; see
-        # MeshExecutorGroup.enable_device_metric). No-op elsewhere.
-        self._install_device_metric(eval_metric)
+            group_k = int(batch_group) if batch_group else 0
+            # monitor check is belt-and-braces: install_monitor already
+            # re-binds fused modules onto the classic group, which fails
+            # _fit_grouped_ready — but a grouped step has no per-batch
+            # boundaries for taps, so gate on it explicitly
+            if group_k > 1 and (monitor is not None or
+                                not self._fit_grouped_ready(eval_metric)):
+                self._warn_once(
+                    "fit_batch_group",
+                    "fit(batch_group=%d) needs the fused mesh path with a "
+                    "fusable optimizer and a device-talliable metric (and "
+                    "no monitor); falling back to per-batch training",
+                    group_k)
+                group_k = 0
 
-        group_k = int(batch_group) if batch_group else 0
-        # monitor check is belt-and-braces: install_monitor already
-        # re-binds fused modules onto the classic group, which fails
-        # _fit_grouped_ready — but a grouped step has no per-batch
-        # boundaries for taps, so gate on it explicitly
-        if group_k > 1 and (monitor is not None or
-                            not self._fit_grouped_ready(eval_metric)):
-            self._warn_once(
-                "fit_batch_group",
-                "fit(batch_group=%d) needs the fused mesh path with a "
-                "fusable optimizer and a device-talliable metric (and "
-                "no monitor); falling back to per-batch training",
-                group_k)
-            group_k = 0
+            loader = None
+            if prefetch_to_device:
+                # created AFTER bind: the loader reads the bound executor
+                # group's shardings so its background device_put lands each
+                # per-device shard exactly where _stage would
+                from ..data import DeviceLoader
+                depth = 2 if prefetch_to_device is True \
+                    else int(prefetch_to_device)
+                loader = DeviceLoader(
+                    train_data, module=self, depth=depth,
+                    batch_group=group_k if group_k > 1 else None)
+                train_data = loader
+            try:
+                self._fit_epochs(train_data, eval_data, eval_metric,
+                                 validation_metric, begin_epoch, num_epoch,
+                                 group_k, monitor, batch_end_callback,
+                                 epoch_end_callback, eval_end_callback,
+                                 eval_batch_end_callback, guardian, report)
+            finally:
+                if loader is not None:
+                    loader.close()
+                if guardian is not None:
+                    guardian.disarm()
 
-        loader = None
-        if prefetch_to_device:
-            # created AFTER bind: the loader reads the bound executor
-            # group's shardings so its background device_put lands each
-            # per-device shard exactly where _stage would
-            from ..data import DeviceLoader
-            depth = 2 if prefetch_to_device is True \
-                else int(prefetch_to_device)
-            loader = DeviceLoader(
-                train_data, module=self, depth=depth,
-                batch_group=group_k if group_k > 1 else None)
-            train_data = loader
-        try:
-            self._fit_epochs(train_data, eval_data, eval_metric,
-                             validation_metric, begin_epoch, num_epoch,
-                             group_k, monitor, batch_end_callback,
-                             epoch_end_callback, eval_end_callback,
-                             eval_batch_end_callback, guardian)
-        finally:
-            if loader is not None:
-                loader.close()
-            if guardian is not None:
-                guardian.disarm()
-
-        # dist_async trains with a staleness-1 in-flight reduction per key;
-        # quiesce so the final gradients are applied before fit returns
-        # (kvstore.push contract)
-        self._drain_async_kvstore()
+            # dist_async trains with a staleness-1 in-flight reduction per key;
+            # quiesce so the final gradients are applied before fit returns
+            # (kvstore.push contract)
+            self._drain_async_kvstore()
 
     def _fit_epochs(self, train_data, eval_data, eval_metric,
                     validation_metric, begin_epoch, num_epoch, group_k,
                     monitor, batch_end_callback, epoch_end_callback,
                     eval_end_callback, eval_batch_end_callback,
-                    guardian=None):
+                    guardian, report):
         """The epoch loop of ``fit`` (split out so the device-feed
         loader's lifetime can bracket it).
 
-        Telemetry (``mxnet_tpu.telemetry``): when enabled, every step
-        writes one :class:`StepTimeline` record (host-wait / step /
-        metric+callback / checkpoint clocks, recompile flag) and one
-        ``"step"`` JSONL line, a :class:`CompileWatch` attaches to the
-        executor group with the warmup boundary declared after the
+        Telemetry (``mxnet_tpu.telemetry``): the loop's phases are
+        ``telemetry.span``s that tile it (``fit.epoch`` holding
+        ``fit.next`` / ``fit.forward_backward`` / ``fit.update`` /
+        ``fit.metric``, then ``fit.epoch_end``), always: they lie in any
+        open profiler trace and fill ``report``, this call's
+        :class:`~mxnet_tpu.telemetry.FitReport`. When telemetry is
+        enabled, every step also writes one :class:`StepTimeline`
+        record from the same clock reads (host-wait / dispatch /
+        metric+callback / checkpoint, recompile flag) and one
+        ``"step"`` JSONL line, and a :class:`CompileWatch` attaches to
+        the executor group with the warmup boundary declared after the
         FIRST epoch of this fit (every steady shape — epoch tails, the
-        eval pass — has compiled by then), and the epoch is bracketed
-        in trace spans. The process RegressionWatchdog is armed at the
-        same warmup boundary (``MXNET_TELEMETRY_WATCHDOG=0`` opts out)
-        and polled between epochs — a steady-state slowdown, roofline
+        eval pass — has compiled by then). The process
+        RegressionWatchdog is armed at the same warmup boundary
+        (``MXNET_TELEMETRY_WATCHDOG=0`` opts out) and polled between
+        epochs — a steady-state slowdown, roofline
         drop, straggler or post-warmup retrace becomes ONE structured
         ``health.*`` incident. All clocks are host-side: no readback, no RNG
         touch, so trained params stay bitwise identical to a
@@ -553,7 +564,7 @@ class BaseModule(object):
                 begin_epoch, num_epoch, group_k, monitor,
                 batch_end_callback, epoch_end_callback, eval_end_callback,
                 eval_batch_end_callback, pipe_stats, wait_seen, tl, watch,
-                guardian)
+                guardian, report)
         except BaseException as exc:
             # crash black box: an exception escaping the train loop —
             # WorkerLost, preemption, a real bug — commits a postmortem
@@ -579,8 +590,9 @@ class BaseModule(object):
                           group_k, monitor, batch_end_callback,
                           epoch_end_callback, eval_end_callback,
                           eval_batch_end_callback, pipe_stats, wait_seen,
-                          tl, watch, guardian=None):
+                          tl, watch, guardian, report):
         from .. import telemetry
+        span = telemetry.span
         # live roofline state (telemetry.introspect): {"basis", "gauges"}
         # once the step program's FLOPs/bytes resolve at the warmup
         # boundary; empty before that (first epoch records carry no
@@ -613,11 +625,11 @@ class BaseModule(object):
             if guardian is not None:
                 guardian.begin_epoch(self, epoch)
             mid_verdict = None
-            with telemetry.span("fit.epoch", epoch=epoch):
+            with span("fit.epoch", epoch=epoch):
                 if group_k > 1:
                     mid_verdict = self._fit_epoch_grouped(
                         train_data, epoch, group_k, eval_metric,
-                        batch_end_callback, tl, watch,
+                        batch_end_callback, report, tl, watch,
                         skip=skip, roof=roof, guardian=guardian)
                 else:
                     nbatch = -1
@@ -635,9 +647,12 @@ class BaseModule(object):
                                 break
                             nbatch += 1
                     while True:
-                        t0 = time.perf_counter() if tl is not None else 0.0
+                        step = report.steps
                         try:
-                            data_batch = next(data_iter)
+                            # the pull that ends the epoch is clocked
+                            # like any other: its time is the epoch's
+                            with span("fit.next", step=step) as s_next:
+                                data_batch = next(data_iter)
                         except StopIteration:
                             break
                         nbatch += 1
@@ -651,21 +666,25 @@ class BaseModule(object):
                         if _faults.armed():
                             data_batch = _poison_batch_seam(
                                 data_batch, self, epoch, nbatch)
-                        t1 = time.perf_counter() if tl is not None else 0.0
                         n_traces = watch.count if watch is not None else 0
                         if monitor is not None:
                             monitor.tic()
-                        self.forward_backward(data_batch)
-                        self.update()
+                        with span("fit.forward_backward",
+                                  step=step) as s_fwd_bwd:
+                            self.forward_backward(data_batch)
+                        with span("fit.update", step=step) as s_update:
+                            self.update()
                         if guardian is not None:
                             guardian.note_step(epoch, nbatch)
-                        t2 = time.perf_counter() if tl is not None else 0.0
-                        self.update_metric(eval_metric, data_batch.label)
-                        if monitor is not None:
-                            monitor.toc_print()
+                        s_metric = span("fit.metric", step=step)
                         try:
-                            self._fire(batch_end_callback, epoch, nbatch,
-                                       eval_metric, locals())
+                            with s_metric:
+                                self.update_metric(eval_metric,
+                                                   data_batch.label)
+                                if monitor is not None:
+                                    monitor.toc_print()
+                                self._fire(batch_end_callback, epoch,
+                                           nbatch, eval_metric, locals())
                         finally:
                             # the record is written even when a callback
                             # raises (WorkerLost, preemption hooks): the
@@ -673,15 +692,12 @@ class BaseModule(object):
                             # it is the flight-recorder postmortem's
                             # last record
                             if tl is not None:
-                                rec = tl.record(
-                                    epoch, nbatch,
-                                    host_wait_ms=(t1 - t0) * 1000.0,
-                                    step_ms=(t2 - t1) * 1000.0,
-                                    metric_cb_ms=(time.perf_counter()
-                                                  - t2) * 1000.0,
-                                    recompile=watch.count > n_traces)
-                                self._roofline_note(rec, roof)
-                                telemetry.log_event("step", rec)
+                                self._timeline_step(
+                                    tl, roof, epoch, nbatch, s_next.ns,
+                                    s_fwd_bwd.ns + s_update.ns,
+                                    s_metric.ns, 1,
+                                    watch.count > n_traces)
+                        report.steps += 1
                         if guardian is not None:
                             # window-boundary poll (long epochs): a
                             # full ring since the last bracket is
@@ -691,127 +707,160 @@ class BaseModule(object):
                             if mid_verdict is not None:
                                 break
 
-            for name, val in eval_metric.get_name_value():
-                self.logger.info("Epoch[%d] Train-%s=%f", epoch, name, val)
-            cost = time.time() - tic
-            self.logger.info("Epoch[%d] Time cost=%.3f", epoch, cost)
-            if pipe_stats is not None:
-                # the epoch's slice of the cumulative host-wait clock:
-                # how long THIS epoch's steps sat blocked on the input
-                # path (0 = the device feed fully hid decode+transfer)
-                snap = pipe_stats.snapshot()
-                wait_ms = snap["host_wait_ms"] - wait_seen
-                wait_seen = snap["host_wait_ms"]
-                self.logger.info(
-                    "Epoch[%d] Host-wait=%.1fms (%.1f%% of epoch, "
-                    "ring high-water %d/%d)", epoch, wait_ms,
-                    100.0 * wait_ms / max(cost * 1000.0, 1e-9),
-                    snap["ring_high_water"], snap["ring_depth"])
+            # everything between two epochs: the metric drain (a
+            # readback that waits for every step), the guardian's poll,
+            # parameter sync, callbacks, eval and the iterator's reset
+            with span("fit.epoch_end", epoch=epoch):
+                for name, val in eval_metric.get_name_value():
+                    self.logger.info("Epoch[%d] Train-%s=%f", epoch, name,
+                                     val)
+                cost = time.time() - tic
+                self.logger.info("Epoch[%d] Time cost=%.3f", epoch, cost)
+                if pipe_stats is not None:
+                    # the epoch's slice of the cumulative host-wait
+                    # clock: how long THIS epoch's steps sat blocked on
+                    # the input path (0 = the device feed fully hid
+                    # decode+transfer)
+                    snap = pipe_stats.snapshot()
+                    wait_ms = snap["host_wait_ms"] - wait_seen
+                    wait_seen = snap["host_wait_ms"]
+                    self.logger.info(
+                        "Epoch[%d] Host-wait=%.1fms (%.1f%% of epoch, "
+                        "ring high-water %d/%d)", epoch, wait_ms,
+                        100.0 * wait_ms / max(cost * 1000.0, 1e-9),
+                        snap["ring_high_water"], snap["ring_depth"])
 
-            if guardian is not None:
-                # the off-path judgment pass, BEFORE the epoch-end
-                # callback: a poisoned epoch must neither checkpoint
-                # nor eval — rollback restores a pre-poison entry and
-                # re-enters the (possibly earlier) epoch with the
-                # convicted batch excluded from the replayed stream
-                verdict = mid_verdict if mid_verdict is not None \
-                    else guardian.poll(self, epoch)
-                if verdict is not None:
-                    epoch = guardian.rollback(self, verdict)
-                    train_data.reset()
-                    continue
+                if guardian is not None:
+                    # the off-path judgment pass, BEFORE the epoch-end
+                    # callback: a poisoned epoch must neither checkpoint
+                    # nor eval — rollback restores a pre-poison entry
+                    # and re-enters the (possibly earlier) epoch with
+                    # the convicted batch excluded from the replayed
+                    # stream
+                    verdict = mid_verdict if mid_verdict is not None \
+                        else guardian.poll(self, epoch)
+                    if verdict is not None:
+                        epoch = guardian.rollback(self, verdict)
+                        train_data.reset()
+                        continue
 
-            # classic modules keep the reference's unconditional epoch-end
-            # get_params+set_params (it is load-bearing: bucketing keeps
-            # sibling executors coherent through it); the fused Module
-            # overrides _epoch_end_sync to skip the ~1s packed readback
-            # when no callback consumes the params — its device params
-            # are the single authority, so nothing needs re-broadcast
-            params = self._epoch_end_sync(epoch_end_callback is not None)
-            if epoch_end_callback is not None:
-                t_cb = time.perf_counter() if tl is not None else 0.0
-                with telemetry.span("fit.epoch_end_callback", epoch=epoch):
-                    arg_params, aux_params = params
-                    for callback in _as_list(epoch_end_callback):
-                        callback(epoch, self.symbol, arg_params, aux_params)
+                # classic modules keep the reference's unconditional
+                # epoch-end get_params+set_params (it is load-bearing:
+                # bucketing keeps sibling executors coherent through
+                # it); the fused Module overrides _epoch_end_sync to
+                # skip the ~1s packed readback when no callback consumes
+                # the params — its device params are the single
+                # authority, so nothing needs re-broadcast
+                params = self._epoch_end_sync(
+                    epoch_end_callback is not None)
+                if epoch_end_callback is not None:
+                    with span("fit.epoch_end_callback",
+                              epoch=epoch) as s_callback:
+                        arg_params, aux_params = params
+                        for callback in _as_list(epoch_end_callback):
+                            callback(epoch, self.symbol, arg_params,
+                                     aux_params)
+                    if tl is not None:
+                        # checkpoint staging dominates this callback
+                        # slot; attributed to the step it actually
+                        # delayed. The epoch's step JSONL lines already
+                        # streamed, so the sink gets this as its own
+                        # event instead
+                        cb_ms = s_callback.ns * 1e-6
+                        tl.note_checkpoint(cb_ms)
+                        telemetry.log_event(
+                            "checkpoint",
+                            {"epoch": epoch,
+                             "checkpoint_ms": round(cb_ms, 3)})
+
+                if eval_data:
+                    with span("fit.eval", epoch=epoch):
+                        res = self.score(
+                            eval_data, validation_metric,
+                            score_end_callback=eval_end_callback,
+                            batch_end_callback=eval_batch_end_callback,
+                            epoch=epoch)
+                    for name, val in res:
+                        self.logger.info("Epoch[%d] Validation-%s=%f",
+                                         epoch, name, val)
+
+                train_data.reset()
                 if tl is not None:
-                    # checkpoint staging dominates this callback slot;
-                    # attributed to the step it actually delayed. The
-                    # epoch's step JSONL lines already streamed, so the
-                    # sink gets this as its own event instead
-                    cb_ms = (time.perf_counter() - t_cb) * 1000.0
-                    tl.note_checkpoint(cb_ms)
-                    telemetry.log_event(
-                        "checkpoint", {"epoch": epoch,
-                                       "checkpoint_ms": round(cb_ms, 3)})
+                    wd = self._telemetry_epoch_end(watch, roof, wd,
+                                                   warmed, epoch)
+                warmed = True
+                report.epochs += 1
+                epoch += 1
 
-            if eval_data:
-                with telemetry.span("fit.eval", epoch=epoch):
-                    res = self.score(
-                        eval_data, validation_metric,
-                        score_end_callback=eval_end_callback,
-                        batch_end_callback=eval_batch_end_callback,
-                        epoch=epoch)
-                for name, val in res:
-                    self.logger.info("Epoch[%d] Validation-%s=%f", epoch,
-                                     name, val)
-
-            train_data.reset()
-            if watch is not None and not warmed:
-                # every steady-state shape (epoch tails, grouped tail
-                # blocks, the eval pass) has now traced once: from here
-                # on a retrace is a performance bug worth a warning
-                watch.mark_warmup_done()
-            if tl is not None and not warmed:
-                # resolve the live-roofline basis at the warmup
-                # boundary: the step program has compiled and
-                # registered; its one-time analysis runs HERE, between
-                # epochs — never on the step path
-                self._resolve_roofline(roof)
-                if os.environ.get("MXNET_TELEMETRY_WATCHDOG",
-                                  "1") != "0":
-                    # arm the regression watchdog at the same boundary:
-                    # records from here on are steady state. Baseline
-                    # comes from a committed snapshot when pinned
-                    # (MXNET_TELEMETRY_BASELINE), else the first polled
-                    # window self-calibrates. Polls run between epochs
-                    # — host arithmetic only, never on the step path.
-                    # Diagnostics, never fit control (same rule as
-                    # _resolve_roofline): a bad baseline path must not
-                    # kill the training run at the epoch boundary.
-                    try:
-                        wd = telemetry.health_watchdog().arm(
-                            baseline=os.environ.get(
-                                "MXNET_TELEMETRY_BASELINE") or None)
-                    except Exception:  # noqa: BLE001
-                        self.logger.exception(
-                            "health watchdog failed to arm; "
-                            "continuing unwatched")
-                        wd = None
-            elif wd is not None:
+    def _telemetry_epoch_end(self, watch, roof, wd, warmed, epoch):
+        """What an enabled telemetry does between two epochs; returns
+        the regression watchdog (armed at the first healthy epoch's end,
+        polled at every later one)."""
+        from .. import telemetry
+        if not warmed:
+            # every steady-state shape (epoch tails, grouped tail
+            # blocks, the eval pass) has now traced once: from here
+            # on a retrace is a performance bug worth a warning
+            watch.mark_warmup_done()
+            # resolve the live-roofline basis at the warmup
+            # boundary: the step program has compiled and
+            # registered; its one-time analysis runs HERE, between
+            # epochs — never on the step path
+            self._resolve_roofline(roof)
+            if os.environ.get("MXNET_TELEMETRY_WATCHDOG", "1") != "0":
+                # arm the regression watchdog at the same boundary:
+                # records from here on are steady state. Baseline
+                # comes from a committed snapshot when pinned
+                # (MXNET_TELEMETRY_BASELINE), else the first polled
+                # window self-calibrates. Polls run between epochs
+                # — host arithmetic only, never on the step path.
+                # Diagnostics, never fit control (same rule as
+                # _resolve_roofline): a bad baseline path must not
+                # kill the training run at the epoch boundary.
                 try:
-                    wd.poll()
-                except Exception:  # noqa: BLE001 - diagnostics only
-                    self.logger.exception("health watchdog poll failed")
-            if tl is not None:
-                # loss-scaler skip decisions, polled off-path at the
-                # same boundary loss_scale() is read: a skip storm
-                # becomes a precision.scale_skips gauge the watchdog's
-                # absolute judge watches (one readback per epoch, only
-                # when a scaling policy is live)
-                skips = getattr(self._exec_group, "scale_skips",
-                                lambda: None)() \
-                    if getattr(self, "_exec_group", None) is not None \
-                    else None
-                if skips is not None:
-                    telemetry.registry().gauge(
-                        "precision.scale_skips").set(skips)
-                telemetry.flush_metrics("epoch %d" % epoch)
-            warmed = True
-            epoch += 1
+                    wd = telemetry.health_watchdog().arm(
+                        baseline=os.environ.get(
+                            "MXNET_TELEMETRY_BASELINE") or None)
+                except Exception:  # noqa: BLE001
+                    self.logger.exception(
+                        "health watchdog failed to arm; "
+                        "continuing unwatched")
+                    wd = None
+        elif wd is not None:
+            try:
+                wd.poll()
+            except Exception:  # noqa: BLE001 - diagnostics only
+                self.logger.exception("health watchdog poll failed")
+        # loss-scaler skip decisions, polled off-path at the
+        # same boundary loss_scale() is read: a skip storm
+        # becomes a precision.scale_skips gauge the watchdog's
+        # absolute judge watches (one readback per epoch, only
+        # when a scaling policy is live)
+        skips = getattr(self._exec_group, "scale_skips",
+                        lambda: None)() \
+            if getattr(self, "_exec_group", None) is not None \
+            else None
+        if skips is not None:
+            telemetry.registry().gauge(
+                "precision.scale_skips").set(skips)
+        telemetry.flush_metrics("epoch %d" % epoch)
+        return wd
+
+    def _timeline_step(self, tl, roof, epoch, nbatch, next_ns, dispatch_ns,
+                       metric_ns, batch_group, recompile):
+        """One step's (or group's) :class:`StepTimeline` record and
+        ``"step"`` JSONL line, from the clock reads of the loop's
+        spans."""
+        from .. import telemetry
+        rec = tl.record(epoch, nbatch, host_wait_ms=next_ns * 1e-6,
+                        dispatch_ms=dispatch_ns * 1e-6,
+                        metric_cb_ms=metric_ns * 1e-6,
+                        batch_group=batch_group, recompile=recompile)
+        self._roofline_note(rec, roof)
+        telemetry.log_event("step", rec)
 
     def _fit_epoch_grouped(self, train_data, epoch, group_k, eval_metric,
-                           batch_end_callback, tl=None, watch=None,
+                           batch_end_callback, report, tl=None, watch=None,
                            skip=0, roof=None, guardian=None):
         """One epoch of K-batches-per-program training (``fit``'s
         ``batch_group`` path).  Assembly of block N+1 runs on the host
@@ -822,18 +871,24 @@ class BaseModule(object):
         group; a batch whose shapes disagree with the open group also
         flushes first (bucketed iterators).
 
+        The spans carry the per-batch loop's names around the same
+        work, so ``report`` has the same shape: the K pulls are K
+        ``fit.next``, assembling and staging the block is
+        ``fit.forward_backward``, the scanned launch ``fit.update``.
         With telemetry enabled (``tl`` = the StepTimeline, ``watch`` =
         the CompileWatch) each GROUP writes one step record: the K
-        iterator pulls' accumulated host-wait, the scanned launch's
-        dispatch time, and ``batch_group`` = the group's true size."""
+        iterator pulls' accumulated host-wait, the staging and the
+        scanned launch's dispatch time, and ``batch_group`` = the
+        group's true size."""
         from .. import telemetry
+        span = telemetry.span
         group = []
         group_nbatches = []   # each member's nbatch (skips make gaps)
         nbatch = -1
-        wait_s = [0.0]  # host-wait accumulated across the open group
+        wait_ns = [0]   # host-wait accumulated across the open group
 
         def _flush(last_nbatch, caller_locals):
-            t1 = time.perf_counter() if tl is not None else 0.0
+            step = report.steps
             n_traces = watch.count if watch is not None else 0
             group_n = len(group)
             if guardian is not None:
@@ -841,39 +896,48 @@ class BaseModule(object):
                 # scanned program counts each of the K steps
                 for nb in group_nbatches:
                     guardian.note_step(epoch, nb)
-            if self._grouped_step(group):
-                # the group's K statistics are already in the device
-                # tally; this consumes the step-done flag like the
-                # per-batch loop's update_metric does
-                t2 = time.perf_counter() if tl is not None else 0.0
-                self.update_metric(eval_metric, group[-1].label)
-            else:
+            with span("fit.forward_backward", step=step) as s_fwd_bwd:
+                staged = self._grouped_stage(group)
+            dispatch_ns = s_fwd_bwd.ns
+            stepped = False
+            if staged is not None:
+                with span("fit.update", step=step) as s_update:
+                    stepped = self._grouped_update(staged)
+                dispatch_ns += s_update.ns
+            if not stepped:
                 # gate said grouped was possible but the step declined
                 # (e.g. optimizer swapped mid-fit): keep exact semantics
                 # by training this group per batch
                 for b in group:
-                    self.forward_backward(b)
-                    self.update()
-                    self.update_metric(eval_metric, b.label)
-                t2 = time.perf_counter() if tl is not None else 0.0
+                    with span("fit.forward_backward",
+                              step=step) as s_fwd_bwd:
+                        self.forward_backward(b)
+                    with span("fit.update", step=step) as s_update:
+                        self.update()
+                    dispatch_ns += s_fwd_bwd.ns + s_update.ns
+                    with span("fit.metric", step=step):
+                        self.update_metric(eval_metric, b.label)
+            s_metric = span("fit.metric", step=step)
             try:
-                self._fire(batch_end_callback, epoch, last_nbatch,
-                           eval_metric, caller_locals)
+                with s_metric:
+                    if stepped:
+                        # the group's K statistics are already in the
+                        # device tally; this consumes the step-done flag
+                        # like the per-batch loop's update_metric does
+                        self.update_metric(eval_metric, group[-1].label)
+                    self._fire(batch_end_callback, epoch, last_nbatch,
+                               eval_metric, caller_locals)
             finally:
                 # record even on a raising callback — the failing
                 # group must be the postmortem's last record (same
                 # contract as the per-batch loop)
                 if tl is not None:
-                    rec = tl.record(
-                        epoch, last_nbatch,
-                        host_wait_ms=wait_s[0] * 1000.0,
-                        step_ms=(t2 - t1) * 1000.0,
-                        metric_cb_ms=(time.perf_counter() - t2) * 1000.0,
-                        batch_group=group_n,
-                        recompile=watch.count > n_traces)
-                    self._roofline_note(rec, roof)
-                    telemetry.log_event("step", rec)
-            wait_s[0] = 0.0
+                    self._timeline_step(
+                        tl, roof, epoch, last_nbatch, wait_ns[0],
+                        dispatch_ns, s_metric.ns, group_n,
+                        watch.count > n_traces)
+            report.steps += group_n
+            wait_ns[0] = 0
             del group[:]
             del group_nbatches[:]
 
@@ -899,9 +963,10 @@ class BaseModule(object):
                     break
                 nbatch += 1
         while True:
-            t0 = time.perf_counter() if tl is not None else 0.0
             try:
-                data_batch = next(data_iter)
+                with span("fit.next",
+                          step=report.steps + len(group)) as s_next:
+                    data_batch = next(data_iter)
             except StopIteration:
                 break
             nbatch += 1
@@ -914,8 +979,7 @@ class BaseModule(object):
             if _faults.armed():
                 data_batch = _poison_batch_seam(data_batch, self, epoch,
                                                 nbatch)
-            if tl is not None:
-                wait_s[0] += time.perf_counter() - t0
+            wait_ns[0] += s_next.ns
             sig = _shape_sig(data_batch)
             if group and sig != open_sig:
                 _flush(nbatch - 1, locals())
@@ -1001,10 +1065,16 @@ class BaseModule(object):
         Default: no — the fused mesh Module overrides."""
         return False
 
-    def _grouped_step(self, batches):
-        """Train one K-batch group as a single staged+scanned device
-        program.  Returns True when handled; the default declines and
-        the caller falls back to per-batch steps."""
+    def _grouped_stage(self, batches):
+        """First half of a grouped step: assemble K batches into one
+        block per input and stage it on the device.  Returns what
+        :meth:`_grouped_update` takes, or None to decline (the default),
+        and the caller falls back to per-batch steps."""
+        return None
+
+    def _grouped_update(self, staged):
+        """Second half: train the staged block as ONE scanned device
+        program.  Returns True when handled."""
         return False
 
     def _resume_from(self, resume_from, begin_epoch):

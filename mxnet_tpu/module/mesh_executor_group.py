@@ -1278,30 +1278,34 @@ class MeshExecutorGroup(object):
         replicated global batch — into the global array with
         ``make_array_from_process_local_data``, so the compiled global
         program runs unchanged across hosts."""
-        from ..dist.staging import stage_sharded
+        import jax
+        from .. import telemetry
+        from ..dist.staging import stage_sharded, stage_zeros
 
         def put(arr):
             val = arr._read() if hasattr(arr, "_read") else arr
+            if not isinstance(val, jax.Array):
+                telemetry.count("input.h2d_bytes", val.nbytes)
             return stage_sharded(
                 val, self._batch_sharding,
                 (self.batch_size,) + tuple(val.shape[1:]))
 
-        inputs = {}
-        data_names = [x[0] for x in self.data_shapes]
-        for name, arr in zip(data_names, batch.data):
-            inputs[name] = put(arr)
-        if self.label_shapes and batch.label:
-            for name, arr in zip(self._label_names, batch.label):
-                if arr is not None:
-                    inputs[name] = put(arr)
-        inputs = self._apply_device_augment(inputs, is_train)
-        from ..dist.staging import stage_zeros
-        bs = next(iter(inputs.values())).shape[0]
-        for name in self._nonparam_names:
-            if name not in inputs:
-                inputs[name] = stage_zeros(
-                    (bs,) + tuple(self._shape_of[name][1:]),
-                    self._batch_sharding)
+        with telemetry.span("exec.stage"):
+            inputs = {}
+            data_names = [x[0] for x in self.data_shapes]
+            for name, arr in zip(data_names, batch.data):
+                inputs[name] = put(arr)
+            if self.label_shapes and batch.label:
+                for name, arr in zip(self._label_names, batch.label):
+                    if arr is not None:
+                        inputs[name] = put(arr)
+            inputs = self._apply_device_augment(inputs, is_train)
+            bs = next(iter(inputs.values())).shape[0]
+            for name in self._nonparam_names:
+                if name not in inputs:
+                    inputs[name] = stage_zeros(
+                        (bs,) + tuple(self._shape_of[name][1:]),
+                        self._batch_sharding)
         return inputs
 
     def _stacked_sharding(self, sharding=None):
@@ -1326,24 +1330,29 @@ class MeshExecutorGroup(object):
         per-batch staging (single-process: plain ``device_put``;
         multi-process: per-process ``(K, B/R, ...)`` blocks assemble
         into the global ``(K, B, ...)`` array)."""
-        from ..dist.staging import stage_sharded
+        import jax
+        from .. import telemetry
+        from ..dist.staging import stage_sharded, stage_zeros
         st_batch = self._stacked_sharding()
-        inputs = {}
-        K = None
-        for name, arr in stacked_data.items():
-            arr = arr._read() if isinstance(arr, nd.NDArray) else arr
-            K = arr.shape[0]
-            inputs[name] = stage_sharded(
-                arr, st_batch,
-                (K, self.batch_size) + tuple(arr.shape[2:]))
-        inputs = self._apply_device_augment(inputs, is_train,
-                                            grouped=True)
-        from ..dist.staging import stage_zeros
-        bs = next(iter(inputs.values())).shape[1]
-        for name in self._nonparam_names:
-            if name not in inputs:
-                inputs[name] = stage_zeros(
-                    (K, bs) + tuple(self._shape_of[name][1:]), st_batch)
+        with telemetry.span("exec.stage"):
+            inputs = {}
+            K = None
+            for name, arr in stacked_data.items():
+                arr = arr._read() if isinstance(arr, nd.NDArray) else arr
+                K = arr.shape[0]
+                if not isinstance(arr, jax.Array):
+                    telemetry.count("input.h2d_bytes", arr.nbytes)
+                inputs[name] = stage_sharded(
+                    arr, st_batch,
+                    (K, self.batch_size) + tuple(arr.shape[2:]))
+            inputs = self._apply_device_augment(inputs, is_train,
+                                                grouped=True)
+            bs = next(iter(inputs.values())).shape[1]
+            for name in self._nonparam_names:
+                if name not in inputs:
+                    inputs[name] = stage_zeros(
+                        (K, bs) + tuple(self._shape_of[name][1:]),
+                        st_batch)
         return inputs
 
     def score_stacked(self, stacked_data):
@@ -1742,11 +1751,12 @@ class MeshExecutorGroup(object):
         args = args + self._step_extras()
         # aval skeleton for diagnostics (bench cost analysis) — the real
         # buffers are donated below and unusable afterwards
-        from ..telemetry import aval_skeleton
-        self._last_step = (fn, aval_skeleton(args))
+        from .. import telemetry
+        self._last_step = (fn, telemetry.aval_skeleton(args))
         self._note_program(kind, fn, args)
         self._note_optimizer_analytic(states, triples)
-        out = self._launch_step_program(kind, fn, args)
+        with telemetry.span("exec.launch"):
+            out = self._launch_step_program(kind, fn, args)
         outs, new_aux, grads, new_params, new_states = \
             self._commit_step_extras(out)
         self._write_outs(outs)
@@ -1760,16 +1770,18 @@ class MeshExecutorGroup(object):
         self._outputs_from = "bwd"
         return True
 
-    def step_update_grouped(self, updater, stacked_data, num_device=1):
+    def step_update_grouped(self, updater, inputs, num_device=1):
         """Run K whole train steps — fwd+bwd+optimizer (+metric tally) —
         as ONE XLA program over a ``(K, batch, ...)`` stacked block.
 
-        ``stacked_data``: dict input name -> (K, batch, ...) host or
-        device block; it is staged with ONE ``device_put`` per input
-        (``stage_stacked``), so the fixed per-transfer cost is paid
-        once per K steps instead of once per step.  The lr-scheduler clock advances K
-        times on the HOST before launch — each scanned step consumes
-        its own true-``num_update`` lr row, so schedules that change
+        ``inputs``: what :meth:`stage_stacked` returned for the block —
+        dict input name -> (K, batch, ...) array on the mesh, staged
+        with ONE ``device_put`` per input, so the fixed per-transfer
+        cost is paid once per K steps instead of once per step (``fit``
+        stages in its ``fit.forward_backward`` phase and launches in
+        ``fit.update``, like a per-batch step).  The lr-scheduler clock
+        advances K times on the HOST before launch — each scanned step
+        consumes its own true-``num_update`` lr row, so schedules that change
         mid-group (and Adam's per-step bias correction) match K
         sequential steps exactly.  Updater states / counters end up
         exactly as K ``step_update`` calls would leave them.
@@ -1783,13 +1795,12 @@ class MeshExecutorGroup(object):
         fa = updater.fused_apply_or_none()
         if fa is None:
             return False
-        import jax
         import numpy as np
+        from .. import telemetry
 
         # a still-deferred per-batch step must run before its params are
         # superseded (same contract as forward())
         self._materialize_backward()
-        inputs = self.stage_stacked(stacked_data)
         K = next(iter(inputs.values())).shape[0]
 
         triples = []
@@ -1836,7 +1847,8 @@ class MeshExecutorGroup(object):
         args = args + self._step_extras()
         self._note_program(kind, fn, args, extra={"batch_group": K})
         self._note_optimizer_analytic(states, triples)
-        out = self._launch_step_program(kind, fn, args)
+        with telemetry.span("exec.launch"):
+            out = self._launch_step_program(kind, fn, args)
         outs, new_aux, grads, new_params, new_states = \
             self._commit_step_extras(out)
         self._write_outs(outs)

@@ -917,29 +917,34 @@ class Module(BaseModule):
             return False
         return grp._metric_live is eval_metric
 
-    def _grouped_step(self, batches):
+    def _grouped_stage(self, batches):
         """Assemble K iterator batches into one stacked block per input
-        and run them as ONE scanned train-step program (the
-        iterations-per-loop pattern; see ``MeshExecutorGroup
-        .step_update_grouped``).  Host batches stack into one contiguous
-        block (ONE ``device_put`` per input); device-resident batches
-        stack on device — neither path pays a readback."""
+        and stage it on the mesh (``MeshExecutorGroup.stage_stacked``).
+        Host batches stack into one contiguous block (ONE
+        ``device_put`` per input); device-resident batches stack on
+        device — neither path pays a readback."""
         grp = self._exec_group
         if not getattr(grp, "fused", False):
-            return False
+            return None
         assert self.binded and self.params_initialized and \
             self.optimizer_initialized
-        # grouped steps bypass forward(); a stale eval-tail pad marker
-        # would make update_metric slice-and-host-update instead of
-        # consuming the device tally's step-done flag
-        self._eval_pad_extra = 0
         stacked = self._staged_group_block(batches)
         if stacked is None:
             stacked = stack_group_inputs(
                 batches, [d[0] for d in grp.data_shapes],
                 getattr(grp, "_label_names", []))
-        if not grp.step_update_grouped(self._updater, stacked,
-                                       num_device=self._num_update_blocks):
+        return grp.stage_stacked(stacked)
+
+    def _grouped_update(self, staged):
+        """Run the staged block's K steps as ONE scanned train-step
+        program (the iterations-per-loop pattern; see
+        ``MeshExecutorGroup.step_update_grouped``)."""
+        # grouped steps bypass forward(); a stale eval-tail pad marker
+        # would make update_metric slice-and-host-update instead of
+        # consuming the device tally's step-done flag
+        self._eval_pad_extra = 0
+        if not self._exec_group.step_update_grouped(
+                self._updater, staged, num_device=self._num_update_blocks):
             return False
         self._params_dirty = True
         return True
@@ -1006,11 +1011,8 @@ class Module(BaseModule):
             eval_metric = metric_mod.create(eval_metric)
             if reset:
                 eval_data.reset()
-            import time as _time
-
             from .. import telemetry
-            t0 = _time.perf_counter()
-            with telemetry.span("score.device", epoch=epoch):
+            with telemetry.span("score.device", epoch=epoch) as s_score:
                 result = grp.score_device(eval_data, eval_metric,
                                           num_batch)
             if result is not None:
@@ -1022,7 +1024,7 @@ class Module(BaseModule):
                     # the health watchdog on this path too
                     rec = telemetry.timeline().record(
                         epoch, seen - 1,
-                        step_ms=(_time.perf_counter() - t0) * 1000.0,
+                        dispatch_ms=s_score.ns * 1e-6,
                         batch_group=seen, loop="eval")
                     telemetry.log_event("eval_step", rec)
                 self._fire(score_end_callback, epoch, seen, eval_metric,

@@ -5,20 +5,19 @@ trace JSON. TPU-natively, per-op timing lives in the XLA/TPU runtime: we
 bridge to ``jax.profiler`` (XPlane traces, viewable in TensorBoard/Perfetto)
 while preserving the reference API (profiler_set_config / set_state /
 dump_profile) and emitting a Chrome-trace JSON of host-side step events.
+While the state is 'run' every ``telemetry.span`` of the program lies in
+that XPlane trace as an ``mx.*`` event, on the device's clock.
 """
 from __future__ import annotations
 
 import json
 import os
-import time
 import threading
 
-__all__ = ["profiler_set_config", "profiler_set_state", "dump_profile",
-           "Scope"]
+__all__ = ["profiler_set_config", "profiler_set_state", "dump_profile"]
 
 _config = {"mode": "symbolic", "filename": "profile.json"}
 _state = "stop"
-_events = []
 _lock = threading.Lock()
 _jax_tracing = False
 _ran_undumped = False  # profiling ran but no dump written yet
@@ -86,52 +85,16 @@ def profiler_set_state(state="stop"):
                 pass
 
 
-def record_event(name, begin_us, end_us, pid=0, tid=None):
-    """Append one duration event (engine's AddOprStat equivalent).
-
-    Emitted as ONE complete event (``"ph": "X"`` with a ``dur``) keyed
-    by the REAL recording thread id. The old encoding — unpaired
-    ``"B"``/``"E"`` pairs stamped with ``tid=pid`` — collapsed every
-    scope onto one track, so nested scopes from different threads
-    interleaved their begin/end markers and Perfetto rendered garbage
-    nesting; complete events carry their own extent, so per-thread
-    containment of ``(ts, dur)`` intervals is unambiguous."""
-    global _ran_undumped
-    if _state != "run":
-        return
-    _ran_undumped = True
-    if tid is None:
-        tid = threading.get_ident()
-    with _lock:
-        _events.append({"name": name, "cat": "operator", "ph": "X",
-                        "ts": begin_us, "dur": max(0.0, end_us - begin_us),
-                        "pid": pid, "tid": tid})
-
-
-class Scope(object):
-    """Context manager timing a named region into the trace."""
-
-    def __init__(self, name, pid=0):
-        self.name = name
-        self.pid = pid
-
-    def __enter__(self):
-        self.begin = time.time() * 1e6
-        return self
-
-    def __exit__(self, *args):
-        record_event(self.name, self.begin, time.time() * 1e6, self.pid)
-
-
 _native_events = []  # drained from the engine, kept so dumps stay cumulative
 
 
 def dump_profile():
-    """Write accumulated events as Chrome tracing JSON (MXDumpProfile),
-    merging the native engine's per-op stamps (OprExecStat equivalents)
-    AND the telemetry span ring (``mxnet_tpu.telemetry.span``), so one
-    file carries the whole host-side timeline. Callable repeatedly —
-    every event source accumulates across dumps."""
+    """Write accumulated events as Chrome tracing JSON (MXDumpProfile):
+    the native engine's per-op stamps (OprExecStat equivalents) and the
+    telemetry span ring (``mxnet_tpu.telemetry.span``, filled while
+    telemetry is enabled), so one file carries the whole host-side
+    timeline. Callable repeatedly — every event source accumulates
+    across dumps."""
     from . import engine as _engine
     eng = _engine.get()
     # "symbolic" mode never emits per-op stamps — skip the temp-file
@@ -150,15 +113,14 @@ def dump_profile():
         finally:
             os.unlink(path)
     with _lock:
-        events = list(_events)
+        events = []
         # "symbolic" mode (MXNET_PROFILER_MODE=0, the reference default)
         # reports executor/step regions only; "all" adds the engine's
         # per-imperative-op stamps (profiler.h:63-66 mode semantics)
         if _config.get("mode") == "all":
             events += list(_native_events)
-        # telemetry spans share the wall clock (time.time() * 1e6), so
-        # host spans, engine op stamps, and the jax.profiler XPlane
-        # trace line up on one timeline in Perfetto
+        # the ring's stamps are wall-clock microseconds, like the
+        # engine's; the XPlane trace holds the same spans itself
         from . import telemetry as _telemetry
         events += _telemetry.trace_events()
         data = {"traceEvents": events, "displayTimeUnit": "ms"}

@@ -5,7 +5,9 @@ Everything the stack measures — serving counters, pipeline host-wait,
 checkpoint durations, train-step timings, XLA retraces — records into
 ONE process-wide :class:`MetricsRegistry`, exportable as an append-only
 JSONL event log and a Prometheus ``/metrics`` endpoint; host spans
-merge into the profiler's Chrome trace; the :class:`StepTimeline`
+(:func:`span`, the one primitive) lie in the ``jax.profiler`` trace on
+the device's clock, feed every ``fit`` call's :func:`last_fit` report
+and merge into the profiler's Chrome trace; the :class:`StepTimeline`
 answers "why was step 412 slow" after the fact; the
 :class:`CompileWatch` attributes every XLA retrace to a call site and
 warns when one lands after the warmup boundary.
@@ -33,8 +35,10 @@ The contracts (ci.sh-gated, pinned by tests/test_telemetry.py):
 
 * **zero-perturbation** — a telemetry-on ``fit`` trains to
   bitwise-identical params (host clocks only: no readback, no RNG);
-* **disabled-mode cost** — one branch per call site
-  (``telemetry.enabled()`` / a shared no-op span);
+* **disabled-mode cost** — one branch per recording call site
+  (``telemetry.enabled()``); a span still reaches the profiler and the
+  fit report (``telemetry.last_fit()``), about two microseconds each: no
+  ring event, no timeline record, no sink write;
 * **post-warmup silence** — the steady-state train loop performs zero
   XLA retraces (``compile.post_warmup_retraces`` stays 0).
 
@@ -57,12 +61,15 @@ from .registry import (Counter, Gauge, Histogram, MetricsRegistry, Scope,
                        instrument_value, DEFAULT_MS_BUCKETS)
 from .slo import SLOTracker
 from .timeline import StepTimeline
-from .tracing import (NOOP_SPAN, Span, clear_trace, record_events, span,
+from . import tracing as _tracing
+from .tracing import (FitReport, Span, clear_trace, count, enabled,
+                      fit_scope, last_fit, record_events, span,
                       trace_events)
 
 __all__ = [
     "MetricsRegistry", "Counter", "Gauge", "Histogram", "Scope",
     "instrument_value", "StepTimeline", "CompileWatch", "Span", "span",
+    "count", "FitReport", "fit_scope", "last_fit",
     "JsonlSink", "MetricsServer", "render_prometheus",
     "ProgramInventory", "FlightRecorder", "load_postmortem",
     "analyze_compiled",
@@ -83,8 +90,7 @@ _INVENTORY = None
 _FLIGHT = None
 _WATCHDOG = None
 _lock = threading.Lock()
-_state = {"enabled": False, "sink": None, "server": None,
-          "active_pipeline": None}
+_state = {"sink": None, "server": None, "active_pipeline": None}
 
 
 def registry():
@@ -154,18 +160,12 @@ def health_report():
     return health_watchdog().report()
 
 
-def enabled():
-    """Whether telemetry recording (spans, step timeline, compile
-    watch, JSONL) is on — THE one branch disabled mode costs."""
-    return _state["enabled"]
-
-
 def enable(jsonl=None, port=None):
     """Turn telemetry recording on. ``jsonl=`` opens an append-only
     event-log sink; ``port=`` serves the Prometheus endpoint (0 picks a
     free port). Idempotent; reconfigures sink/server when given."""
     with _lock:
-        _state["enabled"] = True
+        _tracing.set_enabled(True)
         if jsonl is not None:
             old = _state["sink"]
             if old is not None and old.path != str(jsonl):
@@ -182,7 +182,7 @@ def disable():
     """Turn recording off and release the sink/endpoint. Instruments
     and retained timeline records stay readable."""
     with _lock:
-        _state["enabled"] = False
+        _tracing.set_enabled(False)
         sink, _state["sink"] = _state["sink"], None
         server, _state["server"] = _state["server"], None
     if sink is not None:

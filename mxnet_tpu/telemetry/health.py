@@ -20,13 +20,13 @@ incident per distinct regression.
   (:meth:`start`). Pure host arithmetic over retained records: the
   zero-perturbation contract is untouched. Watched signals:
 
-  - ``step_total_ms`` / ``step_ms`` — median per-batch step time
+  - ``step_total_ms`` / ``dispatch_ms`` — median per-batch step time
     (grouped records normalize by their true K);
   - ``host_wait_fraction`` — the input path's share of the step;
   - ``train.mfu`` / ``achieved_hbm_gbps`` — the live roofline fields
     stamped into post-warmup records (skipped when the peak table
     doesn't know the device — CPU CI never false-fires on MFU);
-  - ``eval_step_ms`` — the eval/score loop's records (``loop="eval"``),
+  - ``eval_dispatch_ms`` — the eval/score loop's records (``loop="eval"``),
     so a served/eval regression trips the same wire;
   - ``compile.post_warmup_retraces`` — any value > 0 is an incident;
   - ``dist.straggler_ratio`` — a straggling host past the threshold;
@@ -59,9 +59,9 @@ __all__ = ["RegressionWatchdog"]
 # check priority: when one poll finds several co-moving regressions
 # (a transform sleep raises host-wait AND total), the FIRST key below
 # becomes THE incident and the rest ride in its "also" list
-_PRIORITY = ("compile.post_warmup_retraces", "step_total_ms", "step_ms",
+_PRIORITY = ("compile.post_warmup_retraces", "step_total_ms", "dispatch_ms",
              "host_wait_fraction", "train.mfu",
-             "train.achieved_hbm_gbps", "eval_step_ms",
+             "train.achieved_hbm_gbps", "eval_dispatch_ms",
              "dist.straggler_ratio", "precision.scale_skips")
 
 
@@ -221,8 +221,8 @@ class RegressionWatchdog(object):
         out = {
             "step_total_ms": _median(
                 [r["total_ms"] / k for r, k in zip(train, ks)]),
-            "step_ms": _median(
-                [r["step_ms"] / k for r, k in zip(train, ks)]),
+            "dispatch_ms": _median(
+                [r["dispatch_ms"] / k for r, k in zip(train, ks)]),
             "host_wait_fraction": _median(
                 [r["host_wait_ms"] / max(r["total_ms"], 1e-9)
                  for r in train]),
@@ -240,8 +240,8 @@ class RegressionWatchdog(object):
     @staticmethod
     def _eval_stats(evals):
         return {
-            "eval_step_ms": _median(
-                [r["step_ms"] / max(int(r.get("batch_group", 1)), 1)
+            "eval_dispatch_ms": _median(
+                [r["dispatch_ms"] / max(int(r.get("batch_group", 1)), 1)
                  for r in evals]),
             "n_eval": len(evals),
         }
@@ -264,8 +264,8 @@ class RegressionWatchdog(object):
                                   b * (1.0 + self.tolerance), 3)}
 
         _slower("step_total_ms")
-        _slower("step_ms")
-        _slower("eval_step_ms")
+        _slower("dispatch_ms")
+        _slower("eval_dispatch_ms")
         b, v = base.get("host_wait_fraction"), \
             window.get("host_wait_fraction")
         if b is not None and v is not None and \

@@ -1,8 +1,9 @@
 """StepTimeline — a bounded per-step record of where fit's time went.
 
 One record per train step (per GROUP with ``batch_group=K``), written
-by the ``Module.fit`` loop from pure host clocks — no device readback,
-no RNG touch, so a telemetry-on run trains to bitwise-identical params
+by the ``Module.fit`` loop from the clock reads of its
+``telemetry.span`` phases — host clocks only, no device readback, no
+RNG touch, so a telemetry-on run trains to bitwise-identical params
 (the zero-perturbation contract, ci.sh-gated).
 
 Record fields (also the docs/api/telemetry.md field table):
@@ -12,11 +13,13 @@ Record fields (also the docs/api/telemetry.md field table):
   the last batch of the group on the grouped path).
 * ``host_wait_ms`` — time blocked pulling this step's batch from the
   iterator (the input path's share of the step).
-* ``step_ms`` — host-observed forward+backward+update time: dispatch
-  plus any blocking the async step imposes. On an async device this is
-  the device-compute view WITHOUT forcing a sync; a sudden jump means
-  the host caught up with the device (or a recompile — see the flag).
-* ``metric_cb_ms`` — update_metric + batch_end_callback time.
+* ``dispatch_ms`` — host time of the ``fit.forward_backward`` and
+  ``fit.update`` spans: staging the batch and ENQUEUEING the step (plus
+  any blocking the async device imposes), not the step's device time.
+  A sudden jump means the host caught up with the device (or a
+  recompile — see the flag).
+* ``metric_cb_ms`` — update_metric + batch_end_callback time (the
+  ``fit.metric`` span).
 * ``checkpoint_ms`` — epoch-end checkpoint staging time, attributed to
   the epoch's last step record (0 elsewhere). The streamed JSONL step
   lines are written BEFORE this fold, so the sink carries the cost as
@@ -60,7 +63,7 @@ class StepTimeline(object):
         self._lock = threading.Lock()
         self._next_step = 0
 
-    def record(self, epoch, nbatch, host_wait_ms=0.0, step_ms=0.0,
+    def record(self, epoch, nbatch, host_wait_ms=0.0, dispatch_ms=0.0,
                metric_cb_ms=0.0, checkpoint_ms=0.0, batch_group=1,
                recompile=False, loop="train"):
         """Append one step record; returns the record dict."""
@@ -71,12 +74,12 @@ class StepTimeline(object):
                 "step": step, "epoch": int(epoch), "nbatch": int(nbatch),
                 "loop": str(loop),
                 "host_wait_ms": round(float(host_wait_ms), 3),
-                "step_ms": round(float(step_ms), 3),
+                "dispatch_ms": round(float(dispatch_ms), 3),
                 "metric_cb_ms": round(float(metric_cb_ms), 3),
                 "checkpoint_ms": round(float(checkpoint_ms), 3),
                 "batch_group": int(batch_group),
                 "recompile": bool(recompile),
-                "total_ms": round(float(host_wait_ms) + float(step_ms)
+                "total_ms": round(float(host_wait_ms) + float(dispatch_ms)
                                   + float(metric_cb_ms)
                                   + float(checkpoint_ms), 3),
                 "ts": round(time.time(), 6),

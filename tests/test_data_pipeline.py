@@ -228,7 +228,7 @@ def test_device_loader_reset_and_shutdown_mid_epoch():
 def test_device_loader_grouped_blocks_via_stage_stacked():
     """batch_group=K: the stager stages ONE (K, B, ...) block per K
     batches through the group's stage_stacked (stacked sharding) and
-    the delivered views carry the block — Module._grouped_step's fast
+    the delivered views carry the block — Module._grouped_stage's fast
     path hands it straight to the scanned program.  The epoch tail
     forms its own smaller block."""
     X, y = _data()

@@ -93,8 +93,8 @@ def test_grouped_step_matches_sequential_sgd_adam():
             seq.update()
         grp = _module(ctxs, opt, kw)
         eg = grp._exec_group
-        assert eg.step_update_grouped(grp._updater,
-                                      _stack_batches(batches))
+        assert eg.step_update_grouped(
+            grp._updater, eg.stage_stacked(_stack_batches(batches)))
         _assert_same_training_state(seq, grp)
         # the group's exposed outputs/grads are the LAST step's — same
         # buffers K sequential steps would leave behind
@@ -154,8 +154,9 @@ def test_grouped_lr_schedule_changes_mid_group():
         seq.forward_backward(b)
         seq.update()
     grp = _module(ctxs, "sgd", kw())
-    assert grp._exec_group.step_update_grouped(grp._updater,
-                                               _stack_batches(batches))
+    eg = grp._exec_group
+    assert eg.step_update_grouped(
+        grp._updater, eg.stage_stacked(_stack_batches(batches)))
     _assert_same_training_state(seq, grp)
     # both clocks advanced once per BATCH, and both schedules decayed
     assert grp._optimizer.num_update == seq._optimizer.num_update == 4

@@ -256,7 +256,8 @@ def test_grouped_steps_match_sequential_under_mode():
     stacked = {
         "data": np.stack([b.data[0].asnumpy() for b in bs]),
         "softmax_label": np.stack([b.label[0].asnumpy() for b in bs])}
-    assert grp._exec_group.step_update_grouped(grp._updater, stacked)
+    eg = grp._exec_group
+    assert eg.step_update_grouped(grp._updater, eg.stage_stacked(stacked))
     _assert_equal(_params(seq), _params(grp))
     for a, b in zip(_state_leaves(seq._updater),
                     _state_leaves(grp._updater)):

@@ -14,6 +14,8 @@ exact snapshot surface while living in the shared registry.
 """
 import json
 import logging
+import os
+import sys
 import threading
 
 import numpy as np
@@ -183,8 +185,8 @@ def test_prometheus_render_and_endpoint():
 def test_span_nesting_merges_into_chrome_trace(tmp_path):
     """Nested spans from two threads land in dump_profile's Chrome
     trace as complete events with REAL thread ids, child intervals
-    contained in their parents; profiler.Scope emits the same complete
-    encoding (the old unpaired B/E-with-tid=pid events are gone)."""
+    contained in their parents (the old unpaired B/E-with-tid=pid
+    events are gone)."""
     from mxnet_tpu import profiler as prof
     tel.enable()
 
@@ -202,14 +204,11 @@ def test_span_nesting_merges_into_chrome_trace(tmp_path):
     out = tmp_path / "trace.json"
     prof.profiler_set_config(mode="symbolic", filename=str(out))
     prof.profiler_set_state("run")
-    with prof.Scope("legacy_scope"):
-        pass
     prof.profiler_set_state("stop")
     prof.dump_profile()
     trace = json.load(open(out))
     events = {e["name"]: e for e in trace["traceEvents"]}
-    for name in ("outer_fg", "inner_fg", "outer_bg", "inner_bg",
-                 "legacy_scope"):
+    for name in ("outer_fg", "inner_fg", "outer_bg", "inner_bg"):
         assert events[name]["ph"] == "X" and "dur" in events[name], \
             events.get(name)
     assert not any(e.get("ph") in ("B", "E")
@@ -237,12 +236,12 @@ def test_step_timeline_short_fit():
     recs = tel.timeline().records()
     assert len(recs) == 2 * (len(X) // 16)   # 2 epochs x 4 steps
     for r in recs:
-        for f in ("step", "epoch", "nbatch", "host_wait_ms", "step_ms",
+        for f in ("step", "epoch", "nbatch", "host_wait_ms", "dispatch_ms",
                   "metric_cb_ms", "checkpoint_ms", "batch_group",
                   "recompile", "total_ms", "ts"):
             assert f in r, (f, r)
         assert r["batch_group"] == 1
-        assert r["total_ms"] >= r["step_ms"]
+        assert r["total_ms"] >= r["dispatch_ms"]
     assert [r["step"] for r in recs] == \
         [recs[0]["step"] + i for i in range(len(recs))]
     assert recs[0]["recompile"] is True
@@ -358,16 +357,24 @@ def test_compile_watch_catches_shape_unstable_eval(caplog):
 # Disabled mode + zero perturbation
 # ----------------------------------------------------------------------
 def test_disabled_mode_is_noop():
+    """Disabled means: no ring events, no timeline records, no sink
+    writes.  The spans still run (they reach the profiler and the fit
+    report), so `last_fit()` is filled all the same."""
     assert not tel.enabled()
-    assert tel.span("x") is tel.NOOP_SPAN
-    with tel.span("x"):
+    with tel.span("x") as sp:
         pass
+    assert sp.ns > 0
     assert tel.trace_events() == []
     tel.log_event("step", {"a": 1})        # no sink: swallowed
     tel.flush_metrics()
+    assert tel.jsonl_sink() is None
     X, y = _data()
     _fit(_mlp(), X, y)
     assert len(tel.timeline()) == 0        # fit recorded nothing
+    assert tel.trace_events() == []
+    rep = tel.last_fit()
+    assert rep["steps"] == 2 * (len(X) // 16) and rep["epochs"] == 2
+    assert rep["spans"]["fit.update"]["count"] == rep["steps"]
 
 
 def test_zero_perturbation_bitwise_params():
@@ -484,3 +491,240 @@ def test_checkpoint_records_duration_and_bytes(tmp_path):
     assert delta("bytes_read") == 32 * 4 + 10
     assert delta("save_ms") > 0 and delta("restore_ms") > 0
     assert np.array_equal(ckpt.params["arg:w"], arrays["arg:w"])
+
+
+# ----------------------------------------------------------------------
+# Spans on the profiler's clock, and the fit report
+# ----------------------------------------------------------------------
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+STEP_SPANS = ("fit.forward_backward", "exec.stage", "fit.update",
+              "exec.launch", "fit.metric")
+PARENT = {"fit.epoch": "fit", "fit.epoch_end": "fit",
+          "fit.next": "fit.epoch", "fit.forward_backward": "fit.epoch",
+          "fit.update": "fit.epoch", "fit.metric": "fit.epoch",
+          "exec.stage": "fit.forward_backward", "exec.launch": "fit.update"}
+
+
+def _traced(tmp_path, work):
+    """Run `work()` under a `jax.profiler` session; returns the host
+    plane's `mx.*` events as (name, start_ns, end_ns), prefix cut."""
+    import jax
+    sys.path.insert(0, ROOT)
+    from benchmark import trace_reduce
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        work()
+    finally:
+        jax.profiler.stop_trace()
+    trace = trace_reduce.load(trace_reduce.find_xplane(str(tmp_path)),
+                              span_prefix="mx.")
+    return [(n[3:], s, e) for n, s, e in trace["spans"]]
+
+
+@pytest.mark.parametrize("batch_group", [None, 2])
+def test_fit_spans_lie_in_the_profiler_trace(tmp_path, batch_group):
+    """With telemetry disabled, a `fit` under anyone's profiler session
+    leaves its phases on the host plane as `mx.*` events: each inside
+    its parent's interval, one of each step-level span per launch, one
+    `fit.next` per pull.  `fit(batch_group=2)` gives the same names."""
+    X, y = _data()
+    spans = _traced(tmp_path, lambda: _fit(_mlp(), X, y,
+                                           batch_group=batch_group))
+    assert not tel.enabled()
+    by_name = {}
+    for name, s, e in spans:
+        by_name.setdefault(name, []).append((s, e))
+    steps, epochs = 2 * (len(X) // 16), 2
+    launches = steps // (batch_group or 1)
+    assert set(by_name) == set(PARENT) | {"fit"}, sorted(by_name)
+    assert len(by_name["fit"]) == 1
+    assert len(by_name["fit.epoch"]) == len(by_name["fit.epoch_end"]) \
+        == epochs
+    assert len(by_name["fit.next"]) == steps + epochs
+    for name in STEP_SPANS:
+        assert len(by_name[name]) == launches, name
+    for name, parent in PARENT.items():
+        for s, e in by_name[name]:
+            assert any(ps <= s and e <= pe for ps, pe in by_name[parent]), \
+                (name, parent)
+    rep = tel.last_fit()
+    assert rep["steps"] == steps and rep["epochs"] == epochs
+    assert {n: r["parent"] for n, r in rep["spans"].items()
+            if n != "fit"} == PARENT
+
+
+def test_score_opens_exec_stage_without_a_fit(tmp_path):
+    """The executor group's spans are opened whoever calls: `score`
+    stages under `score.forward`, and no `fit` span is above it."""
+    X, y = _data()
+    mod = _fit(_mlp(), X, y)
+    before = tel.last_fit()
+    it = NDArrayIter(X, y, batch_size=16, shuffle=False)
+    spans = _traced(tmp_path, lambda: mod.score(
+        it, "acc", batch_end_callback=lambda param: None))
+    names = [n for n, _s, _e in spans]
+    assert names.count("exec.stage") == len(X) // 16
+    assert "score" in names and not any(n.startswith("fit") for n in names)
+    (score,) = [(s, e) for n, s, e in spans if n == "score"]
+    for n, s, e in spans:
+        assert score[0] <= s and e <= score[1], n
+    assert tel.last_fit() == before        # `score` is no `fit`
+
+
+def test_last_fit_report_adds_up():
+    """Counts equal the steps; the self times of all spans add up to
+    the root's total (so children + self = parent at every level); the
+    root's total is the wall time of the call."""
+    import time
+    X, y = _data()
+    mx.random.seed(5)
+    mod = mx.mod.Module(_mlp(), context=[mx.cpu(0)])
+    it = NDArrayIter(X, y, batch_size=16, shuffle=False)
+    t0 = time.perf_counter_ns()
+    mod.fit(it, num_epoch=3, optimizer_params={"learning_rate": 0.1})
+    wall = time.perf_counter_ns() - t0
+    rep = tel.last_fit()
+    spans = rep["spans"]
+    steps = 3 * (len(X) // 16)
+    assert rep["steps"] == steps and rep["epochs"] == 3
+    for name in STEP_SPANS:
+        assert spans[name]["count"] == steps, name
+    assert spans["fit.next"]["count"] == steps + 3
+    assert spans["fit"]["count"] == 1 and spans["fit"]["parent"] is None
+    assert rep["wall_ns"] == spans["fit"]["total_ns"]
+    assert abs(rep["wall_ns"] - wall) <= 0.01 * wall
+    assert sum(r["self_ns"] for r in spans.values()) == rep["wall_ns"]
+    for name, row in spans.items():
+        kids = sum(r["total_ns"] for r in spans.values()
+                   if r["parent"] == name)
+        assert kids + row["self_ns"] == row["total_ns"], name
+        assert 0 < row["max_ns"] <= row["total_ns"]
+        assert 0 <= row["max_step"] <= steps
+    # the first step compiled: it is the longest launch
+    assert spans["exec.launch"]["max_step"] == 0
+
+
+def test_last_fit_is_the_latest_and_this_threads():
+    """The next `fit` replaces the report; a span opened on another
+    thread while a `fit` runs reaches the ring, not the report."""
+    X, y = _data()
+    _fit(_mlp(), X, y)
+    first = tel.last_fit()
+    assert first["steps"] == 8
+    tel.enable()
+    done = threading.Event()
+
+    def elsewhere(param):
+        if param.nbatch == 0 and not done.is_set():
+            def work():
+                with tel.span("elsewhere"):
+                    tel.count("input.h2d_bytes", 7)
+            t = threading.Thread(target=work)
+            t.start()
+            t.join(10)
+            done.set()
+
+    mx.random.seed(11)
+    mod = mx.mod.Module(_mlp(), context=[mx.cpu(0)])
+    mod.fit(NDArrayIter(X, y, batch_size=16, shuffle=False), num_epoch=1,
+            batch_end_callback=elsewhere,
+            optimizer_params={"learning_rate": 0.1})
+    second = tel.last_fit()
+    assert done.is_set()
+    assert second["steps"] == 4 and second["epochs"] == 1
+    assert "elsewhere" not in second["spans"] and not second["counters"]
+    assert "elsewhere" in {e["name"] for e in tel.trace_events()}
+    assert tel.last_fit() == second and second is not tel.last_fit()
+
+
+def _jpeg_rec(tmp_path, n=10, hw=(12, 12)):
+    import io
+    from PIL import Image
+    from mxnet_tpu import recordio
+    path = str(tmp_path / "ten.rec")
+    rec = recordio.MXRecordIO(path, "w")
+    rng = np.random.RandomState(0)
+    for i in range(n):
+        buf = io.BytesIO()
+        Image.fromarray(rng.randint(0, 255, hw + (3,), dtype=np.uint8)) \
+            .save(buf, format="JPEG", quality=90)
+        rec.write(recordio.pack(recordio.IRHeader(0, float(i % 3), i, 0),
+                                buf.getvalue()))
+    rec.close()
+    return path
+
+
+@pytest.mark.parametrize("device_augment", [False, "defer"])
+def test_image_record_iter_stages_under_fit_next(tmp_path, device_augment):
+    """`ImageRecordIter.next()` clocks decode, assemble and put under
+    `fit.next`, and the report counts the host bytes handed to
+    `jax.device_put`: the float32 batch and its labels from the
+    iterator; with the u8 wire (`device_augment="defer"`) a quarter of
+    the batch, handed over in the executor group's staging."""
+    batch, shape = 5, (3, 12, 12)
+    it = mx.io.ImageRecordIter(
+        path_imgrec=_jpeg_rec(tmp_path), data_shape=shape,
+        batch_size=batch, rand_mirror=True, preprocess_threads=2,
+        device_augment=device_augment)
+    net = sym.Flatten(sym.Variable("data"))
+    net = sym.FullyConnected(net, num_hidden=3, name="fc")
+    net = sym.SoftmaxOutput(net, name="softmax")
+    mod = mx.mod.Module(net, context=[mx.cpu(0)])
+    mod.fit(it, num_epoch=2, optimizer_params={"learning_rate": 0.01})
+    rep = tel.last_fit()
+    steps = 4
+    assert rep["steps"] == steps
+    for name in ("input.decode", "input.assemble", "input.put"):
+        assert rep["spans"][name]["count"] == steps, name
+        assert rep["spans"][name]["parent"] == "fit.next"
+    stages = sum(rep["spans"][n]["total_ns"] for n in
+                 ("input.decode", "input.assemble", "input.put"))
+    assert stages <= rep["spans"]["fit.next"]["total_ns"]
+    pixels, labels = batch * 3 * 12 * 12, batch * 4
+    if device_augment == "defer":
+        mirror_draws = batch              # one uint8 a row
+        per_step = pixels + mirror_draws + labels
+    else:
+        per_step = 4 * pixels + labels
+    assert rep["counters"] == {"input.h2d_bytes": per_step * steps}
+
+
+def test_report_shape_is_the_same_enabled_and_disabled():
+    """Enabling telemetry adds the ring, the timeline and the sinks;
+    the report has the same spans with the same counts, and training is
+    bitwise the same."""
+    X, y = _data()
+    off = _fit(_mlp(), X, y, seed=29)
+    rep_off = tel.last_fit()
+    tel.enable()
+    on = _fit(_mlp(), X, y, seed=29)
+    rep_on = tel.last_fit()
+    tel.disable()
+    assert _params_bytes(off) == _params_bytes(on)
+    assert set(rep_on) == set(rep_off)
+    assert {n: (r["count"], r["parent"]) for n, r in rep_on["spans"].items()} \
+        == {n: (r["count"], r["parent"])
+            for n, r in rep_off["spans"].items()}
+    recs = tel.timeline().records()
+    assert len(recs) == rep_on["steps"]
+    # the timeline's clocks are the spans' own reads
+    assert abs(sum(r["host_wait_ms"] for r in recs) * 1e6
+               - rep_on["spans"]["fit.next"]["total_ns"]) \
+        <= rep_on["spans"]["fit.next"]["max_ns"] + 1e3 * len(recs)
+
+
+def test_symbol_node_names_reach_the_lowered_program():
+    """`executor._run_op` evaluates every node under
+    `jax.named_scope(node.name)`: the step program's HLO metadata names
+    the symbol node an instruction came from."""
+    X, y = _data(16)
+    mod = _fit(_mlp(), X, y)
+    fn, skeleton = mod._exec_group._last_step
+    text = fn.lower(*skeleton).as_text(debug_info=True)
+    for node in ("fc1", "fc2", "softmax"):
+        # forward and backward of the node: `.../jvp(fc1)/dot_general`,
+        # `.../transpose(jvp(fc1))/dot_general`
+        assert "/jvp(%s)/" % node in text, node
+    assert "/transpose(jvp(fc1))/" in text

@@ -387,7 +387,7 @@ def test_slo_through_batcher_clean_traffic(served):
 def _feed(tl, n, total_ms, epoch=0, loop="train", mfu=None):
     for i in range(n):
         rec = tl.record(epoch, i, host_wait_ms=total_ms * 0.1,
-                        step_ms=total_ms * 0.9, loop=loop)
+                        dispatch_ms=total_ms * 0.9, loop=loop)
         if mfu is not None:
             rec["mfu"] = mfu
 
@@ -416,8 +416,8 @@ def test_watchdog_self_calibrates_then_fires_once():
     assert inc["value"] == pytest.approx(100.0, rel=0.01)
     assert inc["baseline"] == pytest.approx(10.0, rel=0.01)
     assert inc["window"]["n_train"] == 8
-    # step_ms co-moved and is consumed by the same incident
-    assert "step_ms" in inc["also"]
+    # dispatch_ms co-moved and is consumed by the same incident
+    assert "dispatch_ms" in inc["also"]
     _feed(timeline, 8, 100.0)
     assert wd.poll() == []                  # warn-once: no repeat
     assert wd.healthy is False
@@ -504,7 +504,7 @@ def test_watchdog_watches_eval_records():
     _feed(timeline, 4, 80.0, loop="eval")
     incidents = wd.poll()
     assert len(incidents) == 1
-    assert incidents[0]["gauge"] == "eval_step_ms"
+    assert incidents[0]["gauge"] == "eval_dispatch_ms"
 
 
 def test_watchdog_thin_windows_carry_forward():
@@ -518,13 +518,13 @@ def test_watchdog_thin_windows_carry_forward():
         _feed(timeline, 1, 5.0, loop="eval")
         assert wd.poll() == []
     # the three carried records formed ONE adequate window -> baseline
-    assert "eval_step_ms" in (wd.baseline or {})
+    assert "eval_dispatch_ms" in (wd.baseline or {})
     fired = []
     for _ in range(3):                       # regression, same trickle
         _feed(timeline, 1, 80.0, loop="eval")
         fired += wd.poll()
     assert len(fired) == 1
-    assert fired[0]["gauge"] == "eval_step_ms"
+    assert fired[0]["gauge"] == "eval_dispatch_ms"
 
 
 def test_watchdog_mfu_regression():
@@ -638,7 +638,7 @@ def test_score_writes_eval_records(tmp_path):
     # one per batch — either way the SAME record shape as fit's
     covered = sum(r["batch_group"] for r in recs)
     assert covered == 2
-    for f in ("step", "epoch", "nbatch", "host_wait_ms", "step_ms",
+    for f in ("step", "epoch", "nbatch", "host_wait_ms", "dispatch_ms",
               "metric_cb_ms", "total_ms", "recompile"):
         assert f in recs[0], f
     tel.disable()
