@@ -234,6 +234,26 @@ def leg_train(env):
           "compile.post_warmup_retraces = %d after fit's first epoch: "
           "%r" % (watch.post_warmup_count, watch.events()[-3:]))
 
+    # --- a batch made under the default context ----------------------
+    # ImageRecordIter's nd.array puts onto jax's CPU backend; staging
+    # must take it up from host memory (no client-to-client copy), and
+    # say so in the fit's report
+    rows = np.random.RandomState(1).rand(batch, *shape).astype(np.float32)
+    landed = grp._stage(mx.io.DataBatch(data=[mx.nd.array(rows)],
+                                        label=None))["data"]
+    _on_devices(landed, devices, "a staged mx.nd.array batch")
+    check(np.asarray(landed).tobytes() == rows.tobytes(),
+          "a batch made with mx.nd.array under the default context did "
+          "not land on the mesh bit for bit")
+    counters = mx.telemetry.last_fit()["counters"]
+    routed = counters.get("exec.stage_host_routed_bytes", 0)
+    want = 0 if env["rehearsal"] else counters["input.h2d_bytes"]
+    check(routed == want,
+          "fit's report counts %d bytes staged from host memory, wanted "
+          "%d (on the chip every byte the iterator handed over, on one "
+          "backend none): staging copied the batches client to client"
+          % (routed, want))
+
     # --- one scored batch: finite loss -----------------------------
     t_fit = time.time()
     scores = dict(mod.score(it, mx.metric.create(["acc", "ce"]),

@@ -185,10 +185,18 @@ class DeviceLoader(DataIter):
     # -- staging -------------------------------------------------------
     def _stage_batch(self, batch):
         """Place one host batch on device, preserving the exact bytes
-        ``MeshExecutorGroup._stage`` would transfer."""
+        ``MeshExecutorGroup._stage`` would transfer: with a group the
+        put IS its staging rule (``stage_sharded``: an array on another
+        backend than the mesh's goes up from its host view)."""
         import jax
+        from ..dist.staging import stage_sharded
         grp = self._group_handle
-        sharding = grp._batch_sharding if grp is not None else None
+
+        def place(v):
+            if grp is None:
+                return jax.device_put(v)
+            return stage_sharded(v, grp._batch_sharding,
+                                 (grp.batch_size,) + tuple(v.shape[1:]))
 
         def put(arr):
             v = _host_value(arr)
@@ -200,13 +208,9 @@ class DeviceLoader(DataIter):
                 # unarmed staging pays one branch, nothing more.
                 def attempt():
                     _faults.check("data.device_put")
-                    if sharding is not None:
-                        return jax.device_put(v, sharding)
-                    return jax.device_put(v)
+                    return place(v)
                 return _faults.retry(attempt, site="data.device_put")
-            if sharding is not None:
-                return jax.device_put(v, sharding)
-            return jax.device_put(v)
+            return place(v)
 
         data = [nd.NDArray(put(d)) for d in batch.data]
         label = None
@@ -225,12 +229,14 @@ class DeviceLoader(DataIter):
         carrying the staged dict so ``Module._grouped_stage`` can hand
         the block straight to the scanned program."""
         from ..module.base_module import stack_group_inputs
-        # default stacking rule: all-host batches form ONE contiguous
-        # numpy block (single device_put), device-resident batches
+        # the stacking rule: host batches (numpy, or arrays on another
+        # backend than the mesh's) form ONE contiguous numpy block
+        # (single device_put), batches resident on the mesh's backend
         # (CachedDataset gathers) stack with jnp ON DEVICE — an
         # onp.stack there would be K blocking readbacks
         stacked = stack_group_inputs(
-            batches, self._data_names, self._label_names)
+            batches, self._data_names, self._label_names,
+            self._group_handle._batch_sharding)
         if _faults.armed():
             def attempt():
                 _faults.check("data.device_put", group=len(batches))

@@ -1,13 +1,22 @@
 """Multi-host staging — host rows to global mesh-sharded arrays.
 
 THE batch-staging rule of the whole stack
-(``MeshExecutorGroup._stage`` / ``stage_stacked`` route every input
-through :func:`stage_sharded`):
+(``MeshExecutorGroup._stage`` / ``stage_stacked``, the DeviceLoader's
+stager and the grouped stacker route every input through
+:func:`stage_sharded` / :func:`host_view`):
 
-* single process — exactly ``jax.device_put(value, sharding)``, the
-  path every existing program compiled against (device-resident values
-  pass through untouched, which is how the DeviceLoader ring and the
-  virtual-host feed keep fit's own staging a no-op);
+* an array on ANOTHER backend than the sharding's devices (a batch an
+  iterator made with ``nd.array`` under the default context on a TPU
+  machine lives on jax's CPU backend) is first replaced by its host
+  view (:func:`host_view`: ``onp.asarray``, no copy) and then staged as
+  the numpy value it now is. "Device-resident" means *on the mesh's
+  backend*: a ``device_put`` of the array itself would copy between two
+  PJRT clients, out of one and then into the other, inside the
+  dispatch (397 ms for 154 MB against one direct put, PERF.md PR 26);
+* single process — ``jax.device_put(value, sharding)``, the path every
+  existing program compiled against (arrays already on the mesh's
+  backend pass through untouched, which is how the DeviceLoader ring
+  and the virtual-host feed keep fit's own staging a no-op);
 * multi process — each process holds only its LOCAL slice of the
   global batch (a :class:`~mxnet_tpu.dist.ShardedDataIter` shard), and
   the global array is assembled with
@@ -29,7 +38,7 @@ processes. No host-side concat happens on either path.
 from __future__ import annotations
 
 __all__ = ["stage_sharded", "stage_zeros", "assemble_host_slices",
-           "local_block"]
+           "local_block", "host_view"]
 
 
 def local_block(sharding, global_shape):
@@ -80,18 +89,45 @@ def _vol(box):
     return v
 
 
+def _array_platform(arr):
+    """The backend a fully addressable ``jax.Array`` lives on."""
+    return next(iter(arr.devices())).platform
+
+
+def host_view(val, sharding):
+    """``val`` as staging onto ``sharding`` should take it: a fully
+    addressable ``jax.Array`` on another backend than the sharding's
+    devices comes back as its host view (``onp.asarray``: on the CPU
+    backend a read-only, zero-copy view of the client's buffer, which
+    keeps the array alive), counted in ``exec.stage_host_routed_bytes``;
+    anything else (a numpy value, an array on the sharding's own
+    backend, a global array this process addresses only in part) comes
+    back as the object it is. THE one backend comparison: every staging
+    and stacking site decides "on the device already" here."""
+    import jax
+    if not isinstance(val, jax.Array) or not val.is_fully_addressable or \
+            _array_platform(val) == next(iter(sharding.device_set)).platform:
+        return val
+    import numpy as onp
+    from .. import telemetry
+    host = onp.asarray(val)
+    telemetry.count("exec.stage_host_routed_bytes", host.nbytes)
+    return host
+
+
 def stage_sharded(value, sharding, global_shape=None):
     """Place ``value`` (NDArray / numpy / jax array) onto ``sharding``.
 
     ``global_shape`` is the GLOBAL shape of the array being staged;
     None means ``value`` already has it. See module docstring for the
-    single- vs multi-process behavior. Batch axes may differ from the
-    global shape only in multi-process mode (the local-slice case) —
-    single-process callers staging odd shapes (eval tails, bucketing)
-    keep plain ``device_put`` semantics.
+    off-backend rule and the single- vs multi-process behavior. Batch
+    axes may differ from the global shape only in multi-process mode
+    (the local-slice case) — single-process callers staging odd shapes
+    (eval tails, bucketing) keep plain ``device_put`` semantics.
     """
     import jax
     val = value._read() if hasattr(value, "_read") else value
+    val = host_view(val, sharding)
     if jax.process_count() == 1:
         return jax.device_put(val, sharding)
     gshape = tuple(global_shape) if global_shape is not None \

@@ -461,10 +461,12 @@ class ImageRecordIter(DataIter):
                 "device_augment='defer' (crop runs in-program)")
         self.seq = list(range(len(self.rec)))
         self.cur = 0
-        # NOTE on staging: each batch gets a FRESH host buffer. A pooled
+        # NOTE on staging: each batch gets a FRESH host buffer, 64-byte
+        # aligned (runtime.assemble_batch), so that nd.array of it on the
+        # CPU jax device is that memory and not a copy of it, and the
+        # executor group stages it to the mesh from there. A pooled
         # double-buffer ring (iter_prefetcher.h pattern) was tried and
-        # reverted: jax.device_put zero-copies 64-byte-aligned host arrays
-        # onto the CPU jax device, so a recycled buffer would alias any
+        # reverted for the same reason: a recycled buffer would alias any
         # still-live batch NDArray (and downstream TPU transfers read the
         # alias asynchronously). runtime.core.HostPool remains available
         # (and assemble_batch takes ``out=``) for callers that own the

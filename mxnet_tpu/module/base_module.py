@@ -55,38 +55,49 @@ def pad_batch_rows(arr, target_rows):
     return jnp.concatenate([vals, fill])
 
 
-def stack_group_inputs(batches, data_names, label_names,
-                       stack=None):
+def stack_group_inputs(batches, data_names, label_names, sharding):
     """K batches -> {input name: stacked (K, batch, ...) block} — the
     ONE rule pairing a group's arrays with their bound input names
     (every data input; a label only when every batch in the group
     provides it).  Shared by the grouped train step
     (``Module._grouped_stage``) and the device-feed stager
     (``mxnet_tpu.data.DeviceLoader._stage_block``), so the two can
-    never drift on label handling.  ``stack`` defaults to
-    :func:`_stack_batch_arrays` (host blocks contiguous, device
-    blocks stacked on device)."""
-    stack = stack or _stack_batch_arrays
+    never drift on label handling.  Blocks stack by
+    :func:`_stack_batch_arrays` for staging onto ``sharding`` (host
+    blocks contiguous, blocks on the mesh's backend stacked there)."""
     stacked = {}
     for i, name in enumerate(data_names):
-        stacked[name] = stack([b.data[i] for b in batches])
+        stacked[name] = _stack_batch_arrays(
+            [b.data[i] for b in batches], sharding)
     if label_names and batches[0].label:
         for i, name in enumerate(label_names):
             if i < len(batches[0].label) and \
                     all(b.label[i] is not None for b in batches):
-                stacked[name] = stack([b.label[i] for b in batches])
+                stacked[name] = _stack_batch_arrays(
+                    [b.label[i] for b in batches], sharding)
     return stacked
 
 
-def _stack_batch_arrays(arrs):
+def _stack_batch_arrays(arrs, sharding):
     """K per-batch arrays -> one (K, batch, ...) block — the ONE
     stacking rule for every grouped launch (grouped training and
-    grouped predict).  All-host inputs stack into one contiguous numpy
-    block, so staging is a single ``device_put``; any device-resident
-    input stacks with jnp on device (an ``onp.stack`` there would be K
-    blocking readbacks)."""
+    grouped predict) that stages onto ``sharding``.  Host inputs —
+    numpy values, and arrays on another backend than the sharding's,
+    taken by their host view (``dist.staging.host_view``) — stack into
+    one contiguous numpy block, so staging is a single ``device_put``;
+    any input resident on the sharding's backend stacks with jnp on
+    device (an ``onp.stack`` there would be K blocking readbacks).
+    ``input.h2d_bytes`` counts here what is numpy as it comes in: once
+    stacked, a block no longer shows whether a producer counted it."""
     import numpy as onp
-    vals = [a._read() if hasattr(a, "_read") else a for a in arrs]
+    from .. import telemetry
+    from ..dist.staging import host_view
+    vals = []
+    for a in arrs:
+        v = a._read() if hasattr(a, "_read") else a
+        if isinstance(v, onp.ndarray):
+            telemetry.count("input.h2d_bytes", v.nbytes)
+        vals.append(host_view(v, sharding))
     if all(isinstance(v, onp.ndarray) for v in vals):
         return onp.stack(vals)
     import jax.numpy as jnp
@@ -318,7 +329,8 @@ class BaseModule(object):
         def flush():
             if not chunk:
                 return
-            stacked = {name: _stack_batch_arrays([b[i] for b in chunk])
+            stacked = {name: _stack_batch_arrays([b[i] for b in chunk],
+                                                 group._batch_sharding)
                        for i, name in enumerate(chunk_names)}
             outs = group.score_stacked(stacked)
             for k, pad in enumerate(pads):

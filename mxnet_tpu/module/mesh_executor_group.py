@@ -1270,14 +1270,22 @@ class MeshExecutorGroup(object):
         """Shard the host batch onto the mesh ('dp' on axis 0).
 
         Every input rides THE staging rule
-        (:func:`mxnet_tpu.dist.staging.stage_sharded`): single-process
-        it is exactly ``jax.device_put`` (device-resident arrays from
-        the DeviceLoader / virtual-host feed pass through bitwise);
+        (:func:`mxnet_tpu.dist.staging.stage_sharded`): an array on
+        another backend than the mesh's (``nd.array`` under the default
+        context puts onto jax's CPU backend) goes up from its host
+        view, as a numpy value would; single-process the rest is
+        ``jax.device_put`` (arrays resident on the MESH's backend —
+        the DeviceLoader / virtual-host feed — pass through bitwise);
         multi-process it assembles this process's local rows — a
         ``ShardedDataIter`` slice, or this process's block of a
         replicated global batch — into the global array with
         ``make_array_from_process_local_data``, so the compiled global
-        program runs unchanged across hosts."""
+        program runs unchanged across hosts.
+
+        ``input.h2d_bytes`` counts here only what no producer can have
+        counted, a value that is not a ``jax.Array`` yet; an
+        off-backend array's bytes are counted by staging itself in
+        ``exec.stage_host_routed_bytes``."""
         import jax
         from .. import telemetry
         from ..dist.staging import stage_sharded, stage_zeros
@@ -1327,10 +1335,12 @@ class MeshExecutorGroup(object):
         stacked scoring (``score_stacked``) and the grouped train step
         (``step_update_grouped``) both ride it. Blocks route through
         the same :func:`~mxnet_tpu.dist.staging.stage_sharded` rule as
-        per-batch staging (single-process: plain ``device_put``;
-        multi-process: per-process ``(K, B/R, ...)`` blocks assemble
-        into the global ``(K, B, ...)`` array)."""
-        import jax
+        per-batch staging (off-backend blocks from their host view;
+        single-process: plain ``device_put``; multi-process:
+        per-process ``(K, B/R, ...)`` blocks assemble into the global
+        ``(K, B, ...)`` array).  ``input.h2d_bytes`` is counted
+        where the block was stacked (``_stack_batch_arrays``), which
+        still sees which of its batches came in as numpy."""
         from .. import telemetry
         from ..dist.staging import stage_sharded, stage_zeros
         st_batch = self._stacked_sharding()
@@ -1340,8 +1350,6 @@ class MeshExecutorGroup(object):
             for name, arr in stacked_data.items():
                 arr = arr._read() if isinstance(arr, nd.NDArray) else arr
                 K = arr.shape[0]
-                if not isinstance(arr, jax.Array):
-                    telemetry.count("input.h2d_bytes", arr.nbytes)
                 inputs[name] = stage_sharded(
                     arr, st_batch,
                     (K, self.batch_size) + tuple(arr.shape[2:]))

@@ -932,7 +932,7 @@ class Module(BaseModule):
         if stacked is None:
             stacked = stack_group_inputs(
                 batches, [d[0] for d in grp.data_shapes],
-                getattr(grp, "_label_names", []))
+                getattr(grp, "_label_names", []), grp._batch_sharding)
         return grp.stage_stacked(stacked)
 
     def _grouped_update(self, staged):

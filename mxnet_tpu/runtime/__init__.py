@@ -110,13 +110,29 @@ class RecordFile(object):
             pass
 
 
+def aligned_empty(shape, dtype=onp.float32):
+    """An uninitialised array whose data starts on a 64-byte boundary:
+    what XLA's CPU client asks of host memory before ``jax.device_put``
+    aliases it. numpy's own large blocks start 16 bytes past a page,
+    and the client then copies them, later and on one thread — 154 MB
+    in 220 ms on the v5e's host, which the batch's staging waited out
+    (PERF.md, PR 26)."""
+    dtype = onp.dtype(dtype)
+    nbytes = int(onp.prod(shape)) * dtype.itemsize
+    raw = onp.empty(nbytes + 64, onp.uint8)
+    start = -raw.ctypes.data % 64
+    return raw[start:start + nbytes].view(dtype).reshape(shape)
+
+
 def assemble_batch(images, mean=None, std=None, mirror=None, crop_yx=None,
                    out_hw=None, out=None):
     """uint8 (n,h,w,c) HWC images -> float32 (n,c,oh,ow) NCHW batch.
 
     Runs the native OpenMP loop. ``out`` lets the caller supply a
     staging buffer (e.g. a pooled HostPool array, the iter_prefetcher.h
-    double-buffer pattern) instead of allocating.
+    double-buffer pattern) instead of allocating; the batch allocated
+    here is 64-byte aligned (:func:`aligned_empty`), so ``nd.array`` of
+    it on the CPU backend is the same memory, not a copy.
     """
     images = onp.ascontiguousarray(images, dtype=onp.uint8)
     n, h, w, c = images.shape
@@ -126,7 +142,7 @@ def assemble_batch(images, mean=None, std=None, mirror=None, crop_yx=None,
             and out.flags.c_contiguous, "bad staging buffer"
     lib = get_lib()
     if out is None:
-        out = onp.empty((n, c, oh, ow), dtype=onp.float32)
+        out = aligned_empty((n, c, oh, ow))
     meanp = stdp = None
     if mean is not None:
         mean = onp.ascontiguousarray(mean, dtype=onp.float32)
