@@ -16,7 +16,7 @@ mx.internal.gen.sym <- function(op, args, name) {
   do.call(mx.symbol.create, c(list(op), args, list(name = name)))
 }
 
-# relu/sigmoid/tanh/softrelu (src/operator/activation-inl.h).
+# relu/sigmoid/tanh/softrelu (src/operator/activation-inl.h), and
 #   data : NDArray-or-Symbol
 #   act_type : string, optional
 mx.nd.Activation <- function(..., out = NULL) mx.internal.gen.nd("Activation", list(...), out)
@@ -184,6 +184,21 @@ mx.nd.FullyConnected <- function(..., out = NULL) mx.internal.gen.nd("FullyConne
 mx.nd.GridGenerator <- function(..., out = NULL) mx.internal.gen.nd("GridGenerator", list(...), out)
 mx.symbol.GridGenerator <- function(..., name = NULL) mx.internal.gen.sym("GridGenerator", list(...), name)
 
+# Causal attention of rows cut into sequences of ``seq_len``:
+#   query : NDArray-or-Symbol
+#   key : NDArray-or-Symbol
+#   value : NDArray-or-Symbol
+#   causal : boolean, optional
+#   gated : boolean, optional
+#   head_dim : int, required
+#   num_heads : int, required
+#   num_kv_heads : int, required
+#   scale : float, optional
+#   seq_len : int, required
+#   window : int, optional
+mx.nd.GroupedQueryAttention <- function(..., out = NULL) mx.internal.gen.nd("GroupedQueryAttention", list(...), out)
+mx.symbol.GroupedQueryAttention <- function(..., name = NULL) mx.internal.gen.sym("GroupedQueryAttention", list(...), name)
+
 # IdentityAttachKLSparseReg
 #   data : NDArray-or-Symbol
 #   momentum : float, optional
@@ -251,7 +266,7 @@ mx.symbol.MAERegressionOutput <- function(..., name = NULL) mx.internal.gen.sym(
 mx.nd.MakeLoss <- function(..., out = NULL) mx.internal.gen.nd("MakeLoss", list(...), out)
 mx.symbol.MakeLoss <- function(..., name = NULL) mx.internal.gen.sym("MakeLoss", list(...), name)
 
-# Switch-style top-1 mixture-of-experts block, ep-shardable.
+# Mixture-of-experts block, two layers on one router
 #   data : NDArray-or-Symbol
 #   gate_weight : NDArray-or-Symbol
 #   expert1_weight : NDArray-or-Symbol
@@ -259,8 +274,14 @@ mx.symbol.MakeLoss <- function(..., name = NULL) mx.internal.gen.sym("MakeLoss",
 #   expert2_weight : NDArray-or-Symbol
 #   expert2_bias : NDArray-or-Symbol
 #   capacity_factor : float, optional
+#   experts_held : Shape(tuple), optional
 #   hidden_size : int, required
+#   load_balance_coeff : float, optional
 #   num_experts : int, required
+#   num_experts_per_tok : int, optional
+#   route_norm : boolean, optional
+#   route_scale : float, optional
+#   score_func : string, optional
 mx.nd.MoE <- function(..., out = NULL) mx.internal.gen.nd("MoE", list(...), out)
 mx.symbol.MoE <- function(..., name = NULL) mx.internal.gen.sym("MoE", list(...), name)
 
@@ -311,6 +332,14 @@ mx.symbol.Pooling_v1 <- function(..., name = NULL) mx.internal.gen.sym("Pooling_
 mx.nd.Proposal <- function(..., out = NULL) mx.internal.gen.nd("Proposal", list(...), out)
 mx.symbol.Proposal <- function(..., name = NULL) mx.internal.gen.sym("Proposal", list(...), name)
 
+# y = x / sqrt(mean(x^2) + eps) * gamma over the last axis, or,
+#   data : NDArray-or-Symbol
+#   gamma : NDArray-or-Symbol
+#   eps : float, optional
+#   width : int, optional
+mx.nd.RMSNorm <- function(..., out = NULL) mx.internal.gen.nd("RMSNorm", list(...), out)
+mx.symbol.RMSNorm <- function(..., name = NULL) mx.internal.gen.sym("RMSNorm", list(...), name)
+
 # RNN
 #   data : NDArray-or-Symbol
 #   parameters : NDArray-or-Symbol
@@ -349,6 +378,14 @@ mx.nd.Reshape <- function(..., out = NULL) mx.internal.gen.nd("Reshape", list(..
 #   scale : float, optional
 mx.nd.RingAttention <- function(..., out = NULL) mx.internal.gen.nd("RingAttention", list(...), out)
 mx.symbol.RingAttention <- function(..., name = NULL) mx.internal.gen.sym("RingAttention", list(...), name)
+
+# Rotary position embedding over whole heads (the two halves of a
+#   data : NDArray-or-Symbol
+#   head_dim : int, required
+#   seq_len : int, required
+#   theta : float, optional
+mx.nd.RoPE <- function(..., out = NULL) mx.internal.gen.nd("RoPE", list(...), out)
+mx.symbol.RoPE <- function(..., name = NULL) mx.internal.gen.sym("RoPE", list(...), name)
 
 # Hinge-loss output layer (src/operator/svm_output-inl.h).
 #   data : NDArray-or-Symbol

@@ -45,7 +45,10 @@ def op_section(name, op):
         args = ", ".join("`%s`" % a for a in op.arg_names) or "*(none)*"
     lines.append("")
     lines.append("*Inputs:* " + args)
-    if op.aux_names:
+    if callable(op.aux_names):
+        lines.append("")
+        lines.append("*Auxiliary states:* *attribute-dependent*")
+    elif op.aux_names:
         lines.append("")
         lines.append("*Auxiliary states:* "
                      + ", ".join("`%s`" % a for a in op.aux_names))

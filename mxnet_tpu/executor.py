@@ -51,7 +51,7 @@ def _run_op(n, get, put, rng, is_train, aux_sink=None):
     n_out = n.op.num_outputs(n.attrs)
     for oi in range(n_out):
         put(id(n), oi, res[oi])
-    if n.op.aux_names and aux_sink is not None:
+    if aux_sink is not None and n.op.list_auxiliary_states(n.attrs):
         n_args = len(n.op.list_arguments(n.attrs))
         for (src, _), newv in zip(n.inputs[n_args:], res[n_out:]):
             aux_sink(id(src), jax.lax.stop_gradient(newv))
@@ -247,7 +247,7 @@ def _build_eval_segmented(symbol, remat="full", n_segments=None):
                 later = consumed_in.get(slot, set())
                 if any(sj > si for sj in later) or slot in head_slots:
                     out_slots.append(slot)
-            if n.op.aux_names:
+            if n.op.list_auxiliary_states(n.attrs):
                 n_args = len(n.op.list_arguments(n.attrs))
                 for (src, _) in n.inputs[n_args:]:
                     if id(src) in aux_ids:
@@ -258,13 +258,24 @@ def _build_eval_segmented(symbol, remat="full", n_segments=None):
     # policy object resolved ONCE at build time (mxnet_tpu.precision
     # owns the name -> jax.checkpoint_policies mapping)
     from .precision.policy import remat_checkpoint_policy
+    from .registry import count, counting
     _ckpt_policy = remat_checkpoint_policy(remat)
 
-    def eval_fn(arg_vals, aux_vals, rng, is_train, tap=None):
+    arg_slots = [(id(n), 0) for n in arg_nodes]
+
+    def eval_fn(arg_vals, aux_vals, rng, is_train, tap=None,
+                arg_dtypes=None):
+        """``arg_dtypes``: per argument a type to cast it to where a
+        segment reads it, or None.  A cast made up front would keep a
+        second copy of every parameter alive from the first segment to
+        the last backward one; made inside the segments the copies are
+        a segment's temporaries (and recomputed with it)."""
         import jax
 
         assert tap is None, "segmented remat has no monitor taps"
         policy = _ckpt_policy
+        cast_to = {s: dt for s, dt in zip(arg_slots, arg_dtypes or ())
+                   if dt is not None}
         env = {}
         for n, v in zip(arg_nodes, arg_vals):
             env[(id(n), 0)] = v
@@ -276,7 +287,8 @@ def _build_eval_segmented(symbol, remat="full", n_segments=None):
 
             def seg_fn(in_vals, rng_in, _seg=seg, _in=in_slots,
                        _out=out_slots):
-                local = dict(zip(_in, in_vals))
+                local = {s: v.astype(cast_to[s]) if s in cast_to else v
+                         for s, v in zip(_in, in_vals)}
                 upd = []
 
                 def sink(aid, v):
@@ -284,16 +296,22 @@ def _build_eval_segmented(symbol, remat="full", n_segments=None):
                         upd.append(v)
 
                 r = rng_in
-                for n in _seg:
-                    r, _, _ = _run_op(
-                        n, lambda i, oi: local[(i, oi)],
-                        lambda i, oi, v: local.__setitem__((i, oi), v),
-                        r, is_train, aux_sink=sink)
-                return (tuple(local[s] for s in _out), tuple(upd), r)
+                # what the segment's ops count leaves it as an output,
+                # like everything else traced under the checkpoint
+                with counting() as counted:
+                    for n in _seg:
+                        r, _, _ = _run_op(
+                            n, lambda i, oi: local[(i, oi)],
+                            lambda i, oi, v: local.__setitem__((i, oi), v),
+                            r, is_train, aux_sink=sink)
+                return (tuple(local[s] for s in _out), tuple(upd), r,
+                        counted)
 
             in_vals = tuple(env[s] for s in in_slots)
-            outs, upd, rng = jax.checkpoint(seg_fn, policy=policy)(
-                in_vals, rng)
+            outs, upd, rng, counted = jax.checkpoint(
+                seg_fn, policy=policy)(in_vals, rng)
+            for name, v in counted.items():
+                count(name, v)
             for slot, v in zip(out_slots, outs):
                 env[slot] = v
             for aid, v in zip(aux_updates, upd):

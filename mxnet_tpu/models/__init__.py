@@ -16,12 +16,14 @@ from . import inception_v3
 from . import googlenet
 from . import inception_resnet_v2
 from . import lstm
+from . import afmoe
 
 _MODELS = {
     "mlp": mlp, "lenet": lenet, "alexnet": alexnet, "vgg": vgg,
     "inception-bn": inception_bn,
     "inception-v3": inception_v3, "googlenet": googlenet,
     "inception-resnet-v2": inception_resnet_v2,
+    "afmoe": afmoe,
 }  # resnet/resnext dispatch via the prefix loop in get_symbol
 
 

@@ -726,6 +726,10 @@ class BaseModule(object):
                 for name, val in eval_metric.get_name_value():
                     self.logger.info("Epoch[%d] Train-%s=%f", epoch, name,
                                      val)
+                # what the symbol's ops counted on the device this
+                # epoch, read with the metric's drain
+                for name, val in self._read_op_counters().items():
+                    telemetry.count(name, val)
                 cost = time.time() - tic
                 self.logger.info("Epoch[%d] Time cost=%.3f", epoch, cost)
                 if pipe_stats is not None:
@@ -1208,6 +1212,11 @@ class BaseModule(object):
         """Hook for subclasses that can tally the metric on device inside
         the fused train step; the default (host ``update_metric``) path
         needs nothing."""
+
+    def _read_op_counters(self):
+        """Hook: what the symbol's ops counted on the device since the
+        last call, ``{name: number}`` (``registry.count``)."""
+        return {}
 
     def _drain_async_kvstore(self):
         """Flush a dist_async store's in-flight reductions at fit end.

@@ -27,6 +27,7 @@ import logging
 
 import numpy as onp
 
+import collections
 import itertools
 
 from .. import ndarray as nd
@@ -36,6 +37,42 @@ from ..executor import _build_eval, _build_eval_segmented
 
 # monotonic tokens for optimizer instances (train_step jit cache keys)
 _STEP_TOKENS = itertools.count()
+
+# Output bytes of train steps that may be in flight at once.  The host
+# runs ahead of the device (32 launches on the TPU client) and every
+# launch owns its outputs from the moment it is queued: a step whose
+# outputs are a language model's probabilities (0.8 GB) would hold 26 GB
+# that way.  Past this many bytes the launch waits for the oldest step
+# in flight; an image classifier's outputs (1 MB a step) never reach it.
+STEP_OUTPUT_BYTES_IN_FLIGHT = 1 << 30
+STEPS_IN_FLIGHT = 32        # the TPU client's own run-ahead (PERF.md)
+# Parameters packed into one device array for one readback (get_params)
+PACK_BYTES = 256 << 20
+
+
+def _index_inputs(symbol):
+    """Names of the variables that reach an Embedding as its indices,
+    directly or through reshapes."""
+    names = set()
+    for n in symbol._topo():
+        if n.op is None or n.op.name != "Embedding":
+            continue
+        src = n.inputs[0][0]
+        while src.op is not None and src.op.name in ("Reshape", "Flatten",
+                                                     "BlockGrad"):
+            src = src.inputs[0][0]
+        if src.op is None:
+            names.add(src.name)
+    return names
+
+
+def _grads_not_kept():
+    raise MXNetError(
+        "this step's gradients were not kept: update() ran forward, "
+        "backward and the optimizer as one program, which hands no "
+        "gradients back (they would be a second copy of every "
+        "parameter); read them between backward() and update(), or "
+        "install a monitor")
 
 
 def _tally_add(jnp, stat, labels, outs, acc):
@@ -276,6 +313,18 @@ class MeshExecutorGroup(object):
         self._health_cfg = None
         self._health_state = None
         self._probe_count = 0
+        # what the symbol's ops count in a step (registry.count): a
+        # device tally of one f32 per name rides the train step like
+        # the metric's, read once an epoch (read_op_counters)
+        self._counter_names = sorted({
+            c for n in symbol._topo() if n.op is not None
+            for c in n.op.list_counters(n.attrs)})
+        self._counter_acc = None
+        # inputs that index an Embedding keep their type: ids do not
+        # survive a cast to the compute type
+        self._index_inputs = _index_inputs(symbol)
+        # outputs of steps launched and maybe not yet run, oldest first
+        self._inflight_outs = collections.deque()
         self._grad_names = [n for n in param_names
                             if n not in self.fixed_param_names] \
             if for_training and grad_req == "write" else []
@@ -434,6 +483,25 @@ class MeshExecutorGroup(object):
             from ..dist.staging import stage_zeros
             return nd.NDArray(stage_zeros(shape, sharding), ctx=ctx0)
 
+        def lazy_zeros(shape, sharding, dtype=onp.float32):
+            """Zeros that take no memory until they are read: the
+            gradients of a module that trains under fit() and the
+            outputs before the first forward are written before anyone
+            reads them, and at a language model's size (2.8 GB of
+            gradients, 0.8 GB of probabilities) idle copies cost the
+            step its room."""
+            import jax
+            from ..dist.staging import stage_zeros
+            arr = nd.NDArray(jax.ShapeDtypeStruct(tuple(shape),
+                                                  onp.dtype(dtype)),
+                             ctx=ctx0)
+            chunk = arr._chunk
+
+            def fill():
+                chunk.arr = stage_zeros(shape, sharding, dtype)
+            chunk.force = fill
+            return arr
+
         p_sh = self._param_shardings
         if shared_group is not None:
             # shared_module semantics (executor_group.py:560-585): share the
@@ -475,7 +543,7 @@ class MeshExecutorGroup(object):
                                 if n in self._grad_names
                                 and n not in fresh
                                 and n in shared_group._grad_dict else
-                                ([zeros_with(shape_of[n], p_sh[n])]
+                                ([lazy_zeros(shape_of[n], p_sh[n])]
                                  if n in self._grad_names else None)
                                 for n in param_names]
             self._grad_dict = {n: b[0] for n, b in zip(param_names,
@@ -491,7 +559,7 @@ class MeshExecutorGroup(object):
             # gradients shard exactly like their params: GSPMD reduces them
             # over 'dp' only, and a tp-sharded weight keeps a tp-sharded
             # grad — no gather ever materializes the full tensor
-            self.grad_arrays = [[zeros_with(shape_of[n], p_sh[n])]
+            self.grad_arrays = [[lazy_zeros(shape_of[n], p_sh[n])]
                                 if n in self._grad_names else None
                                 for n in param_names]
             self._grad_dict = {n: b[0] for n, b in zip(param_names,
@@ -504,8 +572,9 @@ class MeshExecutorGroup(object):
 
         # persistent output NDArrays (lazy force thunk, like Executor)
         out_structs = self._out_structs()
-        self._out_arrays = [nd.zeros(s.shape, ctx=ctx0, dtype=s.dtype)
-                            for s in out_structs]
+        self._out_arrays = [
+            lazy_zeros(s.shape, jax.sharding.SingleDeviceSharding(
+                ctx0.jax_device()), s.dtype) for s in out_structs]
 
     # ------------------------------------------------------------------
     def bind_exec(self, data_shapes, label_shapes, shared_group=None,
@@ -599,8 +668,11 @@ class MeshExecutorGroup(object):
         label_names = set(self._label_names)
         grad_names = list(self._grad_names)
 
+        index_inputs = self._index_inputs
+
         def cast(name, v):
-            if cdt is not None and name not in label_names:
+            if cdt is not None and name not in label_names \
+                    and name not in index_inputs:
                 return v.astype(cdt)
             return v
 
@@ -616,7 +688,15 @@ class MeshExecutorGroup(object):
                 v = fake_cast(jnp, v, act_cast)
             return v
 
-        def run_fwd(params, aux, inputs, rng, is_train):
+        def run_fwd(params, aux, inputs, rng, is_train, counts=None):
+            """`counts`: a dict that takes what the ops counted
+            (registry.count) as traced scalars; None collects none."""
+            if counts is not None:
+                from ..registry import counting
+                with counting() as bag:
+                    res = run_fwd(params, aux, inputs, rng, is_train)
+                counts.update(bag)
+                return res
             if not is_train:
                 # narrow-math GEMM seam (precision.quant): entered
                 # INSIDE the traced body so every (re)trace resolves
@@ -630,6 +710,23 @@ class MeshExecutorGroup(object):
             return run_fwd_body(params, aux, inputs, rng, is_train)
 
         def run_fwd_body(params, aux, inputs, rng, is_train):
+            if self.remat and is_train and self._pipe_eval_fn is None:
+                # rematerialization trades HBM for recompute in backward
+                # (the reference's external memonger tool). sqrt-N
+                # contiguous segments each under jax.checkpoint: only
+                # segment boundaries stay live through backward.
+                # "full": recompute everything inside a segment;
+                # "dots": keep matmul/conv outputs (dots_saveable).
+                # parameters reach the segments as they are held and are
+                # cast where a segment reads them (executor.py)
+                vals = [params[n] if n in params else
+                        cast_input(n, inputs[n]) for n in self.arg_names]
+                auxv = [aux[n] for n in self.aux_names]
+                outs, new_aux = self._remat_eval_fn(
+                    vals, auxv, rng, True,
+                    arg_dtypes=[cdt if n in params else None
+                                for n in self.arg_names])
+                return outs, dict(zip(self.aux_names, new_aux))
             vals = [cast(n, params[n]) if n in params else
                     cast_input(n, inputs[n]) for n in self.arg_names]
             # aux (BN moving stats) stay f32: BatchNorm's fcompute runs its
@@ -642,17 +739,6 @@ class MeshExecutorGroup(object):
                 outs, new_aux = self._pipe_eval_fn(vals, auxv, rng,
                                                    is_train)
                 return outs, dict(zip(self.aux_names, new_aux))
-            if self.remat and is_train:
-                # rematerialization trades HBM for recompute in backward
-                # (the reference's external memonger tool). sqrt-N
-                # contiguous segments each under jax.checkpoint: only
-                # segment boundaries stay live through backward.
-                # "full": recompute everything inside a segment;
-                # "dots": keep matmul/conv outputs (dots_saveable).
-                outs, new_aux = self._remat_eval_fn(vals, auxv, rng,
-                                                    True)
-                new_aux = dict(zip(self.aux_names, new_aux))
-                return outs, new_aux
             outs, new_aux = self._eval_fn(vals, auxv, rng, is_train)
             return outs, dict(zip(self.aux_names, new_aux))
 
@@ -661,12 +747,16 @@ class MeshExecutorGroup(object):
         gsh = {n: psh[n] for n in grad_names}  # grads shard like params
 
         def fwd_bwd_math(params, aux, inputs, rng, heads=None,
-                         scale=None):
+                         scale=None, counts=None):
             def f(p):
-                outs, new_aux = run_fwd(p, aux, inputs, rng, True)
-                return tuple(outs), new_aux
+                seen = None if counts is None else {}
+                outs, new_aux = run_fwd(p, aux, inputs, rng, True, seen)
+                return tuple(outs), (new_aux, seen)
 
-            outs, vjp_fn, new_aux = jax.vjp(f, params, has_aux=True)
+            outs, vjp_fn, (new_aux, seen) = jax.vjp(f, params,
+                                                    has_aux=True)
+            if counts is not None:
+                counts.update(seen)
             import jax.numpy as jnp
             hs = tuple(h.astype(o.dtype) for h, o in zip(heads, outs)) \
                 if heads is not None else \
@@ -762,11 +852,11 @@ class MeshExecutorGroup(object):
             probe = kind.endswith(":probe")
 
             def step_math(params, aux, states, inputs, rng, lrs, wds,
-                          ls=None):
+                          ls=None, counts=None):
                 import jax.numpy as jnp
                 if ls is None:
-                    outs, new_aux, grads = fwd_bwd_math(params, aux,
-                                                        inputs, rng)
+                    outs, new_aux, grads = fwd_bwd_math(
+                        params, aux, inputs, rng, counts=counts)
                     finite = None
                 else:
                     # dynamic loss scaling rides the step: scaled heads,
@@ -774,7 +864,8 @@ class MeshExecutorGroup(object):
                     # whether this step's update applies at all
                     scale = ls[0]
                     outs, new_aux, grads = fwd_bwd_math(
-                        params, aux, inputs, rng, scale=scale)
+                        params, aux, inputs, rng, scale=scale,
+                        counts=counts)
                     finite = _grads_finite(jnp, grads)
                 new_params = dict(params)
                 new_states = []
@@ -805,6 +896,10 @@ class MeshExecutorGroup(object):
             if mstat is not None:
                 extra_names.append("macc")
                 extra_sh.append((repl, repl))
+            counter_names = self._counter_names
+            if counter_names:
+                extra_names.append("counters")
+                extra_sh.append(repl)
             if ls_cfg is not None:
                 extra_names.append("ls")
                 extra_sh.append((repl, repl, repl))
@@ -818,19 +913,28 @@ class MeshExecutorGroup(object):
                 import jax.numpy as jnp
                 ex = dict(zip(extra_names, extras))
                 ls = ex.get("ls")
+                counts = {} if counter_names else None
                 sm = step_math(params, aux, states, inputs, rng, lrs,
-                               wds, ls)
+                               wds, ls, counts)
                 if ls is None:
                     outs, new_aux, grads, new_params, new_states = sm
                     new_ls = None
                 else:
                     (outs, new_aux, grads, new_params, new_states,
                      new_ls) = sm
-                res = [outs, new_aux, grads, new_params, new_states]
+                # the gradients stay temporaries of the update: handed
+                # back they are a second copy of every parameter, held
+                # from step to step (read them between backward() and
+                # update(), which runs the plain fwd_bwd program)
+                res = [outs, new_aux, {}, new_params, new_states]
                 if mstat is not None:
                     res.append(_tally_add(
                         jnp, mstat, [inputs[n] for n in mlabels], outs,
                         ex["macc"]))
+                if counter_names:
+                    res.append({n: ex["counters"][n] + counts[n]
+                                if n in counts else ex["counters"][n]
+                                for n in counter_names})
                 if new_ls is not None:
                     res.append(new_ls)
                 if hcfg is not None:
@@ -847,7 +951,7 @@ class MeshExecutorGroup(object):
             donate = (0, 2) if self._platform != "cpu" and not probe \
                 else ()
             base_in = (psh, repl, None, batch, None, None, None)
-            base_out = (self._out_shardings, repl, gsh, psh, None)
+            base_out = (self._out_shardings, repl, {}, psh, None)
             if donate and mstat is not None:
                 donate = donate + (7,)   # macc is always the first extra
             fn = jax_jit(
@@ -888,8 +992,7 @@ class MeshExecutorGroup(object):
                     subs = jnp.broadcast_to(rng, (K,) + rng.shape)
 
                 def body(carry, xs):
-                    (params, aux, states, _outs, _grads, macc, ls,
-                     health) = carry
+                    params, aux, states, _outs, macc, ls, health = carry
                     inp, lr_row, sub = xs
                     if ls is None:
                         outs, aux, grads = fwd_bwd_math(params, aux, inp,
@@ -928,17 +1031,13 @@ class MeshExecutorGroup(object):
                             jnp, hcfg, health, inp, outs, grads,
                             new_params, grad_names_t, mlabels)
                     return (new_params, aux, tuple(new_states), outs,
-                            grads, macc, ls, health), None
+                            macc, ls, health), None
 
-                # last step's outs/grads ride the carry (stacking all K
-                # via scan ys would cost K x params of HBM for grads)
+                # the last step's outs ride the carry; gradients stay
+                # inside the body, as in the per-batch step
                 zero_outs = tuple(jnp.zeros(s.shape, jnp.float32)
                                   for s in out_structs)
-                zero_grads = {n: jnp.zeros(params[n].shape,
-                                           params[n].dtype)
-                              for n in grad_names}
-                carry = (params, aux, states, zero_outs, zero_grads,
-                         macc, ls, health)
+                carry = (params, aux, states, zero_outs, macc, ls, health)
                 # rolled loop, never unrolled: XLA:CPU runs while-loop
                 # bodies on a slow path (8-30x per-step on conv nets),
                 # but unrolling lets XLA fuse ACROSS steps and the
@@ -948,9 +1047,9 @@ class MeshExecutorGroup(object):
                 # also keeps compile time and program size
                 # K-independent on accelerators, where loop bodies run
                 # at full speed anyway.
-                (params, aux, states, outs, grads, macc, ls, health), \
-                    _ = jax.lax.scan(body, carry, (inputs, lrs, subs))
-                return outs, aux, grads, params, states, macc, ls, health
+                (params, aux, states, outs, macc, ls, health), _ = \
+                    jax.lax.scan(body, carry, (inputs, lrs, subs))
+                return outs, aux, params, states, macc, ls, health
 
             # same composable-extras wrapper as the per-batch step
             # (macc, ls, health in fixed order)
@@ -973,11 +1072,11 @@ class MeshExecutorGroup(object):
                 if macc is None:
                     macc = (jnp.zeros((0,), jnp.float32),
                             jnp.zeros((0,), jnp.int32))
-                (outs, new_aux, grads, new_params, new_states, new_macc,
+                (outs, new_aux, new_params, new_states, new_macc,
                  new_ls, new_health) = grouped_math(
                     params, aux, states, inputs, rng, lrs, wds, macc,
                     ex.get("ls"), ex.get("health"))
-                res = [outs, new_aux, grads, new_params, new_states]
+                res = [outs, new_aux, {}, new_params, new_states]
                 if mstat is not None:
                     res.append(new_macc)
                 if new_ls is not None:
@@ -990,7 +1089,7 @@ class MeshExecutorGroup(object):
             donate = (0, 2) if self._platform != "cpu" and not probe \
                 else ()
             base_in = (psh, repl, None, st_batch, None, None, None)
-            base_out = (self._out_shardings, repl, gsh, psh, None)
+            base_out = (self._out_shardings, repl, {}, psh, None)
             if donate and mstat is not None:
                 donate = donate + (7,)
             fn = jax_jit(
@@ -1156,7 +1255,8 @@ class MeshExecutorGroup(object):
                                           self._repl))
 
     def get_params(self, arg_params, aux_params):
-        """Sync host mirrors from device with ONE packed readback.
+        """Sync host mirrors from device with packed readbacks (one up
+        to PACK_BYTES of parameters).
 
         ResNet-50 has ~270 param/aux buffers — per-buffer fetches (the
         reference's copyto-per-array, executor_group.py get_params)
@@ -1192,13 +1292,27 @@ class MeshExecutorGroup(object):
 
             fn = self._jits["pack_params"] = jax.jit(
                 pack, out_shardings=self._repl)
-        flat = onp.asarray(fn([buf._read() for _, buf in items]))
-        off = 0
-        for tgt, buf in items:
-            size = int(onp.prod(buf.shape)) if buf.shape else 1
-            tgt._write(flat[off:off + size].reshape(buf.shape)
-                       .astype(tgt.dtype, copy=False))
-            off += size
+        # one packed fetch per PACK_BYTES of parameters: the pack is a
+        # second copy on the device for as long as its fetch takes, and
+        # one of a whole language model (2.8 GB) does not fit beside a
+        # step in flight.  ResNet-50's 102 MB stay one fetch.
+        groups, size = [[]], 0
+        for item in items:
+            nbytes = 4 * (int(onp.prod(item[1].shape)) if item[1].shape
+                          else 1)
+            if groups[-1] and size + nbytes > PACK_BYTES:
+                groups.append([])
+                size = 0
+            groups[-1].append(item)
+            size += nbytes
+        for group in groups:
+            flat = onp.asarray(fn([buf._read() for _, buf in group]))
+            off = 0
+            for tgt, buf in group:
+                size = int(onp.prod(buf.shape)) if buf.shape else 1
+                tgt._write(flat[off:off + size].reshape(buf.shape)
+                           .astype(tgt.dtype, copy=False))
+                off += size
 
     # ------------------------------------------------------------------
     # device-side input augmentation (mxnet_tpu.data.DeviceAugment)
@@ -1617,6 +1731,13 @@ class MeshExecutorGroup(object):
                     jax.device_put(onp.zeros(self._metric_slots,
                                              onp.int32), self._repl))
             extras += (self._metric_acc,)
+        if self._counter_names:
+            if self._counter_acc is None:
+                self._counter_acc = {
+                    n: jax.device_put(onp.zeros((), onp.float32),
+                                      self._repl)
+                    for n in self._counter_names}
+            extras += (self._counter_acc,)
         ls = self._ls_current()
         if ls is not None:
             extras += (ls,)
@@ -1633,6 +1754,9 @@ class MeshExecutorGroup(object):
         if self._metric_stat is not None:
             self._metric_acc = out[idx]
             self._metric_step_done = True
+            idx += 1
+        if self._counter_names:
+            self._counter_acc = out[idx]
             idx += 1
         if self._ls_cfg is not None:
             self._ls_state = out[idx]
@@ -1677,6 +1801,40 @@ class MeshExecutorGroup(object):
             "sdc_checks").add()
         health = self._sdc_fold_jit()(out1[3], out2[3], out1[-1])
         return out1[:-1] + (health,)
+
+    def _grads_stay_in_the_step(self):
+        """After a one-program step: the gradient arrays hold nothing,
+        and say so when read."""
+        import jax
+        for g in self._grad_dict.values():
+            g._chunk.arr = jax.ShapeDtypeStruct(g.shape, g.dtype)
+            g._chunk.force = _grads_not_kept
+
+    def _bound_outputs_in_flight(self, outs):
+        """Hold the host back while the outputs of the steps in flight
+        pass STEP_OUTPUT_BYTES_IN_FLIGHT: wait for the oldest of them.
+        One step is always let through.  Steps whose outputs could not
+        reach the limit within the client's run-ahead are not tracked
+        at all."""
+        nbytes = sum(o.nbytes for o in outs)
+        if nbytes * STEPS_IN_FLIGHT <= STEP_OUTPUT_BYTES_IN_FLIGHT:
+            return
+        import jax
+        q = self._inflight_outs
+        q.append((outs, nbytes))
+        while len(q) > 1 and sum(b for _o, b in q) > \
+                STEP_OUTPUT_BYTES_IN_FLIGHT:
+            jax.block_until_ready(q.popleft()[0])
+
+    def read_op_counters(self):
+        """What the symbol's ops counted in the train steps since the
+        last call, {name: float}, read back in one transfer; the tally
+        starts again from nought."""
+        acc, self._counter_acc = self._counter_acc, None
+        if acc is None:
+            return {}
+        import jax
+        return {n: float(v) for n, v in jax.device_get(acc).items()}
 
     def _sdc_fold_jit(self):
         """The tiny device comparator folding an SDC probe verdict
@@ -1765,12 +1923,12 @@ class MeshExecutorGroup(object):
         self._note_optimizer_analytic(states, triples)
         with telemetry.span("exec.launch"):
             out = self._launch_step_program(kind, fn, args)
-        outs, new_aux, grads, new_params, new_states = \
+            self._bound_outputs_in_flight(out[0])
+        outs, new_aux, _no_grads, new_params, new_states = \
             self._commit_step_extras(out)
         self._write_outs(outs)
         self._write_aux(new_aux)
-        for n, g in grads.items():
-            self._grad_dict[n]._write(g)
+        self._grads_stay_in_the_step()
         for n, p in new_params.items():
             self._param_dict[n]._write(p)
         for (key, n), ns in zip(triples, new_states):
@@ -1857,12 +2015,11 @@ class MeshExecutorGroup(object):
         self._note_optimizer_analytic(states, triples)
         with telemetry.span("exec.launch"):
             out = self._launch_step_program(kind, fn, args)
-        outs, new_aux, grads, new_params, new_states = \
+        outs, new_aux, _no_grads, new_params, new_states = \
             self._commit_step_extras(out)
         self._write_outs(outs)
         self._write_aux(new_aux)
-        for n, g in grads.items():
-            self._grad_dict[n]._write(g)
+        self._grads_stay_in_the_step()
         for n, p in new_params.items():
             self._param_dict[n]._write(p)
         for (key, n), ns in zip(triples, new_states):

@@ -117,6 +117,10 @@ class Module(BaseModule):
         self._mesh_axes = mesh_axes
         self._param_sharding = list(param_sharding or [])
         self._pipeline_microbatches = pipeline_microbatches
+        if remat is None:
+            # a symbol may name the recomputation it was sized for
+            # (models.afmoe: a step that does not fit without one)
+            remat = symbol.attr("__remat__")
         if remat is None and os.environ.get(
                 "MXNET_BACKWARD_DO_MIRROR", "0") == "1":
             # the reference's activation-recompute switch
@@ -1045,6 +1049,11 @@ class Module(BaseModule):
             grp.disable_device_metric()
             return
         grp.enable_device_metric(eval_metric)
+
+    def _read_op_counters(self):
+        grp = self._exec_group
+        return grp.read_op_counters() if getattr(grp, "fused", False) \
+            else {}
 
     def _sync_params_from_devices(self):
         self._exec_group.get_params(self._arg_params, self._aux_params)

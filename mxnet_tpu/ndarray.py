@@ -444,7 +444,7 @@ def invoke(op, inputs, raw_attrs, out=None, ctx=None):
     if op.variable_args is not None and op.variable_args not in attrs:
         attrs[op.variable_args] = len(inputs)
 
-    n_aux = len(op.aux_names)
+    n_aux = len(op.list_auxiliary_states(attrs))
     vals = [x._read() for x in inputs]
     octx = _registry.OpContext(
         is_train=_autograd.is_training(),
@@ -507,7 +507,8 @@ def _make_op_func(op):
                  if v is not None and not isinstance(v, NDArray)}
         named_in = {k: v for k, v in kwargs.items() if isinstance(v, NDArray)}
         if named_in:
-            order = op.list_arguments(attrs) + list(op.aux_names)
+            order = op.list_arguments(attrs) + \
+                op.list_auxiliary_states(attrs)
             for nm in order:
                 if nm in named_in:
                     inputs.append(named_in.pop(nm))

@@ -78,7 +78,8 @@ def _fully_connected(attrs, ins, octx):
 # ---------------------------------------------------------------------------
 @register("Activation", attr_types={"act_type": str})
 def _activation(attrs, ins, octx):
-    """relu/sigmoid/tanh/softrelu (src/operator/activation-inl.h)."""
+    """relu/sigmoid/tanh/softrelu (src/operator/activation-inl.h), and
+    silu, x * sigmoid(x), the gate of a gated feed-forward."""
     jnp = _jnp()
     x = ins[0]
     t = attrs.get("act_type", "relu")
@@ -90,6 +91,9 @@ def _activation(attrs, ins, octx):
         return [jnp.tanh(x)]
     if t == "softrelu":
         return [jnp.log1p(jnp.exp(-jnp.abs(x))) + jnp.maximum(x, 0)]
+    if t == "silu":
+        import jax
+        return [jax.nn.silu(x)]
     raise ValueError("unknown act_type %s" % t)
 
 

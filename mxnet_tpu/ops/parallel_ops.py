@@ -7,7 +7,11 @@ sharding annotations).
 Both ops read :func:`registry.current_mesh` at trace time (set by
 MeshExecutorGroup around its evaluator closures):
 
-* ``MoE`` — Switch-style top-1 router + capacity-bucketed expert FFN in
+* ``MoE`` — one router, two layers.  With ``experts_held`` one chip's
+  share of an expert-parallel layer: top-k over all the experts, the
+  experts held computed by grouped matrix products over the pairs
+  sorted by expert, no token dropped.  Without it the Switch-style
+  top-1 router + capacity-bucketed expert FFN in
   the GSPMD formulation: dispatch/combine are einsums over an
   expert-major buffer whose expert dim carries a sharding constraint on
   the ``ep`` mesh axis, and the expert weights arrive ``ep``-sharded via
@@ -22,14 +26,50 @@ MeshExecutorGroup around its evaluator closures):
 """
 from __future__ import annotations
 
-from ..registry import register, current_mesh
-from ..parallel.expert_parallel import top1_routing, moe_ffn_block
+from ..registry import register, current_mesh, count as count_op
+from ..parallel.expert_parallel import (top1_routing, moe_ffn_block, route,
+                                        held_experts_ffn, expert_load,
+                                        balance_bias)
 from ..parallel.ring_attention import ring_attention, local_attention
 
 
 def _jnp():
     import jax.numpy as jnp
     return jnp
+
+
+def _held(attrs):
+    """(first, count) of the experts this layer holds, or None for the
+    capacity-bucketed layer that holds them all."""
+    held = attrs.get("experts_held")
+    if held is None:
+        return None
+    first, count = (int(v) for v in held)
+    E = int(attrs["num_experts"])
+    if not (0 <= first and count >= 1 and first + count <= E):
+        raise ValueError("MoE: experts_held=%r is no run of the %d experts"
+                         % (held, E))
+    return first, count
+
+
+def _moe_args(attrs):
+    if _held(attrs) is None:
+        return ("data", "gate_weight", "expert1_weight", "expert1_bias",
+                "expert2_weight", "expert2_bias")
+    return ("data", "router_weight", "experts_gate_weight",
+            "experts_up_weight", "experts_down_weight")
+
+
+def _moe_aux(attrs):
+    return () if _held(attrs) is None else ("router_bias",)
+
+
+_MOE_COUNTERS = ("moe.held_pairs", "moe.load_max", "moe.load_mean",
+                 "moe.dropped")
+
+
+def _moe_counters(attrs):
+    return () if _held(attrs) is None else _MOE_COUNTERS
 
 
 def _moe_infer(attrs, in_shapes, aux):
@@ -39,6 +79,14 @@ def _moe_infer(attrs, in_shapes, aux):
     E = int(attrs["num_experts"])
     f = int(attrs["hidden_size"])
     d = data[-1]
+    held = _held(attrs)
+    if held is not None:
+        n = held[1]
+        in_shapes[1] = (E, d)
+        in_shapes[2] = (n * f, d)
+        in_shapes[3] = (n * f, d)
+        in_shapes[4] = (n * d, f)
+        return in_shapes, [tuple(data), ()], [(E,)]
     in_shapes[1] = (d, E)
     in_shapes[2] = (E, d, f)
     in_shapes[3] = (E, f)
@@ -47,20 +95,38 @@ def _moe_infer(attrs, in_shapes, aux):
     return in_shapes, [tuple(data), ()], aux
 
 
-@register("MoE", arg_names=("data", "gate_weight", "expert1_weight",
-                            "expert1_bias", "expert2_weight",
-                            "expert2_bias"),
+@register("MoE", arg_names=_moe_args, aux_names=_moe_aux,
           attr_types={"num_experts": int, "hidden_size": int,
-                      "capacity_factor": float},
+                      "capacity_factor": float, "num_experts_per_tok": int,
+                      "experts_held": tuple, "score_func": str,
+                      "route_norm": bool, "route_scale": float,
+                      "load_balance_coeff": float},
           required_attrs=("num_experts", "hidden_size"),
           infer_shape=_moe_infer, num_outputs=2,
-          out_names=("output", "aux_loss"))
+          out_names=("output", "aux_loss"), counters=_moe_counters)
 def _moe(attrs, ins, octx):
-    """Switch-style top-1 mixture-of-experts block, ep-shardable.
+    """Mixture-of-experts block, two layers on one router
+    (``parallel.expert_parallel.route``).
 
-    Outputs: the routed expert output (same shape as data) and the
-    scalar load-balance aux loss (add it into the objective via
-    MakeLoss)."""
+    Without ``experts_held``: the Switch layer, top-1 of softmax scores
+    into capacity buckets (tokens over capacity dropped), ReLU experts
+    with biases, ep-shardable.  Outputs: the routed expert output (same
+    shape as data) and the scalar load-balance aux loss (add it into
+    the objective via MakeLoss).
+
+    With ``experts_held=(first, count)``: one chip's share of an
+    expert-parallel layer.  The router scores all ``num_experts``
+    (``score_func``), every token chooses ``num_experts_per_tok`` of
+    them by score plus the selection bias (auxiliary state
+    ``router_bias``, moved after each training step by the sign rule,
+    ``load_balance_coeff``), and the output is the part of the weighted
+    sum that the gated-SiLU experts held here give; no token is
+    dropped under any imbalance.  Expert weights are stacked on rows:
+    gate and up (count * hidden_size, d), down (count * d, hidden_size).
+    The second output is 0."""
+    held = _held(attrs)
+    if held is not None:
+        return _moe_held(attrs, ins, octx, *held)
     import math
 
     jnp = _jnp()
@@ -89,6 +155,37 @@ def _moe(attrs, ins, octx):
     expert_out = _constrain_leading_ep(expert_out)
     y = jnp.einsum("tec,ecd->td", combine.astype(x.dtype), expert_out)
     return [y.reshape(lead + (d,)), aux.astype(f32)]
+
+
+def _moe_held(attrs, ins, octx, first, count):
+    jnp = _jnp()
+    x, wr, wg, wu, wd, bias = ins
+    E, f = int(attrs["num_experts"]), int(attrs["hidden_size"])
+    k = int(attrs.get("num_experts_per_tok", 1))
+    lead, d = x.shape[:-1], x.shape[-1]
+    xt = x.reshape(-1, d)
+    f32 = jnp.float32
+    logits = jnp.dot(xt, wr.astype(xt.dtype).T, preferred_element_type=f32)
+    _scores, chosen, weights = route(
+        logits, k=k, score_func=str(attrs.get("score_func", "softmax")),
+        bias=bias, route_norm=bool(attrs.get("route_norm", False)),
+        route_scale=float(attrs.get("route_scale", 1.0)))
+    def as_kn(w, out):
+        """rows (count * out, in) -> (count, in, out), activation type"""
+        return w.astype(xt.dtype).reshape(count, out, -1).transpose(0, 2, 1)
+
+    y, sizes, dropped = held_experts_ffn(
+        xt, chosen, weights, as_kn(wg, f), as_kn(wu, f), as_kn(wd, d), first)
+    load = expert_load(chosen, E)
+    here = load[first:first + count]
+    count_op("moe.held_pairs", jnp.sum(sizes))
+    count_op("moe.load_max", jnp.max(here))
+    count_op("moe.load_mean", jnp.mean(here))
+    count_op("moe.dropped", dropped)
+    coeff = float(attrs.get("load_balance_coeff", 0.0))
+    new_bias = balance_bias(bias, load, coeff) \
+        if octx.is_train and coeff else bias
+    return [y.reshape(lead + (d,)), jnp.zeros((), f32), new_bias]
 
 
 def _constrain_leading_ep(t):

@@ -1,8 +1,14 @@
 """Expert parallelism (Mixture-of-Experts) over a mesh axis — TPU-native.
 
-Absent from the reference (SURVEY.md §2.3 "TP/EP/CP/Ulysses: Absent —
-design fresh on top of shard_map"). Switch-Transformer-style top-1 routed
-MoE designed for the ICI fabric:
+One router for every MoE layer (:func:`route`: top-k of softmax or
+sigmoid scores plus a selection bias, :func:`balance_bias` its sign
+rule) and two ways to run the experts.  :func:`held_experts_ffn` is one
+chip's share of an expert-parallel layer of gated-SiLU experts: the
+pairs whose expert is held here, sorted by expert, as grouped matrix
+products, with no capacity and no dropped token (``sym.MoE`` with
+``experts_held``).  The rest of the file is the Switch-Transformer-style
+top-1 layer (absent from the reference, SURVEY.md §2.3), designed for
+the ICI fabric:
 
 * tokens live batch-sharded on the 'ep' axis; experts are sharded over the
   same axis (each device owns E/n_ep experts);
@@ -19,12 +25,63 @@ param creation + jit.
 """
 from __future__ import annotations
 
-__all__ = ["top1_routing", "moe_dispatch_combine", "moe_ffn_block",
+__all__ = ["route", "expert_load", "balance_bias", "top1_routing",
+           "held_experts_ffn", "moe_dispatch_combine", "moe_ffn_block",
            "MoELayer"]
 
 
+def route(logits, k=1, score_func="softmax", bias=None, route_norm=False,
+          route_scale=1.0):
+    """The router of every MoE layer here: scores from ``logits`` (T, E)
+    in float32 (``softmax`` over the experts, or ``sigmoid`` of each),
+    the ``k`` experts a token chooses (the largest of scores + ``bias``;
+    the bias steers the choice and never the weight), and the weight
+    of each choice: its score, over the sum of the chosen scores with
+    ``route_norm``, times ``route_scale``.
+
+    Returns (scores (T, E), chosen (T, k) int32, weights (T, k))."""
+    import jax
+    import jax.numpy as jnp
+
+    logits = logits.astype(jnp.float32)
+    if score_func == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    elif score_func == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    else:
+        raise ValueError("score_func must be 'softmax' or 'sigmoid' "
+                         "(got %r)" % (score_func,))
+    steer = scores if bias is None else \
+        scores + jax.lax.stop_gradient(bias.astype(jnp.float32))[None, :]
+    _, chosen = jax.lax.top_k(steer, k)
+    weights = jnp.take_along_axis(scores, chosen, axis=-1)
+    if route_norm:
+        weights = weights / jnp.maximum(
+            jnp.sum(weights, axis=-1, keepdims=True), 1e-20)
+    return scores, chosen.astype(jnp.int32), weights * route_scale
+
+
+def expert_load(chosen, n_experts):
+    """Tokens that chose each expert: (E,) float32.  Counted by
+    comparison, not by scatter: a scatter of 65,536 ones runs one
+    element at a time on the TPU."""
+    import jax.numpy as jnp
+    hit = chosen.reshape(-1)[:, None] == jnp.arange(n_experts)[None, :]
+    return jnp.sum(hit, axis=0, dtype=jnp.float32)
+
+
+def balance_bias(bias, load, coeff):
+    """The selection bias after a step, by the sign rule of loss-free
+    balancing: up for an expert under the mean load, down for one over
+    it, by ``coeff``.  No gradient reaches it."""
+    import jax.numpy as jnp
+    return bias + coeff * jnp.sign(jnp.mean(load) - load)
+
+
 def top1_routing(gate_logits, capacity):
-    """Top-1 router with static capacity buckets.
+    """Top-1 router with static capacity buckets: :func:`route` at
+    k = 1 with softmax scores, then each token's place in its expert's
+    bucket (tokens over capacity are dropped, as in Switch).
 
     gate_logits: (T, E). Returns (dispatch (T, E, C) one-hot, combine
     (T, E, C) prob-weighted, aux_loss scalar — the Switch load-balance loss).
@@ -33,23 +90,168 @@ def top1_routing(gate_logits, capacity):
     import jax.numpy as jnp
 
     T, E = gate_logits.shape
-    probs = jax.nn.softmax(gate_logits, axis=-1)
-    expert = jnp.argmax(probs, axis=-1)                      # (T,)
-    mask = jax.nn.one_hot(expert, E, dtype=gate_logits.dtype)  # (T, E)
+    dtype = gate_logits.dtype
+    probs, chosen, gate = route(gate_logits, k=1)
+    probs, gate = probs.astype(dtype), gate[:, 0].astype(dtype)
+    mask = jax.nn.one_hot(chosen[:, 0], E, dtype=dtype)      # (T, E)
     # position of each token within its expert's capacity bucket
     pos = (jnp.cumsum(mask, axis=0) - 1.0) * mask            # (T, E)
     keep = mask * (pos < capacity)
     pos_idx = jnp.sum(pos * mask, axis=-1).astype(jnp.int32)  # (T,)
-    pos_hot = jax.nn.one_hot(pos_idx, capacity,
-                             dtype=gate_logits.dtype)        # (T, C)
+    pos_hot = jax.nn.one_hot(pos_idx, capacity, dtype=dtype)  # (T, C)
     dispatch = keep[:, :, None] * pos_hot[:, None, :]        # (T, E, C)
-    gate = jnp.sum(probs * mask, axis=-1)                    # (T,)
     combine = dispatch * gate[:, None, None]
     # load-balance aux loss: E * sum_e frac_tokens_e * mean_prob_e
     frac = jnp.mean(mask, axis=0)
     mean_p = jnp.mean(probs, axis=0)
     aux = E * jnp.sum(frac * mean_p)
     return dispatch, combine, aux
+
+
+# True runs the TPU kernel off the TPU, under the Pallas interpreter:
+# the tests patch it, nothing else sets it
+_INTERPRET = False
+
+
+def _grouped_matmul(x, w, group_sizes):
+    """Rows of x (M, K), sorted by group, each times its group's matrix
+    of w (G, K, N); rows past the groups' total give zeros.  On a TPU
+    the library's grouped-matmul kernel (megablox: only the row tiles a
+    group covers are computed), elsewhere ``lax.ragged_dot``."""
+    import jax
+    import jax.numpy as jnp
+
+    M, K = x.shape
+    N = w.shape[2]
+    valid = (jnp.arange(M) < jnp.sum(group_sizes))[:, None]
+    x = jnp.where(valid, x, 0)      # and so its gradient's dead rows
+    tile_m = next((t for t in (512, 256, 128) if M % t == 0), None)
+    if tile_m is not None and K % 128 == 0 and N % 128 == 0 and (
+            jax.default_backend() == "tpu" or _INTERPRET):
+        from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+        tiling = (tile_m, min(K, 1024), min(N, 1024))
+        with jax.named_scope("grouped_matmul"):
+            y = megablox.gmm(x, w, group_sizes, x.dtype, tiling, None, None,
+                             False, _INTERPRET)
+    else:
+        y = jax.lax.ragged_dot(x, w, group_sizes.astype(jnp.int32),
+                               preferred_element_type=jnp.float32
+                               ).astype(x.dtype)
+    return jnp.where(valid, y, 0)
+
+
+def _rows_and_back():
+    """The two moves between tokens (T, d) and sorted pair rows (M, d),
+    each the other's transpose and both gathers in both directions (a
+    scatter of rows runs a row at a time on the TPU, and its time
+    would follow the routing):
+
+    take(x, token, pos, mine): rows[r] = x[token[r]]
+    fold(rows, token, pos, cw): y[t] = sum_j cw[t, j] * rows[pos[t, j]]
+
+    ``token`` (M,) is each row's token, ``pos`` (T, k) each pair's row,
+    ``mine`` / ``cw`` (T, k) which pairs count, and with what weight."""
+    import jax
+    import jax.numpy as jnp
+
+    def gathered(rows, pos):
+        return rows[pos.reshape(-1)].reshape(pos.shape + rows.shape[1:])
+
+    @jax.custom_vjp
+    def fold(rows, token, pos, cw):
+        return jnp.einsum("tk,tkd->td", cw, gathered(rows, pos),
+                          preferred_element_type=jnp.float32)
+
+    def fold_fwd(rows, token, pos, cw):
+        return fold(rows, token, pos, cw), (rows, token, pos, cw)
+
+    def fold_bwd(res, dy):
+        rows, token, pos, cw = res
+        k = pos.shape[1]
+        d_cw = jnp.einsum("td,tkd->tk", dy, gathered(rows, pos),
+                          preferred_element_type=jnp.float32)
+        # row r holds pair (token[r], j): its weight is cw[token[r], j]
+        # for the j with pos[token[r], j] == r
+        at = pos[token] == jnp.arange(rows.shape[0])[:, None]   # (M, k)
+        w_row = jnp.sum(jnp.where(at, cw[token], 0.0), axis=1)
+        d_rows = (dy[token] * w_row[:, None]).astype(rows.dtype)
+        return d_rows, None, None, d_cw.astype(cw.dtype)
+
+    fold.defvjp(fold_fwd, fold_bwd)
+
+    @jax.custom_vjp
+    def take(x, token, pos, mine):
+        return x[token]
+
+    def take_fwd(x, token, pos, mine):
+        return x[token], (token, pos, mine)
+
+    def take_bwd(res, d_rows):
+        token, pos, mine = res
+        return (fold(d_rows, token, pos, mine).astype(d_rows.dtype),
+                None, None, None)
+
+    take.defvjp(take_fwd, take_bwd)
+    return take, fold
+
+
+def _worst_case_rows(T, k, held):
+    """Rows that hold every pair under any routing: every token
+    choosing only experts held here."""
+    return T * min(k, held)
+
+
+def held_experts_ffn(x, chosen, weights, w_gate, w_up, w_down, first):
+    """What the experts held here add to a layer's output: for every
+    token-expert pair whose expert is one of ``first .. first + held``,
+    weight * expert(token), summed by token.  No pair is dropped under
+    any imbalance.
+
+    x: (T, d) tokens; chosen, weights: (T, k) from :func:`route` over
+    ALL experts; w_gate, w_up: (held, d, f), w_down: (held, f, d), the
+    gated-SiLU experts held.  The pairs are sorted by expert, the held
+    ones first, into T * min(k, held) rows: the worst case, every token
+    choosing only experts held here.  The grouped matrix products
+    compute only the row tiles a pair sits in, so their work follows
+    the load; everything else (two gathers each way, the gate) runs
+    over all the rows whatever the routing, so that a step takes the
+    same time under even and uneven routing but for those products.
+
+    Returns (y (T, d), group_sizes (held,) int32: pairs per expert,
+    dropped: the pairs held here that the fold did not read back from
+    a computed row of their own, counted from the indices the gathers
+    use; 0 unless the rows are too few or the sort is wrong)."""
+    import jax
+    import jax.numpy as jnp
+
+    T, d = x.shape
+    k = chosen.shape[1]
+    held = w_gate.shape[0]
+    local = chosen - first                                   # (T, k)
+    here = (local >= 0) & (local < held)
+    key = jnp.where(here, local, held).reshape(-1)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)  # held pairs first
+    pos = jnp.argsort(order).astype(jnp.int32).reshape(T, k)  # a pair's row
+    group_sizes = jnp.sum(key[:, None] == jnp.arange(held)[None, :], axis=0,
+                          dtype=jnp.int32)
+    rows = _worst_case_rows(T, k, held)
+    token = order[:rows] // k
+    has_row = pos < rows
+    pos = jnp.minimum(pos, rows - 1)        # pairs not held: any row, x 0
+    folded = here & has_row & (pos < jnp.sum(group_sizes)) \
+        & (token[pos] == jnp.arange(T)[:, None])
+    dropped = jnp.sum(here) - jnp.sum(folded)
+    take, fold = _rows_and_back()
+    mine = here.astype(jnp.float32)
+
+    xs = take(x, token, pos, mine)
+    h = _grouped_matmul(xs, w_gate, group_sizes)
+    u = _grouped_matmul(xs, w_up, group_sizes)
+    act = (jax.nn.silu(h.astype(jnp.float32))
+           * u.astype(jnp.float32)).astype(x.dtype)
+    out = _grouped_matmul(act, w_down, group_sizes)
+    y = fold(out, token, pos, weights.astype(jnp.float32) * mine)
+    return y.astype(x.dtype), group_sizes, dropped
 
 
 def moe_dispatch_combine(x, wg, expert_fn, axis_name, capacity_factor=1.25):

@@ -84,25 +84,11 @@ def ring_attention(q, k, v, axis_name, causal=False, scale=None):
 
 
 def local_attention(q, k, v, causal=False, scale=None):
-    """Single-device reference attention (for tests / 1-chip fallback)."""
-    import jax.numpy as jnp
-    D = q.shape[-1]
-    scale = scale if scale is not None else 1.0 / (D ** 0.5)
-    s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
-                   k.astype(jnp.float32)) * scale
-    if causal:
-        T, S = s.shape[-2], s.shape[-1]
-        mask = jnp.arange(T)[:, None] >= jnp.arange(S)[None, :]
-        s = jnp.where(mask, s, -1e30)
-    p = _softmax(jnp, s)
-    return jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32)).astype(
-        q.dtype)
-
-
-def _softmax(jnp, s):
-    m = jnp.max(s, axis=-1, keepdims=True)
-    e = jnp.exp(s - m)
-    return e / jnp.sum(e, axis=-1, keepdims=True)
+    """Single-device attention over (B, H, T, D): the package's one
+    blockwise attention (``ops.transformer.attention``), here with as
+    many key-value heads as query heads and no window."""
+    from ..ops.transformer import attention
+    return attention(q, k, v, causal=causal, scale=scale)
 
 
 def ring_self_attention(mesh, axis="sp"):

@@ -56,7 +56,7 @@ class OpDef:
                  aux_names=(), attr_types=None, infer_shape=None,
                  needs_rng=False, variable_args=None, num_outputs=None,
                  alias=(), backward_ignores_head_grads=False,
-                 required_attrs=()):
+                 required_attrs=(), counters=()):
         self.name = name
         self.fcompute = fcompute
         # arg_names may be a callable(attrs) -> names for ops whose input
@@ -64,7 +64,10 @@ class OpDef:
         # OperatorProperty::ListArguments(param).
         self.arg_names = arg_names if callable(arg_names) else tuple(arg_names)
         self.out_names = tuple(out_names)
-        self.aux_names = tuple(aux_names)
+        # like arg_names, a callable(attrs) -> names where the states an
+        # op keeps depend on its attributes (MoE's selection bias)
+        self.aux_names = aux_names if callable(aux_names) \
+            else tuple(aux_names)
         self.attr_types = attr_types or {}
         self._infer_shape = infer_shape
         self.needs_rng = needs_rng
@@ -77,6 +80,14 @@ class OpDef:
         # attrs with no usable default (dmlc::Parameter's .set_default-less
         # fields report "required" through GetAtomicSymbolInfo)
         self.required_attrs = tuple(required_attrs)
+        # names this op may hand to :func:`count` while it traces; a
+        # callable(attrs) -> names where they depend on the attributes
+        self._counters = counters
+
+    def list_counters(self, attrs=None):
+        if callable(self._counters):
+            return list(self._counters(attrs or {}))
+        return list(self._counters)
 
     # -- arity -------------------------------------------------------------
     def list_arguments(self, attrs=None):
@@ -94,6 +105,8 @@ class OpDef:
         return ["%s%d" % (self.out_names[0], i) for i in range(n)]
 
     def list_auxiliary_states(self, attrs=None):
+        if callable(self.aux_names):
+            return list(self.aux_names(attrs or {}))
         return list(self.aux_names)
 
     def num_inputs(self, attrs=None):
@@ -267,3 +280,36 @@ def use_mesh(mesh):
         yield
     finally:
         _CURRENT_MESH.reset(tok)
+
+
+# ---------------------------------------------------------------------------
+# op counters — how an op reports what it counted in a step (MoE: pairs
+# computed, load) without a readback: while an evaluator traces under
+# counting(), count(name, value) adds a traced scalar to the open bag;
+# the evaluator returns the bag with its outputs, the train step folds
+# it into a device tally, and fit reads that once an epoch into the
+# report's counters (telemetry.last_fit()).
+# ---------------------------------------------------------------------------
+_COUNTER_BAG = _contextvars.ContextVar("mxnet_tpu_counter_bag", default=None)
+
+
+def count(name, value):
+    """Add a traced scalar to the named counter of the open bag;
+    nothing where no evaluator collects."""
+    bag = _COUNTER_BAG.get()
+    if bag is not None:
+        import jax.numpy as jnp
+        value = jnp.asarray(value, jnp.float32)
+        bag[name] = bag[name] + value if name in bag else value
+
+
+@_contextlib.contextmanager
+def counting():
+    """Collect :func:`count` calls made while the body traces; yields
+    the bag ``{name: traced f32 scalar}``."""
+    bag = {}
+    tok = _COUNTER_BAG.set(bag)
+    try:
+        yield bag
+    finally:
+        _COUNTER_BAG.reset(tok)

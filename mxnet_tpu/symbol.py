@@ -575,6 +575,7 @@ def _create(op_name, input_syms, attrs, name=None, named_inputs=None):
         attrs[op.variable_args] = len(input_syms)
 
     arg_names = op.list_arguments(attrs)
+    aux_names = op.list_auxiliary_states(attrs)
     named_inputs = named_inputs or {}
     inputs = []
     pos = list(input_syms)
@@ -597,13 +598,13 @@ def _create(op_name, input_syms, attrs, name=None, named_inputs=None):
             "positional input(s) given" % (op.name, len(arg_names),
                                            arg_names, len(pos)))
     unknown = [k for k in named_inputs
-               if k not in arg_names and k not in op.aux_names]
+               if k not in arg_names and k not in aux_names]
     if unknown:
         raise MXNetError(
             "%s got unexpected input(s) %s (arguments for these "
             "attributes: %s)" % (op.name, unknown, arg_names))
     # aux states appended after args, auto-created (BatchNorm moving stats)
-    for nm in op.aux_names:
+    for nm in aux_names:
         if nm in named_inputs:
             head = named_inputs[nm]._heads[0]
             head[0].is_aux = True

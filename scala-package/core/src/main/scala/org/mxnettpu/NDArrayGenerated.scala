@@ -6,7 +6,7 @@ package org.mxnettpu
 // operator; tensor inputs ride positionally, attributes stringify.
 
 object NDArrayGenerated {
-  /** relu/sigmoid/tanh/softrelu (src/operator/activation-inl.h).
+  /** relu/sigmoid/tanh/softrelu (src/operator/activation-inl.h), and
     * args: data: NDArray-or-Symbol; act_type: string, optional
     */
   def Activation(inputs: Seq[NDArray],
@@ -177,6 +177,15 @@ object NDArrayGenerated {
       : IndexedSeq[NDArray] =
     NDArray.invoke("GridGenerator", inputs, params, outputs)
 
+  /** Causal attention of rows cut into sequences of ``seq_len``:
+    * args: query: NDArray-or-Symbol; key: NDArray-or-Symbol; value: NDArray-or-Symbol; causal: boolean, optional; gated: boolean, optional; head_dim: int, required; num_heads: int, required; num_kv_heads: int, required; scale: float, optional; seq_len: int, required; window: int, optional
+    */
+  def GroupedQueryAttention(inputs: Seq[NDArray],
+      params: Map[String, String] = Map.empty,
+      outputs: Seq[NDArray] = null)
+      : IndexedSeq[NDArray] =
+    NDArray.invoke("GroupedQueryAttention", inputs, params, outputs)
+
   /** IdentityAttachKLSparseReg
     * args: data: NDArray-or-Symbol; momentum: float, optional; penalty: float, optional; sparseness_target: float, optional
     */
@@ -258,8 +267,8 @@ object NDArrayGenerated {
       : IndexedSeq[NDArray] =
     NDArray.invoke("MakeLoss", inputs, params, outputs)
 
-  /** Switch-style top-1 mixture-of-experts block, ep-shardable.
-    * args: data: NDArray-or-Symbol; gate_weight: NDArray-or-Symbol; expert1_weight: NDArray-or-Symbol; expert1_bias: NDArray-or-Symbol; expert2_weight: NDArray-or-Symbol; expert2_bias: NDArray-or-Symbol; capacity_factor: float, optional; hidden_size: int, required; num_experts: int, required
+  /** Mixture-of-experts block, two layers on one router
+    * args: data: NDArray-or-Symbol; gate_weight: NDArray-or-Symbol; expert1_weight: NDArray-or-Symbol; expert1_bias: NDArray-or-Symbol; expert2_weight: NDArray-or-Symbol; expert2_bias: NDArray-or-Symbol; capacity_factor: float, optional; experts_held: Shape(tuple), optional; hidden_size: int, required; load_balance_coeff: float, optional; num_experts: int, required; num_experts_per_tok: int, optional; route_norm: boolean, optional; route_scale: float, optional; score_func: string, optional
     */
   def MoE(inputs: Seq[NDArray],
       params: Map[String, String] = Map.empty,
@@ -303,6 +312,15 @@ object NDArrayGenerated {
       : IndexedSeq[NDArray] =
     NDArray.invoke("Proposal", inputs, params, outputs)
 
+  /** y = x / sqrt(mean(x^2) + eps) * gamma over the last axis, or,
+    * args: data: NDArray-or-Symbol; gamma: NDArray-or-Symbol; eps: float, optional; width: int, optional
+    */
+  def RMSNorm(inputs: Seq[NDArray],
+      params: Map[String, String] = Map.empty,
+      outputs: Seq[NDArray] = null)
+      : IndexedSeq[NDArray] =
+    NDArray.invoke("RMSNorm", inputs, params, outputs)
+
   /** RNN
     * args: data: NDArray-or-Symbol; parameters: NDArray-or-Symbol; state: NDArray-or-Symbol; state_cell: NDArray-or-Symbol; bidirectional: boolean, optional; lstm_state_clip_max: float, optional; lstm_state_clip_min: float, optional; mode: string, required; num_layers: int, required; p: float, optional; state_outputs: boolean, optional; state_size: int, required
     */
@@ -338,6 +356,15 @@ object NDArrayGenerated {
       outputs: Seq[NDArray] = null)
       : IndexedSeq[NDArray] =
     NDArray.invoke("RingAttention", inputs, params, outputs)
+
+  /** Rotary position embedding over whole heads (the two halves of a
+    * args: data: NDArray-or-Symbol; head_dim: int, required; seq_len: int, required; theta: float, optional
+    */
+  def RoPE(inputs: Seq[NDArray],
+      params: Map[String, String] = Map.empty,
+      outputs: Seq[NDArray] = null)
+      : IndexedSeq[NDArray] =
+    NDArray.invoke("RoPE", inputs, params, outputs)
 
   /** Hinge-loss output layer (src/operator/svm_output-inl.h).
     * args: data: NDArray-or-Symbol; label: NDArray-or-Symbol; margin: float, optional; regularization_coefficient: float, optional; use_linear: boolean, optional

@@ -6,7 +6,7 @@ package org.mxnettpu
 // operator; tensor inputs ride positionally, attributes stringify.
 
 object SymbolGenerated {
-  /** relu/sigmoid/tanh/softrelu (src/operator/activation-inl.h). */
+  /** relu/sigmoid/tanh/softrelu (src/operator/activation-inl.h), and */
   def Activation(args: Map[String, Symbol],
       params: Map[String, String] = Map.empty,
       name: String = null): Symbol =
@@ -120,6 +120,12 @@ object SymbolGenerated {
       name: String = null): Symbol =
     Symbol.create("GridGenerator", args, params, name)
 
+  /** Causal attention of rows cut into sequences of ``seq_len``: */
+  def GroupedQueryAttention(args: Map[String, Symbol],
+      params: Map[String, String] = Map.empty,
+      name: String = null): Symbol =
+    Symbol.create("GroupedQueryAttention", args, params, name)
+
   /** IdentityAttachKLSparseReg */
   def IdentityAttachKLSparseReg(args: Map[String, Symbol],
       params: Map[String, String] = Map.empty,
@@ -174,7 +180,7 @@ object SymbolGenerated {
       name: String = null): Symbol =
     Symbol.create("MakeLoss", args, params, name)
 
-  /** Switch-style top-1 mixture-of-experts block, ep-shardable. */
+  /** Mixture-of-experts block, two layers on one router */
   def MoE(args: Map[String, Symbol],
       params: Map[String, String] = Map.empty,
       name: String = null): Symbol =
@@ -204,6 +210,12 @@ object SymbolGenerated {
       name: String = null): Symbol =
     Symbol.create("Proposal", args, params, name)
 
+  /** y = x / sqrt(mean(x^2) + eps) * gamma over the last axis, or, */
+  def RMSNorm(args: Map[String, Symbol],
+      params: Map[String, String] = Map.empty,
+      name: String = null): Symbol =
+    Symbol.create("RMSNorm", args, params, name)
+
   /** RNN */
   def RNN(args: Map[String, Symbol],
       params: Map[String, String] = Map.empty,
@@ -227,6 +239,12 @@ object SymbolGenerated {
       params: Map[String, String] = Map.empty,
       name: String = null): Symbol =
     Symbol.create("RingAttention", args, params, name)
+
+  /** Rotary position embedding over whole heads (the two halves of a */
+  def RoPE(args: Map[String, Symbol],
+      params: Map[String, String] = Map.empty,
+      name: String = null): Symbol =
+    Symbol.create("RoPE", args, params, name)
 
   /** Hinge-loss output layer (src/operator/svm_output-inl.h). */
   def SVMOutput(args: Map[String, Symbol],

@@ -84,6 +84,11 @@ def test_params_and_grads_really_sharded():
     assert shard.shape == (16, 32), shard.shape
     assert str(w1.sharding.spec) in ("PartitionSpec('tp', None)",
                                      "PartitionSpec('tp',)")
+    # gradients are read between backward() and update(): the fused
+    # step that update() runs hands none back
+    X, y = _data()
+    mod.forward_backward(next(iter(NDArrayIter(
+        X, y, batch_size=16, label_name="softmax_label"))))
     g1 = eg._grad_dict["fc1_weight"]._read()
     assert g1.addressable_shards[0].data.shape == (16, 32)
     w2 = eg._param_dict["fc2_weight"]._read()  # (10, 64) split on dim 1
