@@ -7,17 +7,21 @@ OpenMP runtime (runtime/recordio.cpp assemble_batch).
 """
 from __future__ import annotations
 
+import collections
 import io as _pyio
 import logging
 import os
 import random
 import threading
+import weakref
 from concurrent.futures import ThreadPoolExecutor
 
 import numpy as onp
 
 from . import ndarray as nd
 from . import recordio
+from .base import MXNetError
+from .context import current_context
 from .io import DataIter, DataBatch, DataDesc
 from . import runtime
 
@@ -343,6 +347,100 @@ def _proc_decode_one(args):
     return img, onp.atleast_1d(header.label)
 
 
+def _stack_chunk(decoded):
+    """What a pool task hands back: its images as ONE contiguous uint8
+    block and its labels as one.  cv2 gives BGR, and RGB is a view that
+    reads it backwards, a strided copy a pixel when it is stacked; made
+    here the copies run on the pool's threads, and the thread that
+    assembles the batch joins a few blocks.  One ``onp.stack`` of the
+    256 views there hands the interpreter back 256 times while the pool
+    and the caller want it: 76 ms alone, 147 beside them (PERF.md,
+    PR 28)."""
+    return (onp.stack([d[0] for d in decoded]),
+            onp.stack([d[1] for d in decoded]))
+
+
+def _proc_decode_chunk(chunk):
+    return _stack_chunk([_proc_decode_one(args) for args in chunk])
+
+
+_DECODE_CHUNK = 8    # records a pool task: 32 tasks a batch of 256
+_READY = 2           # finished batches the producer keeps ahead of next()
+
+
+class _Slot(object):
+    """One batch the producer has begun, in stream order: where the
+    stream stood before it (``before``: epoch, record cursor, batch
+    index; ``rng``: the state of the iterator's draws there) and
+    ``after`` it, its records and their decodes in the pool
+    (``work``), and once ``done`` the batch or the error with what
+    each stage took."""
+
+    __slots__ = ("gen", "before", "after", "rng", "idxs", "pad", "work",
+                 "done", "batch", "error", "stage_ns", "h2d")
+
+    def __init__(self, gen, before, after, rng, idxs, pad):
+        self.gen, self.before, self.after, self.rng = gen, before, after, rng
+        self.idxs, self.pad = idxs, pad
+        self.work, self.done, self.batch, self.error = (), False, None, None
+        self.stage_ns, self.h2d = {}, 0
+
+    def cancel(self):
+        for fut in self.work:
+            fut.cancel()
+
+
+class _RunAhead(object):
+    """What ``next()`` and the producer thread share.  It holds no
+    reference to the iterator: a parked producer keeps a dropped
+    iterator from nothing, and the finalizer stops it through this."""
+
+    def __init__(self):
+        # an RLock: a finalizer may run wherever the collector does
+        self.cond = threading.Condition(threading.RLock())
+        self.slots = collections.deque()   # begun and not taken
+        self.cursor = None     # (epoch, cur, batch index) of the next slot
+        self.gen = 0           # raised when a reset throws the slots away
+        self.stop = False
+        self.thread = None
+        self.ctx = None        # the context the first next() ran under
+
+
+def _run_ahead(ref, state):
+    """The producer thread: one batch a turn while fewer than
+    ``_READY`` wait to be taken.  It holds the iterator for a turn
+    only."""
+    while True:
+        with state.cond:
+            while not state.stop and \
+                    sum(s.done for s in state.slots) >= _READY:
+                state.cond.wait()
+            if state.stop:
+                return
+        it = ref()
+        if it is None:
+            return
+        it._produce(state)
+        del it
+
+
+def _stop_ahead(state, pool):
+    """``close()`` and the finalizer: stop the producer, drop what it
+    made, join it (but for a finalizer that runs on it), and let the
+    decode pool go."""
+    with state.cond:
+        state.stop = True
+        state.gen += 1
+        for slot in state.slots:
+            slot.cancel()
+        state.slots.clear()
+        state.cond.notify_all()
+    thread = state.thread
+    if thread is not None and thread is not threading.current_thread():
+        thread.join()
+    pool.shutdown(wait=False, cancel_futures=True)
+
+
 class ImageRecordIter(DataIter):
     """RecordIO image iterator with threaded decode + native batch assembly
     (src/io/iter_image_recordio_2.cc ImageRecordIter).
@@ -356,9 +454,25 @@ class ImageRecordIter(DataIter):
     ``device_augment=True``, on the accelerator: the batch ships as
     uint8 NHWC (4x fewer host->device bytes than f32 CHW) and ONE
     jitted program does mirror+normalize+transpose device-side —
-    the TPU-native replacement for iter_normalize.h. Wrap with
-    PrefetchingIter (io.py) for background double-buffering like the
-    reference's PrefetcherIter.
+    the TPU-native replacement for iter_normalize.h.
+
+    The iterator runs ahead of its caller, as the reference's does
+    (iter_prefetcher.h): from the first ``next()`` on, a thread of its
+    own decodes, assembles and puts the coming batches and keeps two
+    finished ones waiting, the next batch's decodes in the pool while
+    this one is assembled; ``next()`` takes the oldest.  It goes on
+    into the next epoch, and ``reset()`` adopts what is waiting.  The
+    stream does not depend on how far ahead it was: for one seed and
+    one sequence of ``next`` / ``reset`` / ``set_epoch`` calls the
+    batches are the same, bit for bit, as if each were made when asked
+    for; a ``reset()`` or ``set_epoch()`` that leaves the stream the
+    producer is on throws its batches away and puts ``rng`` back to
+    where it stood before the oldest of them drew.  Every batch gets a
+    fresh 64-byte-aligned host buffer that nothing writes again (the
+    NDArray IS that memory and the chip reads it later), so batches a
+    caller holds stay as they were.  ``close()`` stops and joins the
+    thread and shuts the decode pool down (the iterator cannot be used
+    afterwards); dropping the iterator does the same.
 
     ``device_augment="defer"`` goes one step further: the iterator
     emits raw uint8 NHWC wire batches plus deterministic per-batch
@@ -459,7 +573,9 @@ class ImageRecordIter(DataIter):
                 "image; rand_crop needs fresh geometry every epoch — "
                 "use the streaming path for random-crop training, or "
                 "device_augment='defer' (crop runs in-program)")
-        self.seq = list(range(len(self.rec)))
+        self._base_seq = list(range(len(self.rec)))
+        self._orders = {}        # epoch -> shuffled order, the last two
+        self.seq = self._base_seq
         self.cur = 0
         # NOTE on staging: each batch gets a FRESH host buffer, 64-byte
         # aligned (runtime.assemble_batch), so that nd.array of it on the
@@ -468,9 +584,14 @@ class ImageRecordIter(DataIter):
         # double-buffer ring (iter_prefetcher.h pattern) was tried and
         # reverted for the same reason: a recycled buffer would alias any
         # still-live batch NDArray (and downstream TPU transfers read the
-        # alias asynchronously). runtime.core.HostPool remains available
-        # (and assemble_batch takes ``out=``) for callers that own the
-        # buffer lifetime end-to-end.
+        # alias asynchronously); running ahead changes nothing of that:
+        # two batches wait, one is in assembly, each in memory of its
+        # own. runtime.core.HostPool remains available (and
+        # assemble_batch takes ``out=``) for callers that own the buffer
+        # lifetime end-to-end.
+        self._ahead = _RunAhead()
+        self._finalizer = weakref.finalize(self, _stop_ahead, self._ahead,
+                                           self.pool)
         # decode-time crop geometry: random only on the host-augment
         # streaming path; "defer" decodes deterministically (the
         # in-program pad+crop supplies the randomness)
@@ -491,20 +612,33 @@ class ImageRecordIter(DataIter):
         self._reshuffle()
         self.cur = 0
         self._batch_seq = 0
+        self._follow()
 
-    def _reshuffle(self):
+    def _order(self, epoch):
         """Epoch k's order is a pure function of ``(seed, k)`` —
         re-drawn from the FIXED base order, never cumulatively — so
         ``set_epoch(k)`` replays it exactly regardless of how many
         resets this process has seen (the resume-replay contract; a
-        cumulative ``rng.shuffle`` would depend on the reset COUNT)."""
+        cumulative ``rng.shuffle`` would depend on the reset COUNT),
+        and the producer can draw epoch k + 1's before ``reset()``
+        asks for it.  The lists are shared and never written."""
         if not self.shuffle:
-            return
-        from .data.augment import fold_seed
-        rs = onp.random.RandomState(
-            fold_seed(self.seed ^ 0x5bd1e995, self._epoch, 0))
-        self.seq = list(range(len(self.rec)))
-        rs.shuffle(self.seq)
+            return self._base_seq
+        seq = self._orders.get(epoch)
+        if seq is None:
+            from .data.augment import fold_seed
+            rs = onp.random.RandomState(
+                fold_seed(self.seed ^ 0x5bd1e995, epoch, 0))
+            seq = list(self._base_seq)
+            rs.shuffle(seq)
+            for old in list(self._orders):   # both threads come here
+                if abs(old - epoch) > 1:
+                    self._orders.pop(old, None)
+            self._orders[epoch] = seq
+        return seq
+
+    def _reshuffle(self):
+        self.seq = self._order(self._epoch)
 
     def set_epoch(self, epoch):
         """Pin the epoch coordinate (the resume-replay contract).
@@ -515,6 +649,36 @@ class ImageRecordIter(DataIter):
         self._epoch = int(epoch)
         self._batch_seq = 0
         self._reshuffle()
+        self._follow()
+
+    def _follow(self):
+        """After ``reset()`` / ``set_epoch()`` moved this side's
+        position: if the oldest batch the producer has begun starts
+        exactly there (it went on into the epoch ``reset()`` has just
+        set), what it holds is adopted.  Otherwise it is thrown away,
+        ``rng`` put back to where it stood before the oldest of it
+        drew, and the producer begins again from here."""
+        state = self._ahead
+        if state.thread is None:
+            return
+        here = (self._epoch, self.cur, self._batch_seq)
+        with state.cond:
+            if state.slots:
+                if state.slots[0].before == here:
+                    return
+                self.rng.setstate(state.slots[0].rng)
+                for slot in state.slots:
+                    slot.cancel()
+                state.slots.clear()
+            state.gen += 1
+            state.cursor = here
+            state.cond.notify_all()
+
+    def close(self):
+        """Stop and join the producer thread and shut the decode pool
+        down (idempotent).  The iterator cannot be used afterwards;
+        dropping it does the same."""
+        self._finalizer()
 
     @property
     def epoch_coord(self):
@@ -589,41 +753,140 @@ class ImageRecordIter(DataIter):
         self.pool.shutdown(wait=True)
 
     def next(self):
+        if not self._finalizer.alive:
+            raise MXNetError("ImageRecordIter is closed")
         if self.cur >= len(self.seq):
             raise StopIteration
-        idxs = self.seq[self.cur:self.cur + self.batch_size]
-        self.cur += self.batch_size
-        pad = self.batch_size - len(idxs)
-        if pad > 0:
-            if self.round_batch:
-                idxs = idxs + self.seq[:pad]
-            else:
-                pass
         from . import telemetry
-        span = telemetry.span
-        # record read + JPEG decode + resize/crop of the batch: wall time
-        # on this thread while the pool works (or the cache's gather)
-        with span("input.decode"):
+        state = self._ahead
+        with state.cond:
+            if state.thread is None:
+                state.cursor = (self._epoch, self.cur, self._batch_seq)
+                state.ctx = current_context()
+                state.thread = threading.Thread(
+                    target=_run_ahead, args=(weakref.ref(self), state),
+                    name="ImageRecordIter-producer", daemon=True)
+                state.thread.start()
+            ready = bool(state.slots) and state.slots[0].done
+            while not (state.slots and state.slots[0].done):
+                if not state.thread.is_alive():
+                    raise MXNetError("ImageRecordIter's producer thread "
+                                     "ended without a batch")
+                state.cond.wait(1.0)
+            slot = state.slots.popleft()
+            self._epoch, self.cur, self._batch_seq = slot.after
+            self._reshuffle()
+            state.cond.notify_all()
+        # this side's account: whether the batch was waiting, and the
+        # stages it went through on the producer's thread
+        telemetry.count("input.ready" if ready else "input.waited")
+        for name, ns in slot.stage_ns.items():
+            telemetry.credit(name, ns)
+        if slot.error is not None:
+            raise slot.error
+        telemetry.count("input.h2d_bytes", slot.h2d)
+        return slot.batch
+
+    def _begin(self, state, gen):
+        """Begin the batch at the producer's cursor: note where the
+        stream stands, move the cursor past the batch and hand its
+        records to the decode pool.  At an epoch's end it goes on into
+        the next, as ``reset()`` will.  None where a reset has moved
+        the stream meanwhile."""
+        epoch, cur, batch_seq = state.cursor
+        if cur >= len(self._base_seq):
+            epoch, cur, batch_seq = epoch + 1, 0, 0
+        seq = self._order(epoch)     # a new epoch's shuffle: not locked
+        with state.cond:
+            if state.gen != gen:     # else only this thread moves the
+                return None          # cursor: what was read above holds
+            idxs = seq[cur:cur + self.batch_size]
+            pad = self.batch_size - len(idxs)
+            if pad > 0 and self.round_batch:
+                idxs = idxs + seq[:pad]
+            slot = _Slot(gen, (epoch, cur, batch_seq),
+                         (epoch, cur + self.batch_size,
+                          batch_seq + bool(self._defer)),
+                         self.rng.getstate(), idxs, pad)
+            state.cursor = slot.after
+            state.slots.append(slot)
+        if self.cache_decoded:
+            return slot
+        try:
+            chunks = [idxs[i:i + _DECODE_CHUNK]
+                      for i in range(0, len(idxs), _DECODE_CHUNK)]
+            if self._proc_mode:
+                c, th, tw = self.data_shape
+                ep_seed = self.seed ^ (epoch * 0x9e3779b1 & 0xffffffff)
+                slot.work = [self.pool.submit(_proc_decode_chunk, [
+                    (i, self.resize, th, tw, self._decode_rand_crop, ep_seed)
+                    for i in chunk]) for chunk in chunks]
+            else:
+                slot.work = [self.pool.submit(self._decode_chunk, chunk)
+                             for chunk in chunks]
+        except Exception as err:        # the pool is shut: next() says so
+            slot.error = err
+        with state.cond:
+            if state.gen != gen:     # thrown away before it had its work
+                slot.cancel()
+        return slot
+
+    def _decode_chunk(self, idxs):
+        return _stack_chunk([self._decode_one(i) for i in idxs])
+
+    def _produce(self, state):
+        """One turn of the producer: finish the oldest batch begun and
+        not done.  Its decodes went to the pool a turn ago; the next
+        batch's go there before this one is assembled, so the pool
+        works under the OpenMP loop.  Only this thread draws, in batch
+        order, and the batch's draws come before the next batch is
+        begun: the draws are the serial iterator's."""
+        with state.cond:
+            gen = state.gen
+            slot = next((s for s in state.slots if not s.done), None)
+        if slot is None:
+            slot = self._begin(state, gen)
+            if slot is None:
+                return
+        try:
+            self._make(state, slot)
+        except Exception as err:        # next() raises it in its turn
+            slot.error = err
+        with state.cond:
+            slot.done = True
+            state.cond.notify_all()
+
+    def _make(self, state, slot):
+        from .telemetry import span
+        epoch, _cur, batch_seq = slot.before
+        idxs, ctx = slot.idxs, state.ctx
+        # record read + JPEG decode + resize/crop of the batch: what this
+        # thread still waits for the pool (or the cache's gather)
+        with span("input.decode") as clock:
+            if slot.error is not None:
+                raise slot.error
             if self.cache_decoded:
                 if self._cache is None:
                     self._fill_cache()
                 cache, cl = self._cache
                 imgs = cache[idxs]        # fancy-index gather: memcpy-rate
                 labels = cl[idxs]
-            elif self._proc_mode:
-                c, th, tw = self.data_shape
-                ep_seed = self.seed ^ (self._epoch * 0x9e3779b1
-                                       & 0xffffffff)
-                work = [(i, self.resize, th, tw, self._decode_rand_crop,
-                         ep_seed) for i in idxs]
-                results = list(self.pool.map(_proc_decode_one, work,
-                                             chunksize=4))
             else:
-                results = list(self.pool.map(self._decode_one, idxs))
-        with span("input.assemble"):
+                blocks = [fut.result() for fut in slot.work]
+        slot.stage_ns["input.decode"] = clock.ns
+        mirror = None
+        if self.rand_mirror and not self._defer:
+            with state.cond:
+                if state.gen != slot.gen:
+                    return          # thrown away: it draws nothing
+                mirror = onp.array(
+                    [self.rng.random() < 0.5 for _ in range(len(idxs))],
+                    onp.uint8)
+        self._begin(state, slot.gen)
+        with span("input.assemble") as clock:
             if not self.cache_decoded:
-                imgs = onp.stack([r[0] for r in results])
-                labels = onp.stack([r[1] for r in results])
+                imgs = onp.concatenate([b[0] for b in blocks])
+                labels = onp.concatenate([b[1] for b in blocks])
             label_out = labels if self.label_width > 1 else labels[:, 0]
             label_out = onp.asarray(label_out, onp.float32)
             if self._defer:
@@ -633,28 +896,22 @@ class ImageRecordIter(DataIter):
                 # in one fused stage (4x fewer staged bytes than f32
                 # NCHW)
                 spec = self._aug_spec
-                params = spec.draw(self._data_name, self._epoch,
-                                   self._batch_seq, imgs.shape[0])
-                self._batch_seq += 1
+                params = spec.draw(self._data_name, epoch, batch_seq,
+                                   imgs.shape[0])
                 data = [imgs] + [
                     params[d.name]
                     for d in spec.param_descs(self._data_name,
                                               imgs.shape[0])]
-            else:
-                mirror = None
-                if self.rand_mirror:
-                    mirror = onp.array(
-                        [self.rng.random() < 0.5
-                         for _ in range(len(idxs))], onp.uint8)
-                if not self.device_augment:
-                    imgs = runtime.assemble_batch(
-                        imgs, mean=self.mean, std=self.std / self.scale,
-                        mirror=mirror)
+            elif not self.device_augment:
+                imgs = runtime.assemble_batch(
+                    imgs, mean=self.mean, std=self.std / self.scale,
+                    mirror=mirror)
+        slot.stage_ns["input.assemble"] = clock.ns
         # the calls that hand host memory to jax.device_put (the wire
         # batch of the defer branch goes up in the executor group's
         # staging instead); the transfers complete later
-        with span("input.put"):
-            label = nd.array(label_out)
+        with span("input.put") as clock:
+            label = nd.array(label_out, ctx=ctx)
             h2d = label_out.nbytes
             if not self._defer:
                 h2d += imgs.nbytes
@@ -664,9 +921,10 @@ class ImageRecordIter(DataIter):
                     data = [nd.NDArray(self._device_preprocess(imgs,
                                                                mirror))]
                 else:
-                    data = [nd.array(imgs)]
-            telemetry.count("input.h2d_bytes", h2d)
-        return DataBatch(data, [label], pad=pad)
+                    data = [nd.array(imgs, ctx=ctx)]
+        slot.stage_ns["input.put"] = clock.ns
+        slot.h2d = h2d
+        slot.batch = DataBatch(data, [label], pad=slot.pad)
 
 
 # detection pipeline lives in its own module; re-exported here so the

@@ -160,7 +160,9 @@ class ResizeIter(DataIter):
 
 class PrefetchingIter(DataIter):
     """Background-thread prefetcher (io.py:285; the reference's C++
-    PrefetcherIter wraps dmlc::ThreadedIter the same way).
+    PrefetcherIter wraps dmlc::ThreadedIter the same way).  Not needed
+    around ``ImageRecordIter``, which runs ahead of its caller by
+    itself (wrapped, it is still correct, and one thread more).
 
     Lifecycle: ``close()`` (or the context-manager exit) stops and
     JOINS the worker threads — they used to be fire-and-forget daemons

@@ -62,14 +62,14 @@ from .registry import (Counter, Gauge, Histogram, MetricsRegistry, Scope,
 from .slo import SLOTracker
 from .timeline import StepTimeline
 from . import tracing as _tracing
-from .tracing import (FitReport, Span, clear_trace, count, enabled,
-                      fit_scope, last_fit, record_events, span,
-                      trace_events)
+from .tracing import (OFF_THREAD, FitReport, Span, clear_trace, count,
+                      credit, enabled, fit_scope, last_fit, record_events,
+                      span, trace_events)
 
 __all__ = [
     "MetricsRegistry", "Counter", "Gauge", "Histogram", "Scope",
     "instrument_value", "StepTimeline", "CompileWatch", "Span", "span",
-    "count", "FitReport", "fit_scope", "last_fit",
+    "count", "credit", "OFF_THREAD", "FitReport", "fit_scope", "last_fit",
     "JsonlSink", "MetricsServer", "render_prometheus",
     "ProgramInventory", "FlightRecorder", "load_postmortem",
     "analyze_compiled",

@@ -14,7 +14,9 @@ of clock reads:
   (``Module.fit`` opens one per call; see :func:`fit_scope`), under its
   name, with its self time — the duration minus what its child spans
   cover.  A span on another thread (decode pools, loader workers)
-  reaches the profiler and the ring, not the report;
+  reaches the profiler and the ring, not the report, unless the thread
+  that takes its product credits it (:func:`credit`:
+  ``ImageRecordIter``'s producer);
 * when ``telemetry.enabled()``, it appends ONE complete Chrome event
   (``"ph": "X"`` with a ``dur``) to a bounded ring, keyed by the real
   thread id — Perfetto renders nesting from the containment of
@@ -36,8 +38,11 @@ import time
 
 from jax.profiler import TraceAnnotation
 
-__all__ = ["Span", "span", "count", "enabled", "FitReport", "fit_scope",
-           "last_fit", "trace_events", "clear_trace", "record_events"]
+__all__ = ["Span", "span", "count", "credit", "OFF_THREAD", "enabled",
+           "FitReport", "fit_scope", "last_fit", "trace_events",
+           "clear_trace", "record_events"]
+
+OFF_THREAD = "(off thread)"   # the parent of a row credited from another
 
 _RING_CAPACITY = 16384
 _ring = collections.deque(maxlen=_RING_CAPACITY)
@@ -161,6 +166,20 @@ def count(name, n=1):
     report = _tls.report
     if report is not None:
         report.counters[name] = report.counters.get(name, 0) + n
+
+
+def credit(name, ns):
+    """Add a span that ran on ANOTHER thread to the fit report open on
+    this one: a producer thread clocks its stages where the work
+    happens (the profiler's trace has them there) and hands the
+    durations over with what it made; the thread that takes the product
+    credits them.  The row's parent is :data:`OFF_THREAD`: its time is
+    no part of this thread's, so it is no child of any span here and
+    the self times that tile the ``fit`` call are those of the other
+    rows."""
+    report = _tls.report
+    if report is not None:
+        report.add(name, OFF_THREAD, ns, ns)
 
 
 @contextlib.contextmanager

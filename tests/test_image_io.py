@@ -141,7 +141,7 @@ def test_process_pool_decode_matches_threads(tmp_path):
             np.testing.assert_array_equal(next(t).data[0].asnumpy(),
                                           next(p).data[0].asnumpy())
     finally:
-        p.pool.shutdown(wait=True)
+        p.close()
 
 
 def test_image_iter_imglist(tmp_path):

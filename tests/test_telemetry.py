@@ -658,11 +658,15 @@ def _jpeg_rec(tmp_path, n=10, hw=(12, 12)):
 
 @pytest.mark.parametrize("device_augment", [False, "defer"])
 def test_image_record_iter_stages_under_fit_next(tmp_path, device_augment):
-    """`ImageRecordIter.next()` clocks decode, assemble and put under
-    `fit.next`, and the report counts the host bytes handed to
-    `jax.device_put`: the float32 batch and its labels from the
-    iterator; with the u8 wire (`device_augment="defer"`) a quarter of
-    the batch, handed over in the executor group's staging."""
+    """`ImageRecordIter` clocks decode, assemble and put on its
+    producer's thread and hands each batch's durations over with it:
+    `next()` credits them to the report of the `fit` that took the
+    batch, once a batch taken, as rows that ran off the thread (no
+    children of `fit.next`, whose time is now the wait, and no part of
+    the self times that tile the call).  The report counts the host
+    bytes handed to `jax.device_put`: the float32 batch and its labels
+    from the iterator; with the u8 wire (`device_augment="defer"`) a
+    quarter of the batch, handed over in the executor group's staging."""
     batch, shape = 5, (3, 12, 12)
     it = mx.io.ImageRecordIter(
         path_imgrec=_jpeg_rec(tmp_path), data_shape=shape,
@@ -677,18 +681,26 @@ def test_image_record_iter_stages_under_fit_next(tmp_path, device_augment):
     steps = 4
     assert rep["steps"] == steps
     for name in ("input.decode", "input.assemble", "input.put"):
-        assert rep["spans"][name]["count"] == steps, name
-        assert rep["spans"][name]["parent"] == "fit.next"
-    stages = sum(rep["spans"][n]["total_ns"] for n in
-                 ("input.decode", "input.assemble", "input.put"))
-    assert stages <= rep["spans"]["fit.next"]["total_ns"]
+        row = rep["spans"][name]
+        assert row["count"] == steps, name
+        assert row["parent"] == tel.OFF_THREAD
+        assert 0 < row["max_ns"] <= row["total_ns"] == row["self_ns"]
+    on_thread = [r for r in rep["spans"].values()
+                 if r["parent"] != tel.OFF_THREAD]
+    assert sum(r["self_ns"] for r in on_thread) == rep["wall_ns"]
+    assert not any(r["parent"] == "fit.next" for r in rep["spans"].values())
+    assert rep["spans"]["fit.next"]["self_ns"] \
+        == rep["spans"]["fit.next"]["total_ns"]
     pixels, labels = batch * 3 * 12 * 12, batch * 4
     if device_augment == "defer":
         mirror_draws = batch              # one uint8 a row
         per_step = pixels + mirror_draws + labels
     else:
         per_step = 4 * pixels + labels
-    assert rep["counters"] == {"input.h2d_bytes": per_step * steps}
+    counters = dict(rep["counters"])
+    assert counters.pop("input.ready", 0) + counters.pop("input.waited", 0) \
+        == steps
+    assert counters == {"input.h2d_bytes": per_step * steps}
 
 
 def test_report_shape_is_the_same_enabled_and_disabled():
