@@ -1,9 +1,8 @@
 #!/usr/bin/env bash
 # One-command CI gate mirroring the reference Jenkinsfile stages
 # (Sanity lint :31-41 -> Unit tests :207-258 -> Integration): lint,
-# full test suite, bench-contract smoke, multi-chip dryrun. Nonzero
-# exit on any gate. Runs pure-CPU (the suite's conftest provisions an
-# 8-device virtual mesh; the bench smoke builds its own 1-device env).
+# full test suite, multi-chip dryrun. Nonzero exit on any gate. Runs
+# pure-CPU (the suite's conftest provisions an 8-device virtual mesh).
 set -u
 cd "$(dirname "$0")"
 FAILED=0
@@ -16,11 +15,8 @@ stage() {
 stage "lint (tools/lint.py)"
 python tools/lint.py || FAILED=1
 
-stage "unit + integration suite (pytest tests/, bench smoke deferred)"
-python -m pytest tests/ -q --ignore=tests/test_bench_smoke.py || FAILED=1
-
-stage "bench contract smoke (tests/test_bench_smoke.py)"
-python -m pytest tests/test_bench_smoke.py -q || FAILED=1
+stage "unit + integration suite (pytest tests/)"
+python -m pytest tests/ -q || FAILED=1
 
 stage "convergence gate (train_cifar10 to fixed accuracy)"
 # reference Jenkinsfile integration stage (test_score.py): train a small
@@ -214,15 +210,14 @@ rm -rf "$PF_TMP"
 stage "introspection + health gate (program report + watchdog + bitwise params)"
 # program-introspection contract (docs/api/telemetry.md "Program
 # introspection") plus the judgment layer (same doc, "Regression
-# watchdog"): a 2-epoch fit with the inventory + live roofline + the
-# regression watchdog live must (a) train to BIT-IDENTICAL params vs
+# watchdog"): a 2-epoch fit with the inventory + the regression
+# watchdog live must (a) train to BIT-IDENTICAL params vs
 # telemetry-off, (b) emit a program report with nonzero XLA
-# flops/bytes for the step AND optimizer programs, (c) publish
-# mfu/bound_by/achieved_hbm_gbps gauges and stamp post-warmup step
-# JSONL lines with the roofline fields — with zero post-warmup
-# retraces (asserted in-script) — and (d) arm the watchdog at the
-# warmup boundary, self-calibrate a baseline, and report HEALTHY
-# (zero health incidents on the clean run).
+# flops/bytes for the step AND optimizer programs, (c) write
+# post-warmup step JSONL lines — with zero post-warmup retraces
+# (asserted in-script) — and (d) arm the watchdog at the warmup
+# boundary, self-calibrate a baseline, and report HEALTHY (zero
+# health incidents on the clean run).
 IN_TMP=$(mktemp -d)
 JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=1 \
     timeout 420 python example/image-classification/train_cifar10.py \
@@ -252,9 +247,7 @@ assert "optimizer_update" in kinds, "no optimizer account: %r" % kinds
 steps = [json.loads(l) for l in open(sys.argv[4])
          if json.loads(l).get("kind") == "step"]
 post = [s for s in steps if s["epoch"] >= 1]
-assert post and all("mfu" in s and "bound_by" in s
-                    and "achieved_hbm_gbps" in s for s in post), \
-    "post-warmup step lines lack roofline fields"
+assert post, "no post-warmup step lines"
 health = json.load(open(sys.argv[5]))
 assert health["armed"] and health["calibrated"], health
 assert health["healthy"] and health["incidents"] == [], \
@@ -262,12 +255,10 @@ assert health["healthy"] and health["incidents"] == [], \
 assert health["baseline"] and "step_total_ms" in health["baseline"], \
     "watchdog baseline missing step_total_ms: %r" % health["baseline"]
 print("introspection+health gate: bit-identical params (sha256 "
-      "%s...), %d programs (%s), %d post-warmup steps with live "
-      "roofline (bound_by=%s), watchdog armed+healthy (baseline "
-      "step %.1f ms)" % (a[:16], rep["n_programs"],
-                         ",".join(sorted(kinds)), len(post),
-                         post[-1]["bound_by"],
-                         health["baseline"]["step_total_ms"]))
+      "%s...), %d programs (%s), %d post-warmup steps, watchdog "
+      "armed+healthy (baseline step %.1f ms)"
+      % (a[:16], rep["n_programs"], ",".join(sorted(kinds)), len(post),
+         health["baseline"]["step_total_ms"]))
 PY
 rm -rf "$IN_TMP"
 

@@ -34,20 +34,16 @@ def score(network, dev, batch_size, num_batches, batch_group=1,
     mod.bind(for_training=False, inputs_need_grad=False,
              data_shapes=[("data", data_shape)], label_shapes=None)
     mod.init_params(initializer=mx.init.Xavier(magnitude=2.0))
+    import jax
     from mxnet_tpu.io import DataBatch
     X = np.random.rand(*data_shape).astype(np.float32)
     eg = mod._exec_group
     if getattr(eg, "fused", False):
         # device-resident batch: scoring measures the model, not staging
-        import jax
         batch = DataBatch([mx.nd.NDArray(
             jax.device_put(X, eg._batch_sharding))], [])
     else:
         batch = DataBatch([mx.nd.array(X)], [])
-
-    import jax
-    import jax.numpy as jnp
-    tiny = jax.jit(lambda a: jnp.sum(a.astype(jnp.float32)))
 
     grouped = batch_group > 1 and getattr(eg, "fused", False)
     if grouped:
@@ -72,36 +68,21 @@ def score(network, dev, batch_size, num_batches, batch_group=1,
             mod.forward(batch, is_train=False)
             return mod.get_outputs()[0]._read()
 
-    def barrier(out):
-        # data-dependent 4-byte fetch: cannot return before the forward
-        # that produced ``out`` ran
-        return float(tiny(out))
-
-    # warm up (compile; incl. the barrier program)
+    # warm up (compile)
     for _ in range(2):
         out = dispatch()
-    barrier(out)
+    jax.block_until_ready(out)
     launches = num_batches // batch_group if grouped else num_batches
 
-    def window(n):
-        tic = time.time()
-        out = None
-        for _ in range(n):
-            out = dispatch()
-        # single-queue device: the last forward completes after all
-        # others; the barrier is the window's one readback
-        barrier(out)
-        return time.time() - tic
-
-    # two-window slope: a single window charges the window-ending
-    # readback's fixed cost to a short scoring run. One shared
-    # implementation: bench_timing.two_window_slope.
-    from bench_timing import two_window_slope
-    sl = two_window_slope(window, launches, max(1, launches // 4),
-                          reps=3)
+    tic = time.time()
+    for _ in range(launches):
+        out = dispatch()
+    # single-queue device: the last forward completes after all others;
+    # block_until_ready is a completion barrier
+    jax.block_until_ready(out)
+    dt = time.time() - tic
     eff_batch = batch_size * (batch_group if grouped else 1)
-    rate = sl["n_slope"] * eff_batch / sl["dt"]
-    return rate, (batch_group if grouped else 1)
+    return launches * eff_batch / dt, (batch_group if grouped else 1)
 
 
 if __name__ == "__main__":

@@ -189,10 +189,8 @@ def main():
                              "donation audit) as JSON after training; "
                              "asserts in-process that the step AND "
                              "optimizer programs report nonzero "
-                             "flops/bytes and (for multi-epoch runs) "
-                             "that the live mfu/bound_by roofline "
-                             "gauges were published (the CI "
-                             "introspection gate)")
+                             "flops/bytes (the CI introspection "
+                             "gate)")
     parser.add_argument("--health-report", default=None,
                         help="enable telemetry's regression watchdog "
                              "(armed by fit at the warmup boundary, "
@@ -434,14 +432,6 @@ def main():
         assert "optimizer_update" in by_kind, (
             "program report lacks the optimizer-update account: %r"
             % (by_kind,))
-        gauges = mx.telemetry.registry().snapshot()["gauges"]
-        if args.num_epochs > 1:
-            # the live roofline resolves at the warmup boundary (end of
-            # the first epoch) — any later epoch must have published it
-            for g in ("train.mfu", "train.achieved_hbm_gbps",
-                      "train.bound_by"):
-                assert g in gauges, "roofline gauge %s missing: %r" \
-                    % (g, sorted(gauges))
         logging.info("program report: %d programs -> %s",
                      report["n_programs"], args.program_report)
     if args.health_report:

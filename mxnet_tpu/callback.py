@@ -107,7 +107,7 @@ class Speedometer(object):
     loader it trains through via ``telemetry.set_active_pipeline``).
     ~0% means decode + transfer are fully hidden behind the device
     step; a large value means the epoch is input-bound — visible in
-    the training log, not just in bench.py."""
+    the training log."""
 
     def __init__(self, batch_size, frequent=50):
         self.batch_size = batch_size

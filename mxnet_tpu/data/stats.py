@@ -4,7 +4,7 @@ pipeline, drained as an immutable snapshot.
 Every number answers the question the fed benchmark raised ("is the input path
 or XLA the bottleneck?") without adding a readback anywhere: the stats
 are pure host-side clocks and counters, updated by the stager/transform
-threads and read by ``Speedometer``/``fit``/``bench.py``.
+threads and read by ``Speedometer``/``fit``.
 
 Since the telemetry subsystem landed, PipelineStats is a **view over
 the shared** :class:`mxnet_tpu.telemetry.MetricsRegistry`: each

@@ -3,7 +3,7 @@
 
 Each module exposes ``get_symbol(num_classes, ...)`` with the same signature
 style as the reference's symbol scripts, built on mxnet_tpu.symbol. These
-drive the benchmarks (bench.py) and the example entry points.
+drive the benchmark's cells (``benchmark/``) and the example entry points.
 """
 from . import mlp
 from . import lenet

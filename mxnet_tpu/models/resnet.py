@@ -1,7 +1,7 @@
 """ResNet (example/image-classification/symbols/resnet.py).
 
 Pre-activation residual units (BN→ReLU→Conv), bottleneck for depth>=50.
-This is the headline benchmark network (BASELINE.md ResNet-50).
+This is the benchmark's `resnet50-b256` configuration at depth 50.
 
 Provenance: the unit structure, filter schedules, and layer names are
 partially derived from the reference's model-zoo symbol script so that

@@ -551,8 +551,8 @@ class BaseModule(object):
         eval pass — has compiled by then). The process
         RegressionWatchdog is armed at the same warmup boundary
         (``MXNET_TELEMETRY_WATCHDOG=0`` opts out) and polled between
-        epochs — a steady-state slowdown, roofline
-        drop, straggler or post-warmup retrace becomes ONE structured
+        epochs — a steady-state slowdown, straggler or post-warmup
+        retrace becomes ONE structured
         ``health.*`` incident. All clocks are host-side: no readback, no RNG
         touch, so trained params stay bitwise identical to a
         telemetry-off run (the zero-perturbation contract, ci.sh-gated).
@@ -605,11 +605,6 @@ class BaseModule(object):
                           tl, watch, guardian, report):
         from .. import telemetry
         span = telemetry.span
-        # live roofline state (telemetry.introspect): {"basis", "gauges"}
-        # once the step program's FLOPs/bytes resolve at the warmup
-        # boundary; empty before that (first epoch records carry no
-        # roofline fields — the program has not been analyzed yet)
-        roof = {}
         wd = None   # regression watchdog, armed at the warmup boundary
         # a while loop, not a range: the guardian's rollback-and-skip
         # re-enters an EARLIER epoch after restoring a pre-poison
@@ -642,7 +637,7 @@ class BaseModule(object):
                     mid_verdict = self._fit_epoch_grouped(
                         train_data, epoch, group_k, eval_metric,
                         batch_end_callback, report, tl, watch,
-                        skip=skip, roof=roof, guardian=guardian)
+                        skip=skip, guardian=guardian)
                 else:
                     nbatch = -1
                     data_iter = iter(train_data)
@@ -705,7 +700,7 @@ class BaseModule(object):
                             # last record
                             if tl is not None:
                                 self._timeline_step(
-                                    tl, roof, epoch, nbatch, s_next.ns,
+                                    tl, epoch, nbatch, s_next.ns,
                                     s_fwd_bwd.ns + s_update.ns,
                                     s_metric.ns, 1,
                                     watch.count > n_traces)
@@ -802,13 +797,13 @@ class BaseModule(object):
 
                 train_data.reset()
                 if tl is not None:
-                    wd = self._telemetry_epoch_end(watch, roof, wd,
-                                                   warmed, epoch)
+                    wd = self._telemetry_epoch_end(watch, wd, warmed,
+                                                   epoch)
                 warmed = True
                 report.epochs += 1
                 epoch += 1
 
-    def _telemetry_epoch_end(self, watch, roof, wd, warmed, epoch):
+    def _telemetry_epoch_end(self, watch, wd, warmed, epoch):
         """What an enabled telemetry does between two epochs; returns
         the regression watchdog (armed at the first healthy epoch's end,
         polled at every later one)."""
@@ -818,11 +813,6 @@ class BaseModule(object):
             # blocks, the eval pass) has now traced once: from here
             # on a retrace is a performance bug worth a warning
             watch.mark_warmup_done()
-            # resolve the live-roofline basis at the warmup
-            # boundary: the step program has compiled and
-            # registered; its one-time analysis runs HERE, between
-            # epochs — never on the step path
-            self._resolve_roofline(roof)
             if os.environ.get("MXNET_TELEMETRY_WATCHDOG", "1") != "0":
                 # arm the regression watchdog at the same boundary:
                 # records from here on are steady state. Baseline
@@ -830,9 +820,9 @@ class BaseModule(object):
                 # (MXNET_TELEMETRY_BASELINE), else the first polled
                 # window self-calibrates. Polls run between epochs
                 # — host arithmetic only, never on the step path.
-                # Diagnostics, never fit control (same rule as
-                # _resolve_roofline): a bad baseline path must not
-                # kill the training run at the epoch boundary.
+                # Diagnostics, never fit control: a bad baseline
+                # path must not kill the training run at the epoch
+                # boundary.
                 try:
                     wd = telemetry.health_watchdog().arm(
                         baseline=os.environ.get(
@@ -862,7 +852,7 @@ class BaseModule(object):
         telemetry.flush_metrics("epoch %d" % epoch)
         return wd
 
-    def _timeline_step(self, tl, roof, epoch, nbatch, next_ns, dispatch_ns,
+    def _timeline_step(self, tl, epoch, nbatch, next_ns, dispatch_ns,
                        metric_ns, batch_group, recompile):
         """One step's (or group's) :class:`StepTimeline` record and
         ``"step"`` JSONL line, from the clock reads of the loop's
@@ -872,12 +862,11 @@ class BaseModule(object):
                         dispatch_ms=dispatch_ns * 1e-6,
                         metric_cb_ms=metric_ns * 1e-6,
                         batch_group=batch_group, recompile=recompile)
-        self._roofline_note(rec, roof)
         telemetry.log_event("step", rec)
 
     def _fit_epoch_grouped(self, train_data, epoch, group_k, eval_metric,
                            batch_end_callback, report, tl=None, watch=None,
-                           skip=0, roof=None, guardian=None):
+                           skip=0, guardian=None):
         """One epoch of K-batches-per-program training (``fit``'s
         ``batch_group`` path).  Assembly of block N+1 runs on the host
         while the device computes block N, and the single ``device_put``
@@ -949,7 +938,7 @@ class BaseModule(object):
                 # contract as the per-batch loop)
                 if tl is not None:
                     self._timeline_step(
-                        tl, roof, epoch, last_nbatch, wait_ns[0],
+                        tl, epoch, last_nbatch, wait_ns[0],
                         dispatch_ns, s_metric.ns, group_n,
                         watch.count > n_traces)
             report.steps += group_n
@@ -1014,67 +1003,6 @@ class BaseModule(object):
         if group:
             _flush(nbatch, locals())
         return None
-
-    def _resolve_roofline(self, roof):
-        """Fill ``roof`` with the live-roofline basis — the executor
-        group's analyzed step-program FLOPs/bytes plus n_dev-scaled
-        peaks (``MeshExecutorGroup.roofline_basis`` /
-        ``telemetry.introspect``) — and the ``train.*`` gauges the
-        per-step notes will publish. One-time, at the warmup boundary;
-        the analysis lowers through the jit trace cache under
-        CompileWatch suppression, so the zero-post-warmup-retraces and
-        bitwise-params contracts hold with the roofline live. No-op
-        for executor groups without the introspection surface."""
-        from .. import telemetry
-        grp = getattr(self, "_exec_group", None)
-        basis_fn = getattr(grp, "roofline_basis", None)
-        if basis_fn is None or roof.get("basis"):
-            return
-        try:
-            basis = basis_fn()
-        except Exception:  # noqa: BLE001 - diagnostics, never fit control
-            basis = None
-        if not basis:
-            return
-        scope = telemetry.registry().scope("train")
-        roof["basis"] = basis
-        roof["gauges"] = {
-            "mfu": scope.gauge("mfu"),
-            "achieved_hbm_gbps": scope.gauge("achieved_hbm_gbps"),
-            "achieved_tflops": scope.gauge("achieved_tflops"),
-            "hbm_util": scope.gauge("hbm_util"),
-            "bound_by": scope.gauge("bound_by"),
-        }
-
-    def _roofline_note(self, rec, roof):
-        """Fold the live roofline into one step record + the ``train.*``
-        gauges: the basis' per-step FLOPs/bytes (times the record's true
-        group size) over the record's wall clock — the same arithmetic
-        as bench.py's offline ``xla_achieved_tflops``/``hbm_util``, live
-        (PERF.md's table as gauges). ``bound_by`` publishes as its
-        numeric code (``telemetry.BOUND_BY_CODES``); the record/JSONL
-        carries the string. Pure host arithmetic: no readback, no RNG —
-        the zero-perturbation contract is untouched."""
-        if not roof or not roof.get("basis"):
-            return
-        from ..telemetry.introspect import roofline
-        basis = roof["basis"]
-        k = max(int(rec.get("batch_group", 1)), 1)
-        total_s = max(rec["total_ms"], 1e-6) / 1000.0
-        r = roofline(basis["flops_per_step"] * k,
-                     basis["bytes_per_step"] * k, total_s,
-                     basis["peak_tflops"], basis["peak_hbm_gbps"],
-                     host_wait_fraction=rec["host_wait_ms"]
-                     / max(rec["total_ms"], 1e-9))
-        rec["mfu"] = round(r["mfu"], 6)
-        rec["achieved_hbm_gbps"] = round(r["achieved_hbm_gbps"], 3)
-        rec["bound_by"] = r["bound_by"]
-        gauges = roof["gauges"]
-        gauges["mfu"].set(rec["mfu"])
-        gauges["achieved_hbm_gbps"].set(rec["achieved_hbm_gbps"])
-        gauges["achieved_tflops"].set(round(r["achieved_tflops"], 4))
-        gauges["hbm_util"].set(round(r["hbm_util"], 4))
-        gauges["bound_by"].set(r["bound_by_code"])
 
     def _fit_grouped_ready(self, eval_metric):
         """Whether ``fit(batch_group=K)`` can run grouped device steps.

@@ -1142,8 +1142,7 @@ class MeshExecutorGroup(object):
 
     def _note_optimizer_analytic(self, states, triples):
         """Register the optimizer-update traffic the FUSED train step
-        folds in, as an analytic inventory entry (the separate-program
-        accounting bench.py applies when ``_last_step`` is None): read
+        folds in, as an analytic inventory entry: read
         w/g + write w on f32 plus a read+write of every state leaf —
         5 * 4 * n_params for f32 sgd-momentum. State leaves are
         accounted at their STORAGE dtype: a bf16 opt-state mode
@@ -1191,53 +1190,6 @@ class MeshExecutorGroup(object):
                           "precision_mode": self.precision_mode_name()})
         except Exception:  # noqa: BLE001
             pass
-
-    def program_basis(self, base_kinds):
-        """Analyzed per-STEP (flops, bytes) + n_dev-scaled peaks for
-        the first of ``base_kinds`` this group has registered, or None.
-        Grouped programs divide by their ``batch_group`` so the basis
-        is always one optimizer step's worth; callers re-scale by the
-        record's true group size."""
-        from .. import telemetry
-        inv = telemetry.inventory()
-        for base in base_kinds:
-            name = self._program_names.get(base)
-            if name is None:
-                continue
-            a = inv.analyze(name)
-            if not a or a.get("error") or not a.get("flops"):
-                continue
-            k = max(int(a.get("meta", {}).get("batch_group", 1)), 1)
-            pt, pb = telemetry.device_peaks(self._device_kind)
-            n_dev = max(int(a.get("n_dev", 1)), 1)
-            return {"program": name, "kind": base,
-                    "flops_per_step": a["flops"] / k,
-                    "bytes_per_step": a["bytes_accessed"] / k,
-                    "peak_tflops": pt * n_dev if pt else None,
-                    "peak_hbm_gbps": pb * n_dev if pb else None,
-                    # provenance: the basis is resolved AFTER the policy
-                    # is applied (warmup boundary), so these bytes are
-                    # the mode's true byte basis — the roofline witness
-                    "precision_mode": self.precision_mode_name()}
-        return None
-
-    def roofline_basis(self):
-        """FLOPs/bytes basis for the fit loop's live roofline gauges:
-        the analyzed one-program train step (grouped when the fit runs
-        grouped — already per-step, see :meth:`program_basis`); when
-        only the plain fwd+bwd program exists (optimizer updating as
-        its own program), the optimizer traffic is added analytically,
-        exactly as bench.py's offline ``_xla_cost`` accounts it."""
-        basis = self.program_basis(("train_step_grouped", "train_step"))
-        if basis is not None:
-            return basis
-        basis = self.program_basis(("fwd_bwd",))
-        if basis is not None:
-            n_par = sum(int(onp.prod(self._param_dict[n].shape))
-                        for n in self._grad_names)
-            basis["flops_per_step"] += 4.0 * n_par
-            basis["bytes_per_step"] += 5.0 * 4 * n_par
-        return basis
 
     # ------------------------------------------------------------------
     def set_params(self, arg_params, aux_params):
@@ -1915,7 +1867,7 @@ class MeshExecutorGroup(object):
         args = (params, aux, tuple(states), inputs, rng,
                 np.asarray(lrs, np.float32), np.asarray(wds, np.float32))
         args = args + self._step_extras()
-        # aval skeleton for diagnostics (bench cost analysis) — the real
+        # aval skeleton for diagnostics (cost analysis) — the real
         # buffers are donated below and unusable afterwards
         from .. import telemetry
         self._last_step = (fn, telemetry.aval_skeleton(args))

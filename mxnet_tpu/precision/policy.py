@@ -1,11 +1,10 @@
 """Precision policies — declarative, opt-in byte-count levers.
 
-PERF.md pins the bs128 ResNet-50 step as HBM-bound (~41.8 GB/step,
-``bound_by: "hbm"`` at ~0.16 MFU): the device has ~5x compute headroom
-and the only remaining lever is shipping fewer bytes through the
-compiled program.  A :class:`PrecisionPolicy` names one point in that
-trade space and the Module/Updater/executor stack applies it at the
-existing seams:
+The ResNet-50 train step takes three times its roofline's least time
+(PERF.md §5) and the compiled program moves more bytes than the
+algorithm needs: one lever is shipping fewer bytes through it.  A
+:class:`PrecisionPolicy` names one point in that trade space and the
+Module/Updater/executor stack applies it at the existing seams:
 
 * ``opt_state_dtype="bfloat16"`` — optimizer state (momentum, Adam
   moments) is STORED as bf16 leaves while parameters stay f32 masters;
@@ -40,8 +39,8 @@ Every mode carries the same contract the rest of the repo lives by:
 exact WITHIN-mode reproducibility (same mode + seed -> bit-identical
 params, zero post-warmup retraces), an accuracy gate vs the f32
 reference (ci.sh precision gate), and an introspection witness — the
-``programs.*`` bytes and the live roofline resolve AFTER the policy is
-applied, so ``analyze_compiled`` proves the bytes actually dropped.
+``programs.*`` bytes resolve AFTER the policy is applied, so
+``analyze_compiled`` proves the bytes actually dropped.
 """
 from __future__ import annotations
 

@@ -663,7 +663,7 @@ def register_all():
         serving=_tf_serving,
         chaos_rules=("data.device_put:transient@nth=3",
                      "data.stager:transient@nth=7"),
-        gauges=("train.mfu",),
+        gauges=("health.armed",),
         seed=7))
 
     register(Scenario(

@@ -10,7 +10,7 @@ layers:
 
 * **process-wide jax compilation cache** —
   :func:`enable_persistent_compile_cache`, called by entry points
-  (``chip_smoke.py``, ``bench.py``, the example trainers) before their
+  (``chip_smoke.py``, the example trainers) before their
   first compile, makes sure jax's own persistent compilation cache has
   a directory: ``JAX_COMPILATION_CACHE_DIR`` when the caller's
   environment sets it (jax reads that itself), else the fixed

@@ -192,7 +192,7 @@ class Predictor:
         # remat, optimizer-state dtype, loss scaling — are stripped so
         # an inference-only bucket never builds a segmented-remat
         # evaluator or trips the fused-path requirement. The mode NAME
-        # is kept for telemetry/roofline attribution.
+        # is kept for telemetry attribution.
         src_pol = getattr(module, "_precision", None)
         serve_pol = None
         if src_pol is not None:
@@ -250,7 +250,6 @@ class Predictor:
                 # program registers at warmup as "serving.b<k>.fwd_eval"
                 grp._inventory_owner = "serving.b%d" % b
         self._warmed = False
-        self._roofline = {}   # bucket -> analyzed basis (set by warmup)
 
     # ------------------------------------------------------------------
     @staticmethod
@@ -450,7 +449,6 @@ class Predictor:
                 report[b] = {"warmup_ms": round(ms, 3),
                              "source": source or "jit"}
             self._warmed = True
-            self._resolve_roofline()
         self._warmup_report = report
         return self.stats()
 
@@ -563,8 +561,8 @@ class Predictor:
         an ANALYTIC entry measured off the live executable (XLA cost
         analysis works on deserialized executables too), carrying the
         cache key + warm source in its meta — ``programs.*`` reports
-        and the serving roofline gauges keep working on a warm replica
-        whose jit handles never traced."""
+        keep working on a warm replica whose jit handles never
+        traced."""
         try:
             from .. import telemetry
             analysis = telemetry.analyze_compiled(compiled)
@@ -587,38 +585,6 @@ class Predictor:
         :meth:`ServingStats.release`) — call when discarding a
         Predictor in a long-lived multi-tenant process."""
         self._stats.release()
-
-    def _resolve_roofline(self):
-        """Per-bucket FLOPs/bytes from the program inventory
-        (telemetry.introspect), resolved HERE in warmup — the analysis
-        pass lowers through the jit trace cache and must never run on
-        the request path. ``_run_bucket`` then publishes live
-        ``serving.<i>.b<bucket>.mfu`` / ``achieved_hbm_gbps`` /
-        ``bound_by`` gauges from pure host arithmetic — one triple PER
-        BUCKET, so mixed-size traffic stays attributable on a scrape
-        (a shared gauge would be last-launch-wins). Skipped (gauges
-        absent) when telemetry is disabled."""
-        from .. import telemetry
-        if not telemetry.enabled():
-            return
-        scope = self._stats.scope
-        self._roofline_gauges = {}
-        for b, m in self._modules.items():
-            basis_fn = getattr(m._exec_group, "program_basis", None)
-            if basis_fn is None:
-                continue
-            try:
-                basis = basis_fn(("fwd_eval",))
-            except Exception:  # noqa: BLE001 - diagnostics only
-                basis = None
-            if basis:
-                self._roofline[b] = basis
-                self._roofline_gauges[b] = {
-                    "mfu": scope.gauge("b%d.mfu" % b),
-                    "achieved_hbm_gbps": scope.gauge(
-                        "b%d.achieved_hbm_gbps" % b),
-                    "bound_by": scope.gauge("b%d.bound_by" % b),
-                }
 
     def predict(self, data):
         """Serve one request synchronously (no batching): pad to the
@@ -692,31 +658,15 @@ class Predictor:
             data=[nd.NDArray(pad_batch_rows(arrays[name], bucket))
                   for name, _ in self._data_descs],
             label=None, pad=bucket - rows)
-        basis = self._roofline.get(bucket) if not warmup else None
         if timing is not None:
             t0 = time.perf_counter()
             timing["pad_ms"] = timing.get("pad_ms", 0.0) \
                 + (t0 - t_pad) * 1000.0
-        else:
-            t0 = time.perf_counter() if basis else 0.0
         with telemetry.span("serving.launch", bucket=bucket, rows=rows):
             mod.forward(batch, is_train=False)
             outs = [o.asnumpy()[:rows] for o in mod.get_outputs()]
         if timing is not None:
             timing["device_ms"] = timing.get("device_ms", 0.0) \
                 + (time.perf_counter() - t0) * 1000.0
-        if basis:
-            # live serving roofline: the bucket program's analyzed
-            # FLOPs/bytes over this launch's wall clock (dispatch +
-            # readback — the honest served rate). Host arithmetic only.
-            r = telemetry.roofline(
-                basis["flops_per_step"], basis["bytes_per_step"],
-                time.perf_counter() - t0,
-                basis["peak_tflops"], basis["peak_hbm_gbps"])
-            gauges = self._roofline_gauges[bucket]
-            gauges["mfu"].set(round(r["mfu"], 6))
-            gauges["achieved_hbm_gbps"].set(
-                round(r["achieved_hbm_gbps"], 3))
-            gauges["bound_by"].set(r["bound_by_code"])
         self._stats.note_batch(bucket, rows, warmup=warmup)
         return outs

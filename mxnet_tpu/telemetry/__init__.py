@@ -55,8 +55,7 @@ from .compile_watch import CompileWatch
 from .export import JsonlSink, MetricsServer, render_prometheus
 from .flight import FlightRecorder, load_postmortem
 from .health import RegressionWatchdog
-from .introspect import (ProgramInventory, analyze_compiled, aval_skeleton,
-                         device_peaks, roofline, BOUND_BY_CODES)
+from .introspect import ProgramInventory, analyze_compiled, aval_skeleton
 from .registry import (Counter, Gauge, Histogram, MetricsRegistry, Scope,
                        instrument_value, DEFAULT_MS_BUCKETS)
 from .slo import SLOTracker
@@ -73,7 +72,7 @@ __all__ = [
     "JsonlSink", "MetricsServer", "render_prometheus",
     "ProgramInventory", "FlightRecorder", "load_postmortem",
     "analyze_compiled",
-    "aval_skeleton", "device_peaks", "roofline", "BOUND_BY_CODES",
+    "aval_skeleton",
     "SLOTracker", "RegressionWatchdog",
     "registry", "timeline", "compile_watch", "inventory",
     "flight_recorder", "dump_programs", "enable", "disable",

@@ -1,17 +1,15 @@
-"""RegressionWatchdog — a live judge over the gauges PRs 5/7 publish.
+"""RegressionWatchdog — a live judge over the step records and gauges.
 
-A step-time or MFU regression against the recorded trajectory
-(the bench records kept round over round) used to be visible only when a human re-ran
-bench.py. The watchdog watches the LIVE run instead: off the step
-path, it compares windows of recent :class:`StepTimeline` records and
-registry gauges against a pinned baseline and emits ONE structured
-incident per distinct regression.
+The watchdog watches the LIVE run: off the step path, it compares
+windows of recent :class:`StepTimeline` records and registry gauges
+against a pinned baseline and emits ONE structured incident per
+distinct regression.
 
 * **Arming** — ``Module.fit`` arms the process watchdog at the warmup
   boundary (end of its first epoch — compiles are over, the steady
   state begins) when telemetry is enabled, unless
   ``MXNET_TELEMETRY_WATCHDOG=0``. The baseline is either **pinned**
-  (``baseline=`` dict or a committed ``BASELINE.json``-style snapshot
+  (``baseline=`` dict or a committed :meth:`save_baseline` snapshot
   path, e.g. via ``MXNET_TELEMETRY_BASELINE``) or **self-calibrated**
   from the first post-warmup window (the first polled epoch becomes
   the reference — a clean run is its own baseline and stays silent).
@@ -23,9 +21,6 @@ incident per distinct regression.
   - ``step_total_ms`` / ``dispatch_ms`` — median per-batch step time
     (grouped records normalize by their true K);
   - ``host_wait_fraction`` — the input path's share of the step;
-  - ``train.mfu`` / ``achieved_hbm_gbps`` — the live roofline fields
-    stamped into post-warmup records (skipped when the peak table
-    doesn't know the device — CPU CI never false-fires on MFU);
   - ``eval_dispatch_ms`` — the eval/score loop's records (``loop="eval"``),
     so a served/eval regression trips the same wire;
   - ``compile.post_warmup_retraces`` — any value > 0 is an incident;
@@ -60,8 +55,7 @@ __all__ = ["RegressionWatchdog"]
 # (a transform sleep raises host-wait AND total), the FIRST key below
 # becomes THE incident and the rest ride in its "also" list
 _PRIORITY = ("compile.post_warmup_retraces", "step_total_ms", "dispatch_ms",
-             "host_wait_fraction", "train.mfu",
-             "train.achieved_hbm_gbps", "eval_dispatch_ms",
+             "host_wait_fraction", "eval_dispatch_ms",
              "dist.straggler_ratio", "precision.scale_skips")
 
 
@@ -82,8 +76,7 @@ class RegressionWatchdog(object):
     tolerance : float
         Relative degradation that fires: a step-time median more than
         ``(1 + tolerance)`` × baseline (default 1.0 — 2× — robust to
-        CI timing jitter), an MFU/HBM median below
-        ``(1 - mfu_tolerance)`` × baseline.
+        CI timing jitter).
     min_delta_ms : float
         Absolute floor for time regressions — a 2× blowup of a 0.5 ms
         step is noise, not an incident.
@@ -100,7 +93,7 @@ class RegressionWatchdog(object):
     """
 
     def __init__(self, registry=None, timeline=None, tolerance=1.0,
-                 mfu_tolerance=0.5, min_delta_ms=5.0,
+                 min_delta_ms=5.0,
                  host_wait_margin=0.3, straggler_threshold=2.0,
                  scale_skip_threshold=8, min_samples=3,
                  max_incidents=64, logger=None):
@@ -111,7 +104,6 @@ class RegressionWatchdog(object):
         self._registry = registry
         self._timeline = timeline
         self.tolerance = float(tolerance)
-        self.mfu_tolerance = float(mfu_tolerance)
         self.min_delta_ms = float(min_delta_ms)
         self.host_wait_margin = float(host_wait_margin)
         self.straggler_threshold = float(straggler_threshold)
@@ -150,8 +142,9 @@ class RegressionWatchdog(object):
     def arm(self, baseline=None):
         """Start judging from HERE: records already retained are
         warmup, not evidence. ``baseline`` pins the reference — a dict
-        of medians or a JSON snapshot path (``BASELINE.json`` style:
-        either flat or under a ``"health_baseline"`` key); None
+        of medians or a JSON snapshot path (either flat or under a
+        ``"health_baseline"`` key; keys no judge reads are ignored,
+        so a snapshot an older version wrote still arms); None
         self-calibrates from the first polled window. Re-arming (a new
         fit) restarts calibration against the new program; incident
         history and warn-once state persist for the process."""
@@ -203,7 +196,7 @@ class RegressionWatchdog(object):
 
     def save_baseline(self, path):
         """Write the calibrated baseline as a committed-snapshot JSON
-        (the ``BASELINE.json``-style file :meth:`arm` loads)."""
+        (the file :meth:`arm` loads)."""
         with self._lock:
             if not self._baseline:
                 raise ValueError("no calibrated baseline to save")
@@ -214,11 +207,12 @@ class RegressionWatchdog(object):
         return atomic_json_dump(path, payload)
 
     # -- window stats ---------------------------------------------------
-    def _train_stats(self, train):
+    @staticmethod
+    def _train_stats(train):
         """Per-batch medians of one train window (grouped records
         normalize by their true K)."""
         ks = [max(int(r.get("batch_group", 1)), 1) for r in train]
-        out = {
+        return {
             "step_total_ms": _median(
                 [r["total_ms"] / k for r, k in zip(train, ks)]),
             "dispatch_ms": _median(
@@ -228,14 +222,6 @@ class RegressionWatchdog(object):
                  for r in train]),
             "n_train": len(train),
         }
-        mfus = [r["mfu"] for r in train if r.get("mfu")]
-        if len(mfus) >= self.min_samples:
-            out["train.mfu"] = _median(mfus)
-        hbm = [r["achieved_hbm_gbps"] for r in train
-               if r.get("achieved_hbm_gbps")]
-        if len(hbm) >= self.min_samples:
-            out["train.achieved_hbm_gbps"] = _median(hbm)
-        return out
 
     @staticmethod
     def _eval_stats(evals):
@@ -273,14 +259,6 @@ class RegressionWatchdog(object):
             found["host_wait_fraction"] = {
                 "value": round(v, 4), "baseline": round(b, 4),
                 "threshold": round(b + self.host_wait_margin, 4)}
-        for key in ("train.mfu", "train.achieved_hbm_gbps"):
-            bv, vv = base.get(key), window.get(key)
-            if bv and vv is not None and \
-                    vv < bv * (1.0 - self.mfu_tolerance):
-                found[key] = {"value": round(vv, 6),
-                              "baseline": round(bv, 6),
-                              "threshold": round(
-                                  bv * (1.0 - self.mfu_tolerance), 6)}
         # absolute judges — no baseline needed
         retr = self._registry.counter(
             "compile.post_warmup_retraces").value

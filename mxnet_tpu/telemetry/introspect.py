@@ -1,16 +1,10 @@
-"""Program introspection — every compiled XLA program in the process,
-its true FLOPs/bytes, and the live roofline they imply.
-
-The roofline methodology that proved this stack HBM-bound (PERF.md:
-``bound_by: "hbm"`` at ~41.8 GB/step) lived offline, hand-rolled three
-separate times (bench.py ``_xla_cost``, example/memcost,
-tools/bn_pallas_probe). This module makes it first-class runtime
-observability:
+"""Program introspection — every compiled XLA program in the process
+and XLA's own account of its FLOPs and bytes.
 
 * :func:`analyze_compiled` — THE one cost/memory-analysis helper: a jax
   ``Compiled`` in, ``{"flops", "bytes_accessed", "temp_bytes", ...}``
-  out. The three offline consumers now ride it, so the recorded numbers
-  cannot drift from the live gauges.
+  out (example/memcost, tools/bn_pallas_probe and
+  tools/hlo_byte_audit ride it).
 * :class:`ProgramInventory` — every jitted program the stack runs (fit
   step, grouped scan, optimizer update, padded eval, each serving
   bucket) registers its jit handle + aval skeleton at first launch
@@ -22,59 +16,23 @@ observability:
   contract holds with introspection live. Analyzed numbers publish as a
   ``programs.*`` gauge scope and as a JSON report
   (:meth:`dump_programs` / ``telemetry.dump_programs``).
-* :func:`roofline` + :func:`device_peaks` — the per-step
-  ``mfu`` / ``achieved_hbm_gbps`` / ``bound_by`` arithmetic the fit loop
-  and the serving Predictor publish live (docs/how_to/perf.md §10),
-  using the same per-chip peak table and the same n_dev scaling bench.py
-  reports offline — the two agree by construction.
 
-Scaling note (the bench.py ``_xla_cost`` contract): ``cost_analysis()``
-reports the PER-DEVICE partitioned module; inventory entries scale by
-the mesh size (``n_dev``) so totals compare against n_dev-scaled peaks.
+The program publishes counts and bytes, never a share of a peak: the
+one peak table and the only utilisation figures are the benchmark's
+(``benchmark/peaks.py``, ``step_mfu_pct``, ``train_step_roofline``),
+over a traced device time.
+
+Scaling note: ``cost_analysis()`` reports the PER-DEVICE partitioned
+module; inventory entries scale by the mesh size (``n_dev``) so totals
+are whole-mesh.
 """
 from __future__ import annotations
 
 import collections
-import os
 import threading
 import time
 
-__all__ = ["analyze_compiled", "device_peaks", "roofline",
-           "aval_skeleton", "ProgramInventory", "BOUND_BY_CODES"]
-
-# per-chip peaks by device-kind substring: (bf16 TFLOP/s, HBM GB/s).
-# Shared with bench.py's offline roofline — ONE table, so the live
-# gauges and the recorded BENCH_* numbers can never disagree on peaks.
-_PEAKS = [("v6", 918.0, 1640.0), ("trillium", 918.0, 1640.0),
-          ("v5p", 459.0, 2765.0),
-          ("v5e", 197.0, 819.0), ("v5 lite", 197.0, 819.0),
-          ("v5lite", 197.0, 819.0),
-          ("v4", 275.0, 1228.0), ("v3", 123.0, 900.0), ("v2", 45.0, 700.0)]
-
-# bound_by classification as a Prometheus-representable gauge code
-BOUND_BY_CODES = {"compute": 0, "hbm": 1, "host-wait": 2}
-
-
-def device_peaks(device_kind):
-    """Per-chip (peak bf16 TFLOP/s, peak HBM GB/s) for a jax
-    ``device_kind`` string, or ``(None, None)`` when unknown (e.g. the
-    CPU backend). ``MXNET_PEAK_TFLOPS`` / ``MXNET_PEAK_HBM_GBPS``
-    override PER COMPONENT — setting one to calibrate compute must not
-    null the table's bandwidth peak (that would make ``hbm_util`` read
-    0 and ``bound_by`` unable to ever say "hbm")."""
-    kind = str(device_kind or "").lower()
-    tf = bw = None
-    for sub, t, b in _PEAKS:
-        if sub in kind:
-            tf, bw = t, b
-            break
-    tf_env = os.environ.get("MXNET_PEAK_TFLOPS")
-    bw_env = os.environ.get("MXNET_PEAK_HBM_GBPS")
-    if tf_env:
-        tf = float(tf_env)
-    if bw_env:
-        bw = float(bw_env)
-    return tf, bw
+__all__ = ["analyze_compiled", "aval_skeleton", "ProgramInventory"]
 
 
 def aval_skeleton(args):
@@ -94,11 +52,11 @@ def analyze_compiled(compiled):
     flops / bytes accessed) + memory analysis (temp / argument / output
     / donated-alias buffer bytes), as one flat dict.
 
-    This is THE shared cost/memory-analysis helper — bench.py
-    ``_xla_cost``, example/memcost and tools/bn_pallas_probe all ride
-    it (their recorded field names are their own; the extraction rule
-    lives here once). Values are PER-DEVICE for partitioned modules
-    (scale by mesh size to compare against n_dev-scaled peaks)."""
+    This is THE shared cost/memory-analysis helper — example/memcost,
+    tools/bn_pallas_probe and tools/hlo_byte_audit all ride it (their
+    recorded field names are their own; the extraction rule lives here
+    once). Values are PER-DEVICE for partitioned modules (scale by
+    mesh size for whole-mesh totals)."""
     ca = compiled.cost_analysis()
     ca = ca[0] if isinstance(ca, list) else (ca or {})
     out = {
@@ -117,35 +75,6 @@ def analyze_compiled(compiled):
         out["alias_bytes"] = int(getattr(ma, "alias_size_in_bytes", 0))
         out["generated_code_bytes"] = int(
             getattr(ma, "generated_code_size_in_bytes", 0))
-    return out
-
-
-def roofline(flops, bytes_accessed, seconds, peak_tflops=None,
-             peak_hbm_gbps=None, host_wait_fraction=0.0):
-    """The roofline numbers one (flops, bytes, wall seconds) triple
-    implies — the SAME arithmetic as bench.py's offline
-    ``xla_achieved_tflops`` / ``hbm_util`` / ``bound_by`` fields, so
-    live gauges and recorded bench numbers agree on the same run.
-
-    ``bound_by``: ``host-wait`` when the input path ate most of the
-    step, else ``hbm`` when the implied HBM utilization crosses 0.5
-    (bench's threshold), else ``compute``."""
-    seconds = max(float(seconds), 1e-9)
-    out = {
-        "achieved_tflops": flops / seconds / 1e12,
-        "achieved_hbm_gbps": bytes_accessed / seconds / 1e9,
-    }
-    out["mfu"] = out["achieved_tflops"] / peak_tflops if peak_tflops \
-        else 0.0
-    out["hbm_util"] = out["achieved_hbm_gbps"] / peak_hbm_gbps \
-        if peak_hbm_gbps else 0.0
-    if host_wait_fraction > 0.5:
-        out["bound_by"] = "host-wait"
-    elif out["hbm_util"] > 0.5:
-        out["bound_by"] = "hbm"
-    else:
-        out["bound_by"] = "compute"
-    out["bound_by_code"] = BOUND_BY_CODES[out["bound_by"]]
     return out
 
 

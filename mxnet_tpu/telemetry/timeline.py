@@ -36,11 +36,6 @@ Record fields (also the docs/api/telemetry.md field table):
   step (the "why was step 412 slow" answer).
 * ``total_ms`` / ``ts`` — the sum of the above clocks and the record's
   wall-clock stamp.
-* ``mfu`` / ``achieved_hbm_gbps`` / ``bound_by`` — the live roofline
-  (fit folds them in via ``BaseModule._roofline_note`` once the step
-  program's FLOPs/bytes resolve at the warmup boundary; absent on
-  first-epoch records and when introspection has no basis — see
-  ``telemetry.introspect``).
 
 Query post-hoc: ``timeline.slowest(k)``, ``timeline.records()``,
 ``timeline.to_jsonl(path)``.

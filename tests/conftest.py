@@ -42,3 +42,32 @@ def _clear_process_warn_dedupe():
     base_module._WARNED_PROCESS.clear()
     yield
     base_module._WARNED_PROCESS.clear()
+
+
+_JAX_CACHE_KNOBS = ("jax_compilation_cache_dir",
+                    "jax_persistent_cache_min_compile_time_secs",
+                    "jax_persistent_cache_min_entry_size_bytes")
+
+
+@pytest.fixture(autouse=True)
+def _jax_compile_cache_stays_in_its_test():
+    """``benchmark.harness.run()``, which tests/benchmark_harness calls
+    in this process, points jax's persistent compilation cache at
+    ``<checkout>/.jax_cache`` and keeps every program, for the whole
+    process. Left on, every later test of the same xdist worker
+    compiles through a directory it shares with the other workers and
+    with earlier runs — and XLA:CPU cannot serialize again an
+    executable that came out of that cache: ``Predictor.warmup(
+    cache_dir=)`` then commits entries that load and fail at launch
+    (``NOT_FOUND: Function add_sqrt_fusion not found``). Put the knobs
+    back after the test that turned them."""
+    import jax
+    before = {k: getattr(jax.config, k) for k in _JAX_CACHE_KNOBS}
+    yield
+    turned = [k for k in _JAX_CACHE_KNOBS
+              if getattr(jax.config, k) != before[k]]
+    for k in turned:
+        jax.config.update(k, before[k])
+    if turned:
+        from jax.experimental.compilation_cache import compilation_cache
+        compilation_cache.reset_cache()

@@ -1,5 +1,5 @@
 """tools/bandwidth/measure.py (reference tools/bandwidth — the KVStore
-allreduce benchmark whose numbers BASELINE.md tracks): smoke-run both
+allreduce benchmark): smoke-run both
 measurement modes on the suite's virtual mesh and validate the output
 contract (finite positive GB/s for the kvstore path and the raw psum)."""
 import os

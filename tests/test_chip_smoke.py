@@ -32,11 +32,27 @@ def _cpu_env(n):
     return virtual_cpu_env(n)
 
 
-# every case here starts a fresh interpreter (and the first compiles
-# ResNet-50 three times over): all slow, outside the tier-1 budget
-pytestmark = pytest.mark.slow
+# every case that starts a fresh interpreter (the first compiles
+# ResNet-50 three times over) is slow, outside the tier-1 budget
+slow = pytest.mark.slow
 
 
+def test_unknown_device_kind_fails_loudly(capsys):
+    """The smoke's peak check reads the benchmark's table (the only one
+    in the repository): the chip the cells run on is known, and a
+    ``device_kind`` without a row fails by name, as ``benchmark/run.py``
+    would later."""
+    sys.path.insert(0, ROOT)
+    import chip_smoke
+    assert chip_smoke.peaks_known("TPU v5 lite")
+    assert "197 bf16 TFLOP/s" in capsys.readouterr().out
+    assert not chip_smoke.peaks_known("TPU v0 imaginary")
+    out = capsys.readouterr().out
+    assert out.startswith("FAILED peaks:") and "TPU v0 imaginary" in out \
+        and "benchmark/peaks.py" in out
+
+
+@slow
 def test_rehearsal_runs_every_leg_and_never_passes():
     import chip_smoke
     proc, lines = _run([sys.executable, SMOKE], _cpu_env(2))
@@ -52,6 +68,7 @@ def test_rehearsal_runs_every_leg_and_never_passes():
     assert "no device was checked" in lines[-1]
 
 
+@slow
 def test_leg_that_raises_exits_nonzero_and_names_the_leg():
     code = ("import sys, chip_smoke\n"
             "def boom(env):\n"
@@ -66,6 +83,7 @@ def test_leg_that_raises_exits_nonzero_and_names_the_leg():
     assert "RuntimeError: forced" in proc.stderr
 
 
+@slow
 def test_no_accelerator_without_explicit_cpu_is_refused():
     """JAX falling back to the CPU on its own is the failure this
     script exists to catch: exit 2, no leg runs."""

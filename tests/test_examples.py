@@ -113,6 +113,15 @@ CASES = [
     ("image-classification/serve_cifar10.py",
      ["--num-epochs", "1", "--clients", "4", "--requests", "8",
       "--max-batch-size", "16"]),
+    # the scoring loop and its completion barrier, per launch and
+    # with batch_group batches a launch (a smoke: the CPU's rate is
+    # no device number)
+    ("image-classification/benchmark_score.py",
+     ["--networks", "resnet-18", "--batch-size", "2",
+      "--num-batches", "4"]),
+    ("image-classification/benchmark_score.py",
+     ["--networks", "resnet-18", "--batch-size", "2",
+      "--num-batches", "4", "--batch-group", "2"]),
     # provisions its own 8-device virtual CPU platform (it is a
     # multi-host demo; the harness's 1-device env is overridden inside)
     ("distributed-training/elastic_virtual_hosts.py",

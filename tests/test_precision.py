@@ -85,7 +85,7 @@ def _assert_equal(a, b):
 
 def _compiled_step(mod):
     """The bound one-program train step, re-acquired through the jit
-    trace cache (same recipe as bench.compiled_step)."""
+    trace cache."""
     fn, structs = mod._exec_group._last_step
     return fn.lower(*structs).compile()
 
@@ -345,21 +345,24 @@ def test_byte_witness_argument_bytes_and_optimizer_account():
     assert acc_f["meta"]["precision_mode"] == "f32"
 
 
-def test_roofline_basis_resolves_mode_bytes():
-    """The live-roofline basis (resolved at the warmup boundary, after
-    the policy applied) must carry the mode's true byte account: lower
-    bytes_per_step under bf16_opt than f32, and the mode name as
-    provenance."""
+def test_inventory_step_bytes_drop_under_bf16_opt_state():
+    """The inventory's account of the train step (registered after the
+    policy applied) carries the mode's true bytes: fewer under
+    bf16_opt than under f32."""
+    from mxnet_tpu import telemetry
+
     f32 = _module()
     bf = _module(precision="bf16_opt")
     _train(f32, 2)
     _train(bf, 2)
-    basis_f = f32._exec_group.roofline_basis()
-    basis_b = bf._exec_group.roofline_basis()
-    assert basis_f and basis_b
-    assert basis_f["precision_mode"] == "f32"
-    assert basis_b["precision_mode"] == "bf16_opt"
-    assert basis_b["bytes_per_step"] < basis_f["bytes_per_step"]
+    inv = telemetry.inventory()
+
+    def step_bytes(mod):
+        a = inv.analyze(mod._exec_group._program_names["train_step"])
+        assert a["flops"] > 0
+        return a["bytes_accessed"]
+
+    assert 0 < step_bytes(bf) < step_bytes(f32)
 
 
 # ------------------------------------------------------------- checkpointing

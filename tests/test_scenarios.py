@@ -88,7 +88,7 @@ def test_contract_list_derived_from_features():
     assert kinds == ["BitwiseRepeat", "ZeroRetraces", "AccuracyFloor"]
     full = _dummy(features=("fit", "telemetry", "checkpoint_resume",
                             "serving_predictor"),
-                  gauges=("train.mfu",), serving=lambda mod: {"ok": True})
+                  gauges=("health.armed",), serving=lambda mod: {"ok": True})
     kinds = [type(c).__name__ for c in full.contracts()]
     assert kinds == ["BitwiseRepeat", "ZeroRetraces", "AccuracyFloor",
                      "GaugePresent", "ResumeParity", "ServingParity"]
@@ -130,7 +130,7 @@ def test_catalog_covers_long_tail_and_pins_real_examples():
 GOOD = {
     "digest": "a" * 64, "repeat_digest": "a" * 64,
     "post_warmup_retraces": 0, "accuracy": 0.97,
-    "gauges": {"train.mfu", "data.cache_shard_bytes"},
+    "gauges": {"health.armed", "data.cache_shard_bytes"},
     "resume_digest": "a" * 64,
     "serving": {"ok": True, "detail": "rows bitwise"},
     "chaos": {"digest": "a" * 64, "reference": "a" * 64,
@@ -173,10 +173,10 @@ def test_accuracy_floor_contract_directions():
 
 
 def test_gauge_present_contract():
-    assert _one(GaugePresent(("train.mfu",)), GOOD).ok
-    v = _one(GaugePresent(("train.mfu", "slo.missing")), GOOD)
+    assert _one(GaugePresent(("health.armed",)), GOOD).ok
+    v = _one(GaugePresent(("health.armed", "slo.missing")), GOOD)
     assert not v.ok and "slo.missing" in v.detail
-    assert not _one(GaugePresent(("train.mfu",)), {}).ok
+    assert not _one(GaugePresent(("health.armed",)), {}).ok
 
 
 def test_resume_parity_contract():

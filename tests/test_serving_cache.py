@@ -440,7 +440,7 @@ def test_jax_compile_cache_dir_is_never_overridden(tmp_path):
             setters += [os.path.join(dirpath, f) for f in files
                         if f.endswith(".py")]
     setters += [os.path.join(root, f) for f in
-                ("bench.py", "chip_smoke.py", "__graft_entry__.py")]
+                ("chip_smoke.py", "__graft_entry__.py")]
     setters = [os.path.relpath(p, root) for p in setters
                if re.search(r"update\(\s*[\"']jax_compilation_cache_dir",
                             open(p).read())]

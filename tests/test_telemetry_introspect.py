@@ -1,5 +1,5 @@
 """mxnet_tpu.telemetry.introspect + flight — program introspection,
-live roofline, crash black box, and dist-labeled exports.
+crash black box, and dist-labeled exports.
 
 Pins the observability contracts ISSUE 7 lands:
 
@@ -8,10 +8,11 @@ Pins the observability contracts ISSUE 7 lands:
 * every fused-module program registers with the ProgramInventory and
   analyzes lazily — with ZERO post-warmup retraces and BITWISE
   identical params while the whole introspection path is live;
-* fit publishes per-step ``mfu`` / ``achieved_hbm_gbps`` / ``bound_by``
-  (gauges + step-record fields) from the same numbers bench.py's
-  offline roofline reads — agreement is by construction (shared
-  helper), and the test re-derives a gauge from the inventory entry;
+* the program publishes times, bytes and counts and never a share of
+  a peak: a step record holds exactly its host clocks, no
+  ``mfu``-family gauge exists in fit or in serving, and nothing under
+  ``mxnet_tpu/`` reads a peak from the environment (the one peak table
+  is ``benchmark/peaks.py``);
 * the FlightRecorder commits postmortems atomically: a crash mid-dump
   leaves only ``.tmp-*``, never a torn committed file;
 * Prometheus/JSONL exports carry ``rank``/``process_count`` labels
@@ -32,8 +33,7 @@ import mxnet_tpu.symbol as sym
 from mxnet_tpu import telemetry as tel
 from mxnet_tpu.io import NDArrayIter
 from mxnet_tpu.telemetry.introspect import (ProgramInventory,
-                                            analyze_compiled,
-                                            device_peaks, roofline)
+                                            analyze_compiled)
 
 
 @pytest.fixture(autouse=True)
@@ -87,7 +87,7 @@ def _params_bytes(mod):
 
 
 # ----------------------------------------------------------------------
-# analyze_compiled / peaks / roofline primitives
+# analyze_compiled, and the yardstick the package does not hold
 # ----------------------------------------------------------------------
 def test_analyze_compiled_fields():
     import jax
@@ -104,32 +104,27 @@ def test_analyze_compiled_fields():
     assert a["argument_bytes"] == 2 * 16 * 16 * 4
 
 
-def test_device_peaks_table_and_override(monkeypatch):
-    tf, bw = device_peaks("TPU v5e")
-    assert (tf, bw) == (197.0, 819.0)
-    assert device_peaks("cpu") == (None, None)
-    monkeypatch.setenv("MXNET_PEAK_TFLOPS", "100")
-    monkeypatch.setenv("MXNET_PEAK_HBM_GBPS", "500")
-    assert device_peaks("cpu") == (100.0, 500.0)
-    # PER-COMPONENT override: calibrating one peak must not null the
-    # table's value for the other (hbm_util would read 0 forever)
-    monkeypatch.delenv("MXNET_PEAK_HBM_GBPS")
-    assert device_peaks("TPU v5p") == (100.0, 2765.0)
-
-
-def test_roofline_classification():
-    # hbm-bound: bytes dominate against a known peak
-    r = roofline(1e12, 900e9, 1.0, peak_tflops=100.0,
-                 peak_hbm_gbps=1000.0)
-    assert r["bound_by"] == "hbm" and r["bound_by_code"] == 1
-    assert r["achieved_hbm_gbps"] == pytest.approx(900.0)
-    assert r["mfu"] == pytest.approx(0.01)
-    # compute (or unknown peaks): default class
-    assert roofline(1e12, 1e9, 1.0)["bound_by"] == "compute"
-    # host-wait dominates everything
-    r = roofline(1e12, 900e9, 1.0, peak_hbm_gbps=1000.0,
-                 host_wait_fraction=0.8)
-    assert r["bound_by"] == "host-wait" and r["bound_by_code"] == 2
+def test_package_holds_no_peak_table_or_peak_option():
+    """Only ``benchmark/`` divides work by time: the package exports no
+    roofline arithmetic and no peak table, and no file under
+    ``mxnet_tpu/`` reads a peak from the environment (the names are
+    spelled in pieces so this file is not a match itself)."""
+    import mxnet_tpu.telemetry.introspect as introspect
+    for name in ("roofline", "device" + "_peaks", "BOUND_BY_CODES"):
+        assert not hasattr(tel, name), name
+        assert not hasattr(introspect, name), name
+    for name in ("ProgramInventory", "analyze_compiled", "aval_skeleton"):
+        assert hasattr(tel, name), name
+    options = ["MXNET_" + "PEAK_" + tail for tail in ("TFLOPS", "HBM_GBPS")]
+    pkg = os.path.dirname(os.path.abspath(mx.__file__))
+    readers = []
+    for dirpath, _, files in os.walk(pkg):
+        for f in files:
+            if f.endswith(".py"):
+                text = open(os.path.join(dirpath, f)).read()
+                readers += [os.path.join(dirpath, f) for o in options
+                            if o in text]
+    assert readers == []
 
 
 # ----------------------------------------------------------------------
@@ -202,50 +197,40 @@ def test_eval_fit_no_per_epoch_recompile():
         - total_before == 2
 
 
-def test_fit_roofline_gauges_and_step_fields():
+_RECORD_KEYS = {"step", "epoch", "nbatch", "loop", "host_wait_ms",
+                "dispatch_ms", "metric_cb_ms", "checkpoint_ms",
+                "batch_group", "recompile", "total_ms", "ts"}
+_UTILISATION = ("mfu", "achieved_hbm_gbps", "achieved_tflops", "hbm_util",
+                "bound_by")
+
+
+@pytest.mark.parametrize("batch_group", [1, 2])
+def test_fit_step_records_are_host_clocks_only(batch_group):
+    """A 3-epoch fit with telemetry on: every step record is exactly
+    its host clocks, no utilisation gauge is published, the retrace
+    counter does not move after the warm-up boundary, and the step
+    program still analyses to flops and bytes for whoever holds a peak
+    table and a device time (``benchmark/``)."""
     before = tel.registry().counter("compile.post_warmup_retraces").value
     tel.enable()
-    mod = _fit(epochs=3)
+    mod = _fit(epochs=3, batch_group=batch_group)
     tel.disable()
     assert tel.registry().counter("compile.post_warmup_retraces").value \
         == before
     recs = tel.timeline().records()
-    first_epoch = [r for r in recs if r["epoch"] == 0]
-    later = [r for r in recs if r["epoch"] >= 1]
-    # basis resolves at the warmup boundary: epoch-0 records have no
-    # roofline fields, every later record does
-    assert all("mfu" not in r for r in first_epoch)
-    assert later and all(
-        "mfu" in r and "bound_by" in r and "achieved_hbm_gbps" in r
-        for r in later)
+    assert {r["epoch"] for r in recs} == {0, 1, 2}
+    assert all(set(r) == _RECORD_KEYS for r in recs), \
+        [sorted(set(r) ^ _RECORD_KEYS) for r in recs
+         if set(r) != _RECORD_KEYS][:1]
+    assert all(r["batch_group"] == batch_group for r in recs)
     gauges = tel.registry().snapshot()["gauges"]
-    for g in ("train.mfu", "train.achieved_hbm_gbps", "train.bound_by",
-              "train.achieved_tflops", "train.hbm_util"):
-        assert g in gauges, g
-    # the gauge re-derives from the inventory entry + the record's own
-    # clock — the same arithmetic bench.py applies offline (shared
-    # helper), so live and offline numbers agree by construction
-    a = tel.inventory().analyze(
-        mod._exec_group._program_names["train_step"])
-    last = later[-1]
-    expect = a["bytes_accessed"] / (last["total_ms"] / 1000.0) / 1e9
-    # record values round to 3 decimals — compare at that precision
-    assert last["achieved_hbm_gbps"] == pytest.approx(expect, rel=0.02,
-                                                      abs=2e-3)
-    assert gauges["train.achieved_hbm_gbps"] == last["achieved_hbm_gbps"]
-    assert last["bound_by"] in ("compute", "hbm", "host-wait")
-
-
-def test_grouped_fit_roofline_scales_by_group():
-    before = tel.registry().counter("compile.post_warmup_retraces").value
-    tel.enable()
-    _fit(epochs=3, batch_group=2)
-    tel.disable()
-    recs = [r for r in tel.timeline().records()
-            if r["epoch"] >= 1 and r["batch_group"] == 2]
-    assert recs and all("mfu" in r for r in recs)
-    assert tel.registry().counter("compile.post_warmup_retraces").value \
-        == before
+    assert not [g for g in gauges
+                if g.startswith("train.") and g.split(".")[-1]
+                in _UTILISATION], sorted(gauges)
+    names = mod._exec_group._program_names
+    kind = "train_step_grouped" if batch_group > 1 else "train_step"
+    a = tel.inventory().analyze(names[kind])
+    assert a["flops"] > 0 and a["bytes_accessed"] > 0
 
 
 def test_introspection_zero_perturbation_bitwise(tmp_path):
@@ -472,26 +457,27 @@ def test_virtual_feed_straggler_gauge():
 
 
 # ----------------------------------------------------------------------
-# serving roofline
+# serving: bucket programs in the inventory, no utilisation gauge
 # ----------------------------------------------------------------------
-def test_serving_roofline_gauges():
+def test_serving_bucket_programs_in_inventory_and_rows_bitwise():
     from mxnet_tpu.serving import Predictor
     X, y = _data()
     mod = _fit(epochs=1)
     tel.enable()
     pred = Predictor(mod, max_batch_size=8)
     pred.warmup()
-    pred.predict(X[:3, :6])
+    served = pred.predict(X[:3, :6])
     tel.disable()
-    snap = pred._stats.scope.snapshot()
-    # per-BUCKET gauges: a 3-row request runs bucket 4 — its triple is
-    # attributable on a scrape even under mixed-size traffic
-    assert "b4.mfu" in snap["gauges"] and "b4.bound_by" in snap["gauges"]
-    assert snap["gauges"]["b4.achieved_hbm_gbps"] > 0
-    # served rows still bitwise vs Module.predict (roofline is
-    # arithmetic only) — quick spot check
-    it = NDArrayIter(X[:3, :6], None, batch_size=3)
+    # a 3-row request runs bucket 4: its program is registered and
+    # analyses to non-zero flops and bytes
+    grp = pred._modules[4]._exec_group
+    a = tel.inventory().analyze(grp._program_names["fwd_eval"])
+    assert a["flops"] > 0 and a["bytes_accessed"] > 0
+    gauges = pred._stats.scope.snapshot()["gauges"]
+    assert not [g for g in gauges if g.split(".")[-1] in _UTILISATION], \
+        sorted(gauges)
+    # served rows bitwise vs Module.predict with telemetry on
     np.testing.assert_array_equal(
-        pred.predict(X[:3, :6]),
+        served,
         mod.predict(NDArrayIter(X[:4, :6], None, batch_size=4),
                     num_batch=1).asnumpy()[:3])

@@ -223,8 +223,7 @@ def run_shape(shape, steps, relu=True, dtype="bfloat16"):
             continue
         fns[name] = jfn
         comp = jfn.lower(du, x, rstd, mean, scale, shift).compile()
-        # shared extraction rule (telemetry.introspect) — same numbers
-        # the live roofline gauges publish
+        # shared extraction rule (telemetry.introspect)
         from mxnet_tpu.telemetry.introspect import analyze_compiled
         by = analyze_compiled(comp)["bytes_accessed"]
         if by:
@@ -244,9 +243,9 @@ def run_shape(shape, steps, relu=True, dtype="bfloat16"):
 
     # timing: dependent chain (previous dx IS the next du — no blend, so
     # no extra traffic and no fusion-barrier asymmetry between paths),
-    # two chain lengths differenced (same methodology as bench.py's
-    # two_window_slope): a single window would charge the window-ending
-    # readback's fixed cost to kernels whose true cost is ~1ms.
+    # two chain lengths differenced: a single window would charge the
+    # window-ending readback's fixed cost to kernels whose true cost is
+    # ~1ms.
     tiny = jax.jit(lambda a: jnp.sum(a.astype(f32)))
     L1, L2 = max(4, steps // 4), steps
 

@@ -15,8 +15,8 @@ Usage (real TPU):
 The byte model: fusion internals live in registers/VMEM; only a
 fusion's external operands and outputs touch HBM.  That is the same
 model XLA's own cost analysis uses for "bytes accessed", so the totals
-here reconcile with bench.py's xla_bytes_per_step_gb (within the cost
-model's double-count of shared operands).
+here reconcile with ``analyze_compiled``'s (within the cost model's
+double-count of shared operands).
 """
 from __future__ import annotations
 
@@ -175,8 +175,8 @@ def report(rows, total, top=40, out=sys.stdout):
 
 def compiled_train_step(batch=128, img=224, num_classes=1000,
                         compute_dtype="bfloat16", network="resnet-50"):
-    """Build the bench train-step program through Module and return the
-    jax `Compiled` for its fwd+bwd(+update) step (bench.py _xla_cost)."""
+    """Build the train-step program through Module and return the jax
+    `Compiled` for its one-program fwd+bwd+update step."""
     import numpy as np
     import mxnet_tpu as mx
     from mxnet_tpu import models
@@ -204,10 +204,8 @@ def compiled_train_step(batch=128, img=224, num_classes=1000,
     b = DataBatch(data=[Xd], label=[yd])
     mod.forward_backward(b)
     mod.update()
-    # one shared lowering protocol with bench.py's cost analysis, so
-    # this audit always reconciles with xla_bytes_per_step_gb
-    from bench import compiled_step
-    return compiled_step(eg)
+    fn, structs = eg._last_step
+    return fn.lower(*structs).compile()
 
 
 def main(argv=None):

@@ -206,8 +206,8 @@ def _mesh_module(net, data_shape, label_shape, mesh_axes, n_dev,
     yd = mx.nd.NDArray(jax.device_put(y, eg._batch_sharding), ctx=ctxs[0])
     mod.forward_backward(DataBatch(data=[Xd], label=[yd]))
     mod.update()
-    from bench import compiled_step
-    return compiled_step(eg)
+    fn, structs = eg._last_step
+    return fn.lower(*structs).compile()
 
 
 def build_dp(n_dev=8, per_dev_batch=8):

@@ -16,7 +16,7 @@ import os
 import sys
 
 ROOTS = ["mxnet_tpu", "tools", "tests", "example", "docs",
-         "bench.py", "bench_handwritten.py", "__graft_entry__.py"]
+         "__graft_entry__.py"]
 MAX_LEN = 100
 _PDB = "import " + "pdb"   # split so this file passes its own gate
 _BP = "breakpoint" + "("
