@@ -188,13 +188,26 @@ def _build_eval_segmented(symbol, remat="full", n_segments=None):
     O(sqrt(N))-memory rematerialization the reference's memonger tool
     approximates by graph re-planning (example/memcost).
 
-    remat="dots" keeps matmul/conv outputs inside segments
-    (``jax.checkpoint_policies.dots_saveable``); "full" recomputes
-    everything inside a segment; "bn_stats" additionally keeps the
-    ``checkpoint_name("bn_stats")``-tagged per-channel BatchNorm
-    statistics (ops/nn.py tags them) so the backward's segment replays
-    never redo the stat sweeps; a callable passes straight through as
-    the jax checkpoint policy (mxnet_tpu.precision's custom escape).
+    A segment's backward pass re-runs what is cheaper to make than to
+    hold and is handed the rest: the ops name what is dear
+    (``precision.policy.keep``: ``FullyConnected``'s output, attention's
+    output and log-sum-exp, BatchNorm's batch statistics) and the policy
+    says which names it keeps.  remat="full" keeps the products and
+    attention and makes norms, rotations, casts and activations again
+    (``Convolution`` is not named: a convolutional net's activations
+    are its convolutions' outputs); "dots" keeps what
+    ``jax.checkpoint_policies.dots_saveable`` sees (matmul/conv
+    outputs) and attention, which on a TPU is a Pallas call that it
+    cannot see; "bn_stats" additionally the per-channel BatchNorm
+    statistics, so the replays never redo the stat sweeps; a callable
+    passes straight through as the jax checkpoint policy
+    (``jax.checkpoint_policies.nothing_saveable``: a segment keeps
+    nothing, the strict sqrt-N schedule).
+
+    The LAST segment is not wrapped: its backward pass follows its
+    forward pass at once, so a checkpoint there frees no byte at the
+    peak and runs the segment (the classifier's product) a second time.
+
     Training-mode only, no tap support (the monitor path uses the
     per-node evaluator).
     """
@@ -257,19 +270,26 @@ def _build_eval_segmented(symbol, remat="full", n_segments=None):
 
     # policy object resolved ONCE at build time (mxnet_tpu.precision
     # owns the name -> jax.checkpoint_policies mapping)
-    from .precision.policy import remat_checkpoint_policy
+    from .precision.policy import (keeping, remat_checkpoint_policy,
+                                   remat_kept_names)
     from .registry import count, counting
     _ckpt_policy = remat_checkpoint_policy(remat)
+    _kept_names = remat_kept_names(remat)
 
     arg_slots = [(id(n), 0) for n in arg_nodes]
 
     def eval_fn(arg_vals, aux_vals, rng, is_train, tap=None,
-                arg_dtypes=None):
+                arg_dtypes=None, kept=None):
         """``arg_dtypes``: per argument a type to cast it to where a
         segment reads it, or None.  A cast made up front would keep a
         second copy of every parameter alive from the first segment to
         the last backward one; made inside the segments the copies are
-        a segment's temporaries (and recomputed with it)."""
+        a segment's temporaries (and recomputed with it).
+
+        ``kept``: a dict that takes, per name the policy keeps, the
+        bytes of the values so named inside the wrapped segments
+        (Python ints by shape, known when this traces): what the
+        backward pass is handed beside the segments' arguments."""
         import jax
 
         assert tap is None, "segmented remat has no monitor taps"
@@ -283,7 +303,8 @@ def _build_eval_segmented(symbol, remat="full", n_segments=None):
             env[(id(n), 0)] = v
         aux_out = {id(n): v for n, v in zip(aux_nodes, aux_vals)}
 
-        for seg, in_slots, out_slots, aux_updates in seg_plan:
+        for si, (seg, in_slots, out_slots, aux_updates) in \
+                enumerate(seg_plan):
 
             def seg_fn(in_vals, rng_in, _seg=seg, _in=in_slots,
                        _out=out_slots):
@@ -308,8 +329,14 @@ def _build_eval_segmented(symbol, remat="full", n_segments=None):
                         counted)
 
             in_vals = tuple(env[s] for s in in_slots)
-            outs, upd, rng, counted = jax.checkpoint(
-                seg_fn, policy=policy)(in_vals, rng)
+            if si == len(seg_plan) - 1:
+                # the last segment's backward pass follows its forward
+                # pass at once: the same values are live either way
+                outs, upd, rng, counted = seg_fn(in_vals, rng)
+            else:
+                with keeping(kept, _kept_names):
+                    outs, upd, rng, counted = jax.checkpoint(
+                        seg_fn, policy=policy)(in_vals, rng)
             for name, v in counted.items():
                 count(name, v)
             for slot, v in zip(out_slots, outs):

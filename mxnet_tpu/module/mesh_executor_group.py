@@ -414,6 +414,7 @@ class MeshExecutorGroup(object):
             self._remat_eval_fn = _with_mesh(self._remat_eval_fn)
         else:
             self._remat_eval_fn = None
+        self._remat_kept_bytes = 0
         self.pipeline_microbatches = pipeline_microbatches
         if pipeline_microbatches:
             if "pp" not in self.mesh_axes:
@@ -713,19 +714,24 @@ class MeshExecutorGroup(object):
             if self.remat and is_train and self._pipe_eval_fn is None:
                 # rematerialization trades HBM for recompute in backward
                 # (the reference's external memonger tool). sqrt-N
-                # contiguous segments each under jax.checkpoint: only
-                # segment boundaries stay live through backward.
-                # "full": recompute everything inside a segment;
-                # "dots": keep matmul/conv outputs (dots_saveable).
+                # contiguous segments each under jax.checkpoint: segment
+                # boundaries stay live through backward, and inside a
+                # segment what its policy keeps by name ("full": the
+                # matrix products' and attention's outputs; "dots":
+                # matmul/conv outputs and attention's).
                 # parameters reach the segments as they are held and are
                 # cast where a segment reads them (executor.py)
                 vals = [params[n] if n in params else
                         cast_input(n, inputs[n]) for n in self.arg_names]
                 auxv = [aux[n] for n in self.aux_names]
+                kept = {}
                 outs, new_aux = self._remat_eval_fn(
                     vals, auxv, rng, True,
                     arg_dtypes=[cdt if n in params else None
-                                for n in self.arg_names])
+                                for n in self.arg_names], kept=kept)
+                # known by shape while this traces; every step that
+                # runs the program counts it (_count_remat_kept)
+                self._remat_kept_bytes = sum(kept.values())
                 return outs, dict(zip(self.aux_names, new_aux))
             vals = [cast(n, params[n]) if n in params else
                     cast_input(n, inputs[n]) for n in self.arg_names]
@@ -1524,11 +1530,22 @@ class MeshExecutorGroup(object):
                 sh) for g, sh in zip(out_grads, self._out_shardings))
             fn = self._get_jit("fwd_bwd_heads")
             outs, new_aux, grads = fn(params, aux, inputs, rng, heads)
+        self._count_remat_kept()
         self._write_outs(outs)
         self._write_aux(new_aux)
         for n, g in grads.items():
             self._grad_dict[n]._write(g)
         self._outputs_from = "bwd"
+
+    def _count_remat_kept(self, steps=1):
+        """After a program that ran ``steps`` remat steps: count into
+        the fit report the bytes the segments' backward passes were
+        handed in place of making them again (the named values the
+        policy keeps, by shape; known since the program traced)."""
+        if self._remat_eval_fn is not None:
+            from .. import telemetry
+            telemetry.count("remat.kept_bytes",
+                            steps * self._remat_kept_bytes)
 
     def _materialize_backward(self):
         """Early outputs/grads read while a one-program step was pending:
@@ -1876,6 +1893,7 @@ class MeshExecutorGroup(object):
         with telemetry.span("exec.launch"):
             out = self._launch_step_program(kind, fn, args)
             self._bound_outputs_in_flight(out[0])
+        self._count_remat_kept()
         outs, new_aux, _no_grads, new_params, new_states = \
             self._commit_step_extras(out)
         self._write_outs(outs)
@@ -1967,6 +1985,7 @@ class MeshExecutorGroup(object):
         self._note_optimizer_analytic(states, triples)
         with telemetry.span("exec.launch"):
             out = self._launch_step_program(kind, fn, args)
+        self._count_remat_kept(K)
         outs, new_aux, _no_grads, new_params, new_states = \
             self._commit_step_extras(out)
         self._write_outs(outs)

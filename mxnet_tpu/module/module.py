@@ -31,14 +31,28 @@ class Module(BaseModule):
 
     ``remat="full"`` (or ``MXNET_BACKWARD_DO_MIRROR=1``, matching the
     reference's graph_executor.cc:210-223 mirror switch) trains through the
-    sqrt-N segmented-checkpoint evaluator: measured 0.41x peak temp memory
-    for +27% recompute flops on a v5e (example/memcost). The reduction is
-    realized by XLA:TPU/GPU buffer assignment — a Module left on the default
-    cpu() context compiles for XLA:CPU, which schedules through checkpoint
-    boundaries and only shows the recompute, not the memory win.
-    ``remat="dots"`` keeps matmul/conv outputs (checkpoint_policies
-    .dots_saveable) — useful for transformer-style nets where elementwise
-    chains dominate between matmuls; on conv nets it saves nothing.
+    sqrt-N segmented-checkpoint evaluator (``executor._build_eval_segmented``):
+    segment boundaries are held, and a segment's backward pass makes again
+    what is cheap (norms, rotations, casts, activations) and is handed what
+    is dear, as the reference's switch never recomputed ``FullyConnected``:
+    the matrix products' outputs and attention's (the ops name them,
+    ``precision.policy.keep``; ``Convolution`` is not named, since a
+    convolutional net's activations are its convolutions' outputs). The
+    trade, read on a v5e on a five-layer sparse decoder at 8,192 tokens a
+    step (PERF.md section 6, PR 31): against a step that keeps nothing
+    inside a segment, 1.65 GiB kept, peak 10.03 -> 11.23 GiB, the step
+    345.8 -> 320.2 ms; without remat that step needs 18.7 GiB. The
+    reduction is realized by XLA:TPU/GPU buffer assignment — a Module left
+    on the default cpu() context compiles for XLA:CPU, which schedules
+    through checkpoint boundaries and only shows the recompute, not the
+    memory win.
+    ``remat="dots"`` keeps what ``checkpoint_policies.dots_saveable`` sees
+    (matmul/conv outputs) and attention's output — useful for
+    transformer-style nets where elementwise chains dominate between
+    matmuls; on conv nets it saves nothing.
+    ``remat=jax.checkpoint_policies.nothing_saveable`` (any jax checkpoint
+    policy callable passes through) is the strict schedule, a segment keeps
+    nothing: for a step that fits so and not with its products kept.
 
     ``mesh_axes`` + ``param_sharding`` make tensor/model parallelism
     user-reachable through ``fit`` (the TPU-native upgrade of the

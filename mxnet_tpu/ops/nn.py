@@ -70,7 +70,11 @@ def _fully_connected(attrs, ins, octx):
         y = jnp.dot(x2, w.T, precision=f32_precision(x2))
     if not attrs.get("no_bias", False):
         y = y + ins[2].astype(y.dtype)[None, :]
-    return [y]
+    # remat tag: a matrix product is dear to make again, so the
+    # segmented evaluator's "full" policy keeps it (in the activation
+    # type, before any consumer's cast); the identity everywhere else
+    from ..precision.policy import PRODUCT, keep
+    return [keep(y, PRODUCT)]
 
 
 # ---------------------------------------------------------------------------
@@ -613,9 +617,9 @@ def _batch_norm(attrs, ins, octx):
         # sweeps. Outside such a policy checkpoint_name is identity —
         # bitwise-neutral for every other mode (pinned by the existing
         # parity suites).
-        from jax.ad_checkpoint import checkpoint_name
-        mean = checkpoint_name(mean, "bn_stats")
-        var = checkpoint_name(var, "bn_stats")
+        from ..precision.policy import BN_STATS, keep
+        mean = keep(mean, BN_STATS)
+        var = keep(var, BN_STATS)
         new_mmean = (mmean * mom +
                      jax.lax.stop_gradient(mean).astype(mmean.dtype) *
                      (1 - mom))

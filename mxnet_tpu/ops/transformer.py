@@ -21,6 +21,7 @@ from __future__ import annotations
 import functools
 import math
 
+from ..precision.policy import ATTENTION, keep, note_kept
 from ..registry import register
 
 
@@ -64,7 +65,9 @@ def _attend_block(q, k, v, q_lo, k_lo, causal, window, scale):
 def _blockwise(q, k, v, causal, window, scale, block):
     """Query blocks in turn, each against the keys its mask leaves and
     each under ``jax.checkpoint``: the backward pass recomputes a
-    block's scores and keeps none."""
+    block's scores and keeps none.  The result carries the name the
+    kernel path gives its own, so a remat policy keeps the same set on
+    both paths."""
     import jax
     jnp = _jnp()
     B, H, T, D = q.shape
@@ -79,7 +82,8 @@ def _blockwise(q, k, v, causal, window, scale, block):
             window=window, scale=scale))
         outs.append(fn(qg[:, :, :, q_lo:q_hi], k[:, :, k_lo:k_hi],
                        v[:, :, k_lo:k_hi]))
-    return jnp.concatenate(outs, axis=3).reshape(B, H, T, D)
+    return keep(jnp.concatenate(outs, axis=3).reshape(B, H, T, D),
+                ATTENTION)
 
 
 # True runs the TPU kernel off the TPU, under the Pallas interpreter:
@@ -103,9 +107,14 @@ def _splash_kernel(T, R, causal, window, block):
         block_q=block, block_kv=block, block_kv_compute=block,
         block_q_dkv=block, block_kv_dkv=block, block_kv_dkv_compute=block,
         use_fused_bwd_kernel=True)
+    # the kernel names its output and its log-sum-exp inside its
+    # custom_vjp: a remat policy that keeps the name hands them to the
+    # backward kernels and the forward one is not run again
     return sk.make_splash_mqa(sm.MultiHeadMask([mask] * R),
                               block_sizes=sizes, head_shards=1,
-                              q_seq_shards=1, interpret=_INTERPRET)
+                              q_seq_shards=1,
+                              residual_checkpoint_name=ATTENTION,
+                              interpret=_INTERPRET)
 
 
 def _splash(q, k, v, causal, window, scale, block):
@@ -116,6 +125,8 @@ def _splash(q, k, v, causal, window, scale, block):
     qg = (q * scale).astype(q.dtype).reshape(B, G, H // G, T, D)
     with jax.named_scope("splash_attention"):
         out = jax.vmap(jax.vmap(kernel))(qg, k, v)
+    # what the kernel named: its output and a float32 log-sum-exp a query
+    note_kept(ATTENTION, out.size * out.dtype.itemsize + B * H * T * 4)
     return out.reshape(B, H, T, D).astype(q.dtype)
 
 

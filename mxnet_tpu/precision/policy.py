@@ -16,12 +16,13 @@ Module/Updater/executor stack applies it at the existing seams:
   seam (``MeshExecutorGroup`` ``compute_dtype``), named so a mode can
   carry it.
 * ``remat=...`` — a named ``jax.checkpoint`` policy for the segmented
-  rematerialization evaluator: ``"none"``, ``"full"`` (recompute
-  everything inside a segment), ``"dots_saveable"`` (keep matmul/conv
-  outputs), ``"offload_bn_stats"`` (dots_saveable + keep the tagged
-  per-channel BatchNorm statistics, ``checkpoint_name("bn_stats")``),
-  or a raw jax policy callable.  Trades recompute FLOPs (we have the
-  headroom) for activation bytes.
+  rematerialization evaluator: ``"none"``, ``"full"`` (a segment's
+  backward pass is handed ``FullyConnected``'s and attention's outputs
+  and makes the rest again), ``"dots_saveable"`` (keep matmul/conv
+  outputs, and attention's), ``"offload_bn_stats"`` (dots_saveable +
+  keep the tagged per-channel BatchNorm statistics), or a raw jax
+  policy callable (``jax.checkpoint_policies.nothing_saveable``: a
+  segment keeps nothing).  Trades recompute FLOPs for activation bytes.
 * ``act_cast="int8"|"fp8"`` (EXPERIMENTAL, ``MXNET_PRECISION_EXPERIMENTAL=1``)
   — fake-quantized low-bit casts at the input seam, with device-side
   dynamic loss scaling for the narrow backward.
@@ -44,6 +45,8 @@ reference (ci.sh precision gate), and an introspection witness — the
 """
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import os
 
 from ..base import MXNetError
@@ -51,6 +54,7 @@ from ..base import MXNetError
 __all__ = ["PrecisionPolicy", "MODES", "resolve", "register_mode",
            "mode_name", "canon_dtype", "canon_remat", "state_np_dtype",
            "wrap_fused_apply", "fake_cast", "remat_checkpoint_policy",
+           "remat_kept_names", "keep", "keeping", "note_kept",
            "loss_scale_config"]
 
 
@@ -338,24 +342,84 @@ def fake_cast(jnp, v, kind):
     raise MXNetError("unknown act_cast %r" % (kind,))
 
 
+# ---------------------------------------------------------------------------
+# remat: what a segment's backward pass is handed instead of re-running
+# ---------------------------------------------------------------------------
+# The names an op gives a value that is dear to make again (:func:`keep`)
+# and, per canonical remat spec, the names its policy keeps.  One table:
+# the ops import the names, the segmented evaluator the rest.
+PRODUCT = "product"        # FullyConnected's output, in the activation type
+ATTENTION = "attention"    # attention's output (and the kernel's log-sum-exp)
+BN_STATS = "bn_stats"      # BatchNorm's per-channel batch statistics
+
+_KEPT_NAMES = {"full": (PRODUCT, ATTENTION),
+               "dots": (ATTENTION,),
+               "bn_stats": (BN_STATS, ATTENTION)}
+
+_KEPT_BAG = contextvars.ContextVar("mxnet_tpu_remat_kept",
+                                   default=(None, ()))
+
+
+def note_kept(name, nbytes):
+    """Tell the evaluator that collects (:func:`keeping`) that a value
+    of ``nbytes`` was named ``name``; nothing where none collects, or
+    not that name."""
+    bag, names = _KEPT_BAG.get()
+    if name in names:
+        bag[name] = bag.get(name, 0) + int(nbytes)
+
+
+def keep(value, name):
+    """Name ``value`` for the remat policies (``checkpoint_name``): a
+    segment's backward pass is handed a value whose name its policy
+    keeps, and makes every other again.  Outside a checkpoint the name
+    is the identity and the program is unchanged."""
+    from jax.ad_checkpoint import checkpoint_name
+    note_kept(name, value.size * value.dtype.itemsize)
+    return checkpoint_name(value, name)
+
+
+@contextlib.contextmanager
+def keeping(bag, names):
+    """While the body traces, add to ``bag`` (``{name: bytes}``, Python
+    ints by shape) the bytes of the values given one of ``names`` in
+    it; a ``bag`` of None collects nothing."""
+    tok = _KEPT_BAG.set((bag, names if bag is not None else ()))
+    try:
+        yield
+    finally:
+        _KEPT_BAG.reset(tok)
+
+
+def remat_kept_names(remat):
+    """The names whose values a canonical remat spec keeps inside a
+    segment; none for a callable, which decides for itself."""
+    if callable(remat):
+        return ()
+    if remat not in _KEPT_NAMES:
+        raise MXNetError("unknown remat policy %r" % (remat,))
+    return _KEPT_NAMES[remat]
+
+
 def remat_checkpoint_policy(remat):
     """The ``jax.checkpoint`` policy object for a canonical remat spec
-    (:func:`canon_remat` output).  ``"full"`` maps to None (recompute
-    everything inside a segment); ``"bn_stats"`` keeps matmul/conv
-    outputs AND the ``checkpoint_name("bn_stats")``-tagged per-channel
-    BatchNorm statistics (ops/nn.py tags them)."""
+    (:func:`canon_remat` output).  Every named spec keeps the values
+    named in :func:`remat_kept_names`: ``"full"`` those alone (the
+    matrix products' and attention's outputs; norms, rotations, casts
+    and activations are made again), ``"dots"`` and ``"bn_stats"``
+    beside what ``dots_saveable`` sees (attention is named because on a
+    TPU it is a Pallas call, no ``dot_general``).  A callable passes
+    through: ``jax.checkpoint_policies.nothing_saveable`` is the strict
+    "a segment keeps nothing"."""
     import jax
     if callable(remat):
         return remat
+    by_name = jax.checkpoint_policies.save_only_these_names(
+        *remat_kept_names(remat))
     if remat == "full":
-        return None
-    if remat == "dots":
-        return jax.checkpoint_policies.dots_saveable
-    if remat == "bn_stats":
-        return jax.checkpoint_policies.save_from_both_policies(
-            jax.checkpoint_policies.dots_saveable,
-            jax.checkpoint_policies.save_only_these_names("bn_stats"))
-    raise MXNetError("unknown remat policy %r" % (remat,))
+        return by_name
+    return jax.checkpoint_policies.save_from_both_policies(
+        jax.checkpoint_policies.dots_saveable, by_name)
 
 
 def loss_scale_config(policy):

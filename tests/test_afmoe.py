@@ -370,14 +370,21 @@ TINY = dict(vocab_size=64, seq_len=16, hidden_size=32, num_attention_heads=4,
                          "full_attention"])
 
 
-def _fit(remat, compute_dtype=None, epochs=2):
+def _fit(remat, compute_dtype=None, epochs=2, net=None):
+    """`remat`: a name goes the model's way (`get_symbol(remat=)`); a
+    jax policy callable, or any for a `net` of the caller's, the
+    Module's."""
     from mxnet_tpu import models
     mx.random.seed(5)
-    net = models.get_symbol("afmoe", remat=remat, **TINY)
+    by_module = remat if callable(remat) or net is not None else None
+    if net is None:
+        net = models.get_symbol(
+            "afmoe", remat=None if by_module else remat, **TINY)
     rs = np.random.RandomState(0)
     x = rs.randint(0, 64, (128,)).astype(np.float32)
     y = rs.randint(0, 64, (128,)).astype(np.float32)
-    mod = mx.mod.Module(net, context=[mx.cpu(0)], compute_dtype=compute_dtype)
+    mod = mx.mod.Module(net, context=[mx.cpu(0)], compute_dtype=compute_dtype,
+                        remat=by_module)
     mod.fit(mx.io.NDArrayIter(x, y, batch_size=32), num_epoch=epochs,
             optimizer="sgd", eval_metric="acc",
             optimizer_params={"learning_rate": 0.1, "momentum": 0.9},
@@ -402,12 +409,74 @@ def test_model_is_found_by_name_and_trains_with_its_counters():
         assert np.abs(b.asnumpy()).max() <= 0.008 + 1e-6
 
 
-def test_remat_changes_no_number():
-    a, b = _fit(None, epochs=1), _fit("full", epochs=1)
+@pytest.mark.parametrize("remat", [
+    "full", "dots", jax.checkpoint_policies.nothing_saveable],
+    ids=["full", "dots", "strict"])
+def test_remat_changes_no_number(remat):
+    """What a segment keeps is the value it would have made again: the
+    last step's probabilities and the parameters after an epoch are
+    those of the step that recomputes nothing."""
+    a, b = _fit(None, epochs=1), _fit(remat, epochs=1)
+    np.testing.assert_allclose(a.get_outputs()[0].asnumpy(),
+                               b.get_outputs()[0].asnumpy(),
+                               rtol=2e-4, atol=2e-6)
     pa, pb = a.get_params()[0], b.get_params()[0]
     for k in pa:
         np.testing.assert_allclose(pa[k].asnumpy(), pb[k].asnumpy(),
                                    rtol=2e-4, atol=2e-6)
+
+
+def test_fit_counts_what_the_segments_keep_once_a_step():
+    """`remat.kept_bytes` of the fit report: every step adds the bytes
+    of the products and attention outputs named inside the wrapped
+    segments (by shape); a module that does not recompute counts none."""
+    from mxnet_tpu import telemetry
+    rows, steps, f32 = 32, 4, 4
+    # three layers of q, k, v, gate, o; the dense layer's gate, up,
+    # down; two shared experts' (the head lies in the last segment)
+    products = 3 * (32 + 16 + 16 + 32 + 32) + (48 + 48 + 32) \
+        + 2 * (16 + 16 + 32)
+    attention = 3 * (2 * 4 * 16 * 8)
+    mod = _fit("full", epochs=1)
+    assert mod._exec_group._remat_kept_bytes \
+        == (rows * products + attention) * f32
+    assert telemetry.last_fit()["counters"]["remat.kept_bytes"] \
+        == steps * (rows * products + attention) * f32
+    _fit(jax.checkpoint_policies.nothing_saveable, epochs=1)
+    assert telemetry.last_fit()["counters"]["remat.kept_bytes"] == 0
+    _fit(None, epochs=1)
+    assert "remat.kept_bytes" not in telemetry.last_fit()["counters"]
+
+
+def test_the_last_segment_is_not_wrapped_and_its_counters_arrive():
+    """Four op nodes make two segments and the expert layer lies in the
+    second, which runs outside any checkpoint: what it counts still
+    reaches the fit report."""
+    from mxnet_tpu import telemetry
+    from mxnet_tpu.executor import _build_eval_segmented
+    h = mx.sym.Embedding(mx.sym.Variable("data"), input_dim=64,
+                         output_dim=32, name="embed")
+    h = mx.sym.RMSNorm(h, name="norm")
+    h = mx.sym.MoE(h, num_experts=8, hidden_size=16, num_experts_per_tok=2,
+                   experts_held=(2, 4), score_func="sigmoid",
+                   name="moe")[0]
+    net = mx.sym.SoftmaxOutput(h, mx.sym.Variable("softmax_label"),
+                               name="softmax")
+    assert [n.name for n in net._topo() if n.op is not None][2:] \
+        == ["moe", "softmax"]
+    shapes, _, aux_shapes = net.infer_shape(data=(32,), softmax_label=(32,))
+    args = [jnp.zeros(s, jnp.float32) + 0.1 for s in shapes]
+    auxs = [jnp.zeros(s, jnp.float32) for s in aux_shapes]
+    ev, _ = _build_eval_segmented(net, "full")
+    jaxpr = str(jax.make_jaxpr(jax.grad(lambda v: jnp.sum(ev(
+        v, auxs, jax.random.PRNGKey(0), True)[0][0])))(args))
+    assert jaxpr.count("remat") + jaxpr.count("checkpoint") == 1
+
+    _fit("full", epochs=1, net=net)
+    counters = telemetry.last_fit()["counters"]
+    assert 0 < counters["moe.held_pairs"] <= 4 * 32 * 2
+    assert counters["moe.dropped"] == 0
+    assert counters["remat.kept_bytes"] == 0    # embed, norm: nothing dear
 
 
 def test_ids_survive_the_compute_type():

@@ -3,11 +3,15 @@ numerics must match the plain evaluator exactly — outputs, gradients,
 and BatchNorm aux updates — since Module(remat=...) swaps it in for
 training. Also asserts the checkpoint structure is really present
 (remat in the grad jaxpr) and a Module-level A/B on the fused path."""
+import re
+
 import numpy as np
+import pytest
 
 import mxnet_tpu as mx
 import mxnet_tpu.symbol as sym
 from mxnet_tpu.executor import _build_eval, _build_eval_segmented
+from test_afmoe import TINY as AFMOE_TINY
 
 
 def _bn_net():
@@ -154,5 +158,197 @@ def test_segmented_jaxpr_contains_checkpoints():
     jaxpr = str(jax.make_jaxpr(jax.grad(loss))(args))
     assert "remat" in jaxpr or "checkpoint" in jaxpr, \
         "segmented evaluator lost its checkpoint structure"
-    assert jaxpr.count("remat") + jaxpr.count("checkpoint") >= 3, \
-        "expected one checkpoint per segment"
+    # the last segment's backward pass follows its forward pass at
+    # once: a checkpoint there would only run it a second time
+    assert jaxpr.count("remat") + jaxpr.count("checkpoint") == 2, \
+        "expected one checkpoint a segment but the last"
+
+
+# ------------------------------------------------------------------
+# what a segment's backward pass re-runs: the cheap, not the dear
+# ------------------------------------------------------------------
+TINY = dict(AFMOE_TINY, layer_types=["sliding_attention", "full_attention"])
+ROWS = 32
+# the FullyConnected nodes of the two layers, by width; `head` apart: it
+# lies in the last segment (47 op nodes: 7 segments of 7, the last of 5)
+PRODUCTS = {"l%d_%s" % (i, n): w for i in (0, 1) for n, w in
+            [("q", 32), ("k", 16), ("v", 16), ("gate", 32), ("o", 32)]}
+PRODUCTS.update(l0_mlp_gate=48, l0_mlp_up=48, l0_mlp_down=32,
+                l1_shared_gate=16, l1_shared_up=16, l1_shared_down=32)
+# products no backward pass makes again under any policy: a segment's
+# last node read by the next segment alone (a boundary value, held
+# anyway), and one that only an addition reads (whose gradient needs it
+# not)
+NEVER_AGAIN = {"l0_k", "l0_mlp_down", "l1_shared_down"}
+ATTENTION_BYTES = 2 * (2 * 4 * 16 * 8) * 4      # two layers' (B, H, T, D) f32
+PRODUCT_BYTES = ROWS * sum(PRODUCTS.values()) * 4
+
+
+def _strict():
+    import jax
+    return jax.checkpoint_policies.nothing_saveable
+
+
+def _tiny_decoder():
+    """The two-layer decoder (a dense layer, an expert layer), its
+    argument values and auxiliary state."""
+    from mxnet_tpu import models
+    net = models.get_symbol("afmoe", **TINY)
+    shapes, _, aux_shapes = net.infer_shape(data=(ROWS,),
+                                            softmax_label=(ROWS,))
+    rs = np.random.RandomState(0)
+    args = [rs.randint(0, 64, s).astype(np.float32)
+            if n in ("data", "softmax_label")
+            else (rs.randn(*s) * 0.1).astype(np.float32)
+            for n, s in zip(net.list_arguments(), shapes)]
+    return net, args, [np.zeros(s, np.float32) for s in aux_shapes]
+
+
+def _eqns(jaxpr, out=None):
+    """Every equation of a jaxpr and of the jaxprs inside it."""
+    out = [] if out is None else out
+    for e in jaxpr.eqns:
+        out.append(e)
+        for v in e.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _eqns(sub, out)
+    return out
+
+
+def _runs(eqns, primitive, node):
+    """How many `primitive` equations the symbol node `node` traced to,
+    forward, recomputed and backward together."""
+    at = re.compile(r"(^|[/(])%s([/)]|$)" % re.escape(node))
+    return sum(1 for e in eqns if e.primitive.name == primitive
+               and at.search(str(e.source_info.name_stack)))
+
+
+def _grad_eqns(net, args, auxs, remat, kept=None):
+    import jax
+    import jax.numpy as jnp
+    ev, _ = _build_eval_segmented(net, remat)
+    key = jax.random.PRNGKey(0)
+
+    def loss(vals):
+        outs, _ = ev(vals, auxs, key, True, kept=kept)
+        return jnp.sum(outs[0] * outs[0])
+
+    return _eqns(jax.make_jaxpr(jax.grad(loss))(args).jaxpr), loss
+
+
+def test_full_keeps_the_products_and_attention_and_remakes_the_norms(capsys):
+    """Under "full" a wrapped segment's backward pass is handed each
+    FullyConnected's product and attention's output (a product runs
+    forward once, then its two backward products) and makes the norms
+    again; the strict callable makes the products again too."""
+    from jax.ad_checkpoint import print_saved_residuals
+    net, args, auxs = _tiny_decoder()
+    full, loss = _grad_eqns(net, args, auxs, "full")
+    strict, strict_loss = _grad_eqns(net, args, auxs, _strict())
+    for node in PRODUCTS:
+        assert _runs(full, "dot_general", node) == 3, node
+        assert _runs(strict, "dot_general", node) \
+            == (3 if node in NEVER_AGAIN else 4), node
+    for eqns in (full, strict):
+        # the last segment is not wrapped: nothing in it runs twice
+        assert _runs(eqns, "dot_general", "head") == 3
+        assert _runs(eqns, "rsqrt", "final_norm") == 1
+        for norm in ("l0_in_norm", "l0_q_norm", "l1_pre_mlp_norm"):
+            assert _runs(eqns, "rsqrt", norm) == 2, norm
+    assert _runs(full, "dot_general", "l0_attn") \
+        < _runs(strict, "dot_general", "l0_attn")
+
+    # what is kept, as jax itself lists it: beside the arguments, one
+    # value for every attention and every product of a wrapped segment
+    # that a backward pass reads
+    print_saved_residuals(loss, args)
+    lines = capsys.readouterr().out.splitlines()
+    named = [ln for ln in lines if ln.rstrip().endswith("(keep)")]
+    assert len(named) == len(PRODUCTS) - len(NEVER_AGAIN) + 2
+    assert sum("f32[2,4,16,8]" in ln for ln in named) == 2    # attention
+    assert sum("from the argument" in ln for ln in lines) > 30
+    # strictly, only what leaves a segment is held (two of the products)
+    print_saved_residuals(strict_loss, args)
+    held = [ln for ln in capsys.readouterr().out.splitlines()
+            if ln.rstrip().endswith("(keep)")]
+    assert len(held) == 2 and not any("f32[2,4,16,8]" in ln for ln in held)
+
+
+def test_strict_callable_reaches_the_program_through_module():
+    """`Module(remat=jax.checkpoint_policies.nothing_saveable)` is the
+    escape to "a segment keeps nothing": its step program runs a
+    product forward twice where `remat="full"`'s runs it once."""
+    import jax
+    net, _, _ = _tiny_decoder()
+
+    def step_eqns(remat):
+        mod = mx.mod.Module(net, context=[mx.cpu(0)], remat=remat)
+        mod.bind(data_shapes=[("data", (ROWS,))],
+                 label_shapes=[("softmax_label", (ROWS,))])
+        mod.init_params(mx.initializer.Xavier())
+        eg = mod._exec_group
+        assert eg.fused
+        spec = lambda d: {n: jax.ShapeDtypeStruct(v.shape, v.dtype)
+                          for n, v in d.items()}
+        params = spec({n: b._read() for n, b in eg._param_dict.items()})
+        aux = spec({n: b._read() for n, b in eg._aux_dict.items()})
+        inputs = {n: jax.ShapeDtypeStruct((ROWS,), np.float32)
+                  for n in ("data", "softmax_label")}
+        rng = jax.ShapeDtypeStruct((2,), np.uint32)
+        return _eqns(jax.make_jaxpr(eg._get_jit("fwd_bwd"))(
+            params, aux, inputs, rng).jaxpr), eg
+
+    full, eg = step_eqns("full")
+    assert eg._remat_kept_bytes == PRODUCT_BYTES + ATTENTION_BYTES
+    strict, eg = step_eqns(_strict())
+    assert eg._remat_kept_bytes == 0
+    for node in ("l0_q", "l1_o", "l1_shared_up"):
+        assert _runs(full, "dot_general", node) == 3, node
+        assert _runs(strict, "dot_general", node) == 4, node
+
+
+@pytest.mark.parametrize("remat,names", [
+    ("full", ("product", "attention")), ("dots", ("attention",)),
+    ("bn_stats", ("attention",)), ("strict", ())])
+def test_kept_bytes_are_the_named_values_by_shape(remat, names):
+    """`kept` takes the bytes of the values the policy keeps by name
+    inside the wrapped segments: the head's logits (last segment) are
+    no part, and a callable, which decides for itself, reports none."""
+    net, args, auxs = _tiny_decoder()
+    kept = {}
+    eqns, _ = _grad_eqns(net, args, auxs,
+                         _strict() if remat == "strict" else remat, kept)
+    by_shape = {"product": PRODUCT_BYTES, "attention": ATTENTION_BYTES}
+    assert kept == {n: by_shape[n] for n in names}
+    # "dots" keeps attention's output on the blockwise path, where its
+    # own policy sees only products: the blocks' forward products are
+    # not made a third time
+    strict, _ = _grad_eqns(net, args, auxs, _strict())
+    if names:
+        assert _runs(eqns, "dot_general", "l1_attn") \
+            < _runs(strict, "dot_general", "l1_attn")
+    else:
+        assert _runs(eqns, "dot_general", "l1_attn") \
+            == _runs(strict, "dot_general", "l1_attn")
+
+
+def test_bn_stats_are_counted_where_their_policy_keeps_them():
+    import jax
+    import jax.numpy as jnp
+    net = _bn_net()
+    shapes, _, aux_shapes = net.infer_shape(data=(4, 2, 8, 8),
+                                            softmax_label=(4,))
+    args = [np.full(s, 0.5, np.float32) for s in shapes]
+    auxs = [np.zeros(s, np.float32) for s in aux_shapes]
+    got = {}
+    for remat in ("bn_stats", "dots", "full"):
+        ev, _ = _build_eval_segmented(net, remat, n_segments=3)
+        kept = got[remat] = {}
+        jax.make_jaxpr(jax.grad(lambda v: jnp.sum(ev(
+            v, auxs, jax.random.PRNGKey(0), True, kept=kept)[0][0])))(args)
+    # bn1 lies in the first segment, bn2 in the second (wrapped both):
+    # a mean and a variance of 4 channels each; `fc` is in the last
+    assert got == {"bn_stats": {"bn_stats": 2 * 2 * 4 * 4}, "dots": {},
+                   "full": {}}
