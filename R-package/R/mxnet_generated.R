@@ -16,7 +16,7 @@ mx.internal.gen.sym <- function(op, args, name) {
   do.call(mx.symbol.create, c(list(op), args, list(name = name)))
 }
 
-# relu/sigmoid/tanh/softrelu (src/operator/activation-inl.h), and
+# relu/sigmoid/tanh/softrelu (src/operator/activation-inl.h),
 #   data : NDArray-or-Symbol
 #   act_type : string, optional
 mx.nd.Activation <- function(..., out = NULL) mx.internal.gen.nd("Activation", list(...), out)
@@ -57,6 +57,15 @@ mx.symbol.CTCLoss <- function(..., name = NULL) mx.internal.gen.sym("CTCLoss", l
 #   dtype : string, optional
 mx.nd.Cast <- function(..., out = NULL) mx.internal.gen.nd("Cast", list(...), out)
 mx.symbol.Cast <- function(..., name = NULL) mx.internal.gen.sym("Cast", list(...), name)
+
+# Depthwise convolution along each sequence of ``seq_len`` rows,
+#   data : NDArray-or-Symbol
+#   weight : NDArray-or-Symbol
+#   bias : NDArray-or-Symbol
+#   kernel : int, required
+#   seq_len : int, required
+mx.nd.CausalConv1D <- function(..., out = NULL) mx.internal.gen.nd("CausalConv1D", list(...), out)
+mx.symbol.CausalConv1D <- function(..., name = NULL) mx.internal.gen.sym("CausalConv1D", list(...), name)
 
 # Concat
 #   arg0 : NDArray-or-Symbol
@@ -177,6 +186,15 @@ mx.nd.Flatten <- function(..., out = NULL) mx.internal.gen.nd("Flatten", list(..
 #   num_hidden : int, required
 mx.nd.FullyConnected <- function(..., out = NULL) mx.internal.gen.nd("FullyConnected", list(...), out)
 
+# RMSNorm of ``data * silu(gate)`` over each of ``groups`` equal
+#   data : NDArray-or-Symbol
+#   gate : NDArray-or-Symbol
+#   gamma : NDArray-or-Symbol
+#   eps : float, optional
+#   groups : int, optional
+mx.nd.GatedRMSNorm <- function(..., out = NULL) mx.internal.gen.nd("GatedRMSNorm", list(...), out)
+mx.symbol.GatedRMSNorm <- function(..., name = NULL) mx.internal.gen.sym("GatedRMSNorm", list(...), name)
+
 # Affine/warp grid generation (src/operator/grid_generator-inl.h).
 #   data : NDArray-or-Symbol
 #   target_shape : Shape(tuple), optional
@@ -274,7 +292,9 @@ mx.symbol.MakeLoss <- function(..., name = NULL) mx.internal.gen.sym("MakeLoss",
 #   expert2_weight : NDArray-or-Symbol
 #   expert2_bias : NDArray-or-Symbol
 #   capacity_factor : float, optional
+#   expert_act : string, optional
 #   experts_held : Shape(tuple), optional
+#   gated : boolean, optional
 #   hidden_size : int, required
 #   load_balance_coeff : float, optional
 #   num_experts : int, required
@@ -386,6 +406,23 @@ mx.symbol.RingAttention <- function(..., name = NULL) mx.internal.gen.sym("RingA
 #   theta : float, optional
 mx.nd.RoPE <- function(..., out = NULL) mx.internal.gen.nd("RoPE", list(...), out)
 mx.symbol.RoPE <- function(..., name = NULL) mx.internal.gen.sym("RoPE", list(...), name)
+
+# The Mamba-2 selective scan over rows cut into sequences of
+#   data : NDArray-or-Symbol
+#   dt : NDArray-or-Symbol
+#   B : NDArray-or-Symbol
+#   C : NDArray-or-Symbol
+#   A_log : NDArray-or-Symbol
+#   dt_bias : NDArray-or-Symbol
+#   D : NDArray-or-Symbol
+#   chunk : int, optional
+#   groups : int, required
+#   head_dim : int, required
+#   heads : int, required
+#   seq_len : int, required
+#   state : int, required
+mx.nd.SSD <- function(..., out = NULL) mx.internal.gen.nd("SSD", list(...), out)
+mx.symbol.SSD <- function(..., name = NULL) mx.internal.gen.sym("SSD", list(...), name)
 
 # Hinge-loss output layer (src/operator/svm_output-inl.h).
 #   data : NDArray-or-Symbol

@@ -17,13 +17,14 @@ from . import googlenet
 from . import inception_resnet_v2
 from . import lstm
 from . import afmoe
+from . import nemotron_h
 
 _MODELS = {
     "mlp": mlp, "lenet": lenet, "alexnet": alexnet, "vgg": vgg,
     "inception-bn": inception_bn,
     "inception-v3": inception_v3, "googlenet": googlenet,
     "inception-resnet-v2": inception_resnet_v2,
-    "afmoe": afmoe,
+    "afmoe": afmoe, "nemotron_h": nemotron_h,
 }  # resnet/resnext dispatch via the prefix loop in get_symbol
 
 

@@ -19,4 +19,5 @@ from . import detection  # noqa: F401
 from . import sequence_loss  # noqa: F401
 from . import parallel_ops  # noqa: F401
 from . import transformer  # noqa: F401
+from . import ssm  # noqa: F401
 from .. import operator  # noqa: F401  (registers the Custom op)

@@ -82,8 +82,9 @@ def _fully_connected(attrs, ins, octx):
 # ---------------------------------------------------------------------------
 @register("Activation", attr_types={"act_type": str})
 def _activation(attrs, ins, octx):
-    """relu/sigmoid/tanh/softrelu (src/operator/activation-inl.h), and
-    silu, x * sigmoid(x), the gate of a gated feed-forward."""
+    """relu/sigmoid/tanh/softrelu (src/operator/activation-inl.h),
+    silu, x * sigmoid(x), the gate of a gated feed-forward, and relu2,
+    relu(x)^2, the activation of an ungated one."""
     jnp = _jnp()
     x = ins[0]
     t = attrs.get("act_type", "relu")
@@ -98,6 +99,8 @@ def _activation(attrs, ins, octx):
     if t == "silu":
         import jax
         return [jax.nn.silu(x)]
+    if t == "relu2":
+        return [jnp.square(jnp.maximum(x, 0))]
     raise ValueError("unknown act_type %s" % t)
 
 

@@ -9,8 +9,9 @@ MeshExecutorGroup around its evaluator closures):
 
 * ``MoE`` — one router, two layers.  With ``experts_held`` one chip's
   share of an expert-parallel layer: top-k over all the experts, the
-  experts held computed by grouped matrix products over the pairs
-  sorted by expert, no token dropped.  Without it the Switch-style
+  experts held (gated or not, ``expert_act``) computed by grouped
+  matrix products over the pairs sorted by expert, no token dropped.
+  Without it the Switch-style
   top-1 router + capacity-bucketed expert FFN in
   the GSPMD formulation: dispatch/combine are einsums over an
   expert-major buffer whose expert dim carries a sharding constraint on
@@ -56,8 +57,9 @@ def _moe_args(attrs):
     if _held(attrs) is None:
         return ("data", "gate_weight", "expert1_weight", "expert1_bias",
                 "expert2_weight", "expert2_bias")
-    return ("data", "router_weight", "experts_gate_weight",
-            "experts_up_weight", "experts_down_weight")
+    gate = ("experts_gate_weight",) if attrs.get("gated", True) else ()
+    return ("data", "router_weight") + gate + (
+        "experts_up_weight", "experts_down_weight")
 
 
 def _moe_aux(attrs):
@@ -83,9 +85,8 @@ def _moe_infer(attrs, in_shapes, aux):
     if held is not None:
         n = held[1]
         in_shapes[1] = (E, d)
-        in_shapes[2] = (n * f, d)
-        in_shapes[3] = (n * f, d)
-        in_shapes[4] = (n * d, f)
+        in_shapes[2:-1] = [(n * f, d)] * (len(in_shapes) - 3)   # gate?, up
+        in_shapes[-1] = (n * d, f)
         return in_shapes, [tuple(data), ()], [(E,)]
     in_shapes[1] = (d, E)
     in_shapes[2] = (E, d, f)
@@ -100,7 +101,8 @@ def _moe_infer(attrs, in_shapes, aux):
                       "capacity_factor": float, "num_experts_per_tok": int,
                       "experts_held": tuple, "score_func": str,
                       "route_norm": bool, "route_scale": float,
-                      "load_balance_coeff": float},
+                      "load_balance_coeff": float, "expert_act": str,
+                      "gated": bool},
           required_attrs=("num_experts", "hidden_size"),
           infer_shape=_moe_infer, num_outputs=2,
           out_names=("output", "aux_loss"), counters=_moe_counters)
@@ -120,8 +122,11 @@ def _moe(attrs, ins, octx):
     them by score plus the selection bias (auxiliary state
     ``router_bias``, moved after each training step by the sign rule,
     ``load_balance_coeff``), and the output is the part of the weighted
-    sum that the gated-SiLU experts held here give; no token is
-    dropped under any imbalance.  Expert weights are stacked on rows:
+    sum that the experts held here give; no token is dropped under any
+    imbalance.  An expert is ``(act(x.Wgate) * (x.Wup)).Wdown`` with
+    ``expert_act`` "silu" (the default) or "relu2", or with
+    ``gated=False`` the two matrices ``act(x.Wup).Wdown`` (no
+    ``experts_gate_weight`` then).  Expert weights are stacked on rows:
     gate and up (count * hidden_size, d), down (count * d, hidden_size).
     The second output is 0."""
     held = _held(attrs)
@@ -159,7 +164,8 @@ def _moe(attrs, ins, octx):
 
 def _moe_held(attrs, ins, octx, first, count):
     jnp = _jnp()
-    x, wr, wg, wu, wd, bias = ins
+    x, wr, *experts, bias = ins
+    wg, wu, wd = experts if len(experts) == 3 else (None,) + tuple(experts)
     E, f = int(attrs["num_experts"]), int(attrs["hidden_size"])
     k = int(attrs.get("num_experts_per_tok", 1))
     lead, d = x.shape[:-1], x.shape[-1]
@@ -175,7 +181,9 @@ def _moe_held(attrs, ins, octx, first, count):
         return w.astype(xt.dtype).reshape(count, out, -1).transpose(0, 2, 1)
 
     y, sizes, dropped = held_experts_ffn(
-        xt, chosen, weights, as_kn(wg, f), as_kn(wu, f), as_kn(wd, d), first)
+        xt, chosen, weights, None if wg is None else as_kn(wg, f),
+        as_kn(wu, f), as_kn(wd, d), first,
+        act=str(attrs.get("expert_act", "silu")))
     load = expert_load(chosen, E)
     here = load[first:first + count]
     count_op("moe.held_pairs", jnp.sum(sizes))

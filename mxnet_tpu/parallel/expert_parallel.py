@@ -3,7 +3,8 @@
 One router for every MoE layer (:func:`route`: top-k of softmax or
 sigmoid scores plus a selection bias, :func:`balance_bias` its sign
 rule) and two ways to run the experts.  :func:`held_experts_ffn` is one
-chip's share of an expert-parallel layer of gated-SiLU experts: the
+chip's share of an expert-parallel layer (gated experts of three
+matrices, or ungated ones of two, under a named activation): the
 pairs whose expert is held here, sorted by expert, as grouped matrix
 products, with no capacity and no dropped token (``sym.MoE`` with
 ``experts_held``).  The rest of the file is the Switch-Transformer-style
@@ -117,7 +118,13 @@ def _grouped_matmul(x, w, group_sizes):
     """Rows of x (M, K), sorted by group, each times its group's matrix
     of w (G, K, N); rows past the groups' total give zeros.  On a TPU
     the library's grouped-matmul kernel (megablox: only the row tiles a
-    group covers are computed), elsewhere ``lax.ragged_dot``."""
+    group covers are computed), elsewhere ``lax.ragged_dot``.  The
+    kernel's tiles are whole lanes of 128: a width that is none (an
+    expert 1,856 wide) is padded with zeros up to one, which adds
+    nothing to a product; XLA:TPU's ``ragged_dot`` multiplies every row
+    by every group's matrix."""
+    import math
+
     import jax
     import jax.numpy as jnp
 
@@ -126,13 +133,24 @@ def _grouped_matmul(x, w, group_sizes):
     valid = (jnp.arange(M) < jnp.sum(group_sizes))[:, None]
     x = jnp.where(valid, x, 0)      # and so its gradient's dead rows
     tile_m = next((t for t in (512, 256, 128) if M % t == 0), None)
-    if tile_m is not None and K % 128 == 0 and N % 128 == 0 and (
-            jax.default_backend() == "tpu" or _INTERPRET):
+    if tile_m is not None and (jax.default_backend() == "tpu" or _INTERPRET):
         from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
         tiling = (tile_m, min(K, 1024), min(N, 1024))
+        pad_k, pad_n = -K % 128, -N % 128
+        if pad_k or pad_n:
+            x = jnp.pad(x, ((0, 0), (0, pad_k)))
+            w = jnp.pad(w, ((0, 0), (0, pad_k), (0, pad_n)))
+            # a padded width only: one tile for both widths, so that it
+            # divides them in the backward products too, where they
+            # change places
+            tile = next(t for t in range(1024, 0, -128)
+                        if math.gcd(K + pad_k, N + pad_n) % t == 0)
+            tiling = (tile_m, tile, tile)
         with jax.named_scope("grouped_matmul"):
             y = megablox.gmm(x, w, group_sizes, x.dtype, tiling, None, None,
                              False, _INTERPRET)
+        if pad_n:
+            y = y[:, :N]
     else:
         y = jax.lax.ragged_dot(x, w, group_sizes.astype(jnp.int32),
                                preferred_element_type=jnp.float32
@@ -201,7 +219,8 @@ def _worst_case_rows(T, k, held):
     return T * min(k, held)
 
 
-def held_experts_ffn(x, chosen, weights, w_gate, w_up, w_down, first):
+def held_experts_ffn(x, chosen, weights, w_gate, w_up, w_down, first,
+                     act="silu"):
     """What the experts held here add to a layer's output: for every
     token-expert pair whose expert is one of ``first .. first + held``,
     weight * expert(token), summed by token.  No pair is dropped under
@@ -209,7 +228,10 @@ def held_experts_ffn(x, chosen, weights, w_gate, w_up, w_down, first):
 
     x: (T, d) tokens; chosen, weights: (T, k) from :func:`route` over
     ALL experts; w_gate, w_up: (held, d, f), w_down: (held, f, d), the
-    gated-SiLU experts held.  The pairs are sorted by expert, the held
+    experts held: ``(act(x.w_gate) * (x.w_up)).w_down``, or with
+    ``w_gate`` None the ungated ``act(x.w_up).w_down``; ``act`` is
+    ``"silu"`` or ``"relu2"`` (relu(x)^2).
+    The pairs are sorted by expert, the held
     ones first, into T * min(k, held) rows: the worst case, every token
     choosing only experts held here.  The grouped matrix products
     compute only the row tiles a pair sits in, so their work follows
@@ -224,9 +246,14 @@ def held_experts_ffn(x, chosen, weights, w_gate, w_up, w_down, first):
     import jax
     import jax.numpy as jnp
 
+    if act not in ("silu", "relu2"):
+        raise ValueError("held_experts_ffn: act must be 'silu' or 'relu2' "
+                         "(got %r)" % (act,))
+    activate = jax.nn.silu if act == "silu" else \
+        (lambda t: jnp.square(jnp.maximum(t, 0)))
     T, d = x.shape
     k = chosen.shape[1]
-    held = w_gate.shape[0]
+    held = w_up.shape[0]
     local = chosen - first                                   # (T, k)
     here = (local >= 0) & (local < held)
     key = jnp.where(here, local, held).reshape(-1)
@@ -245,11 +272,10 @@ def held_experts_ffn(x, chosen, weights, w_gate, w_up, w_down, first):
     mine = here.astype(jnp.float32)
 
     xs = take(x, token, pos, mine)
-    h = _grouped_matmul(xs, w_gate, group_sizes)
-    u = _grouped_matmul(xs, w_up, group_sizes)
-    act = (jax.nn.silu(h.astype(jnp.float32))
-           * u.astype(jnp.float32)).astype(x.dtype)
-    out = _grouped_matmul(act, w_down, group_sizes)
+    h = None if w_gate is None else _grouped_matmul(xs, w_gate, group_sizes)
+    u = _grouped_matmul(xs, w_up, group_sizes).astype(jnp.float32)
+    mid = activate(u) if h is None else activate(h.astype(jnp.float32)) * u
+    out = _grouped_matmul(mid.astype(x.dtype), w_down, group_sizes)
     y = fold(out, token, pos, weights.astype(jnp.float32) * mine)
     return y.astype(x.dtype), group_sizes, dropped
 

@@ -17,12 +17,13 @@ Module/Updater/executor stack applies it at the existing seams:
   carry it.
 * ``remat=...`` — a named ``jax.checkpoint`` policy for the segmented
   rematerialization evaluator: ``"none"``, ``"full"`` (a segment's
-  backward pass is handed ``FullyConnected``'s and attention's outputs
-  and makes the rest again), ``"dots_saveable"`` (keep matmul/conv
-  outputs, and attention's), ``"offload_bn_stats"`` (dots_saveable +
-  keep the tagged per-channel BatchNorm statistics), or a raw jax
-  policy callable (``jax.checkpoint_policies.nothing_saveable``: a
-  segment keeps nothing).  Trades recompute FLOPs for activation bytes.
+  backward pass is handed ``FullyConnected``'s, attention's and the
+  selective scan's outputs and makes the rest again),
+  ``"dots_saveable"`` (keep matmul/conv outputs, and attention's),
+  ``"offload_bn_stats"`` (dots_saveable + keep the tagged per-channel
+  BatchNorm statistics), or a raw jax policy callable
+  (``jax.checkpoint_policies.nothing_saveable``: a segment keeps
+  nothing).  Trades recompute FLOPs for activation bytes.
 * ``act_cast="int8"|"fp8"`` (EXPERIMENTAL, ``MXNET_PRECISION_EXPERIMENTAL=1``)
   — fake-quantized low-bit casts at the input seam, with device-side
   dynamic loss scaling for the narrow backward.
@@ -351,8 +352,9 @@ def fake_cast(jnp, v, kind):
 PRODUCT = "product"        # FullyConnected's output, in the activation type
 ATTENTION = "attention"    # attention's output (and the kernel's log-sum-exp)
 BN_STATS = "bn_stats"      # BatchNorm's per-channel batch statistics
+SCAN = "scan"              # a selective scan's output and boundary states
 
-_KEPT_NAMES = {"full": (PRODUCT, ATTENTION),
+_KEPT_NAMES = {"full": (PRODUCT, ATTENTION, SCAN),
                "dots": (ATTENTION,),
                "bn_stats": (BN_STATS, ATTENTION)}
 
@@ -405,9 +407,9 @@ def remat_checkpoint_policy(remat):
     """The ``jax.checkpoint`` policy object for a canonical remat spec
     (:func:`canon_remat` output).  Every named spec keeps the values
     named in :func:`remat_kept_names`: ``"full"`` those alone (the
-    matrix products' and attention's outputs; norms, rotations, casts
-    and activations are made again), ``"dots"`` and ``"bn_stats"``
-    beside what ``dots_saveable`` sees (attention is named because on a
+    matrix products', attention's and the selective scan's outputs;
+    norms, rotations, casts and activations are made again),
+    ``"dots"`` and ``"bn_stats"`` beside what ``dots_saveable`` sees (attention is named because on a
     TPU it is a Pallas call, no ``dot_general``).  A callable passes
     through: ``jax.checkpoint_policies.nothing_saveable`` is the strict
     "a segment keeps nothing"."""

@@ -6,7 +6,7 @@ package org.mxnettpu
 // operator; tensor inputs ride positionally, attributes stringify.
 
 object NDArrayGenerated {
-  /** relu/sigmoid/tanh/softrelu (src/operator/activation-inl.h), and
+  /** relu/sigmoid/tanh/softrelu (src/operator/activation-inl.h),
     * args: data: NDArray-or-Symbol; act_type: string, optional
     */
   def Activation(inputs: Seq[NDArray],
@@ -59,6 +59,15 @@ object NDArrayGenerated {
       outputs: Seq[NDArray] = null)
       : IndexedSeq[NDArray] =
     NDArray.invoke("Cast", inputs, params, outputs)
+
+  /** Depthwise convolution along each sequence of ``seq_len`` rows,
+    * args: data: NDArray-or-Symbol; weight: NDArray-or-Symbol; bias: NDArray-or-Symbol; kernel: int, required; seq_len: int, required
+    */
+  def CausalConv1D(inputs: Seq[NDArray],
+      params: Map[String, String] = Map.empty,
+      outputs: Seq[NDArray] = null)
+      : IndexedSeq[NDArray] =
+    NDArray.invoke("CausalConv1D", inputs, params, outputs)
 
   /** Concat
     * args: arg0: NDArray-or-Symbol; dim: int, optional; num_args: int, required
@@ -168,6 +177,15 @@ object NDArrayGenerated {
       : IndexedSeq[NDArray] =
     NDArray.invoke("FullyConnected", inputs, params, outputs)
 
+  /** RMSNorm of ``data * silu(gate)`` over each of ``groups`` equal
+    * args: data: NDArray-or-Symbol; gate: NDArray-or-Symbol; gamma: NDArray-or-Symbol; eps: float, optional; groups: int, optional
+    */
+  def GatedRMSNorm(inputs: Seq[NDArray],
+      params: Map[String, String] = Map.empty,
+      outputs: Seq[NDArray] = null)
+      : IndexedSeq[NDArray] =
+    NDArray.invoke("GatedRMSNorm", inputs, params, outputs)
+
   /** Affine/warp grid generation (src/operator/grid_generator-inl.h).
     * args: data: NDArray-or-Symbol; target_shape: Shape(tuple), optional; transform_type: string, optional
     */
@@ -268,7 +286,7 @@ object NDArrayGenerated {
     NDArray.invoke("MakeLoss", inputs, params, outputs)
 
   /** Mixture-of-experts block, two layers on one router
-    * args: data: NDArray-or-Symbol; gate_weight: NDArray-or-Symbol; expert1_weight: NDArray-or-Symbol; expert1_bias: NDArray-or-Symbol; expert2_weight: NDArray-or-Symbol; expert2_bias: NDArray-or-Symbol; capacity_factor: float, optional; experts_held: Shape(tuple), optional; hidden_size: int, required; load_balance_coeff: float, optional; num_experts: int, required; num_experts_per_tok: int, optional; route_norm: boolean, optional; route_scale: float, optional; score_func: string, optional
+    * args: data: NDArray-or-Symbol; gate_weight: NDArray-or-Symbol; expert1_weight: NDArray-or-Symbol; expert1_bias: NDArray-or-Symbol; expert2_weight: NDArray-or-Symbol; expert2_bias: NDArray-or-Symbol; capacity_factor: float, optional; expert_act: string, optional; experts_held: Shape(tuple), optional; gated: boolean, optional; hidden_size: int, required; load_balance_coeff: float, optional; num_experts: int, required; num_experts_per_tok: int, optional; route_norm: boolean, optional; route_scale: float, optional; score_func: string, optional
     */
   def MoE(inputs: Seq[NDArray],
       params: Map[String, String] = Map.empty,
@@ -365,6 +383,15 @@ object NDArrayGenerated {
       outputs: Seq[NDArray] = null)
       : IndexedSeq[NDArray] =
     NDArray.invoke("RoPE", inputs, params, outputs)
+
+  /** The Mamba-2 selective scan over rows cut into sequences of
+    * args: data: NDArray-or-Symbol; dt: NDArray-or-Symbol; B: NDArray-or-Symbol; C: NDArray-or-Symbol; A_log: NDArray-or-Symbol; dt_bias: NDArray-or-Symbol; D: NDArray-or-Symbol; chunk: int, optional; groups: int, required; head_dim: int, required; heads: int, required; seq_len: int, required; state: int, required
+    */
+  def SSD(inputs: Seq[NDArray],
+      params: Map[String, String] = Map.empty,
+      outputs: Seq[NDArray] = null)
+      : IndexedSeq[NDArray] =
+    NDArray.invoke("SSD", inputs, params, outputs)
 
   /** Hinge-loss output layer (src/operator/svm_output-inl.h).
     * args: data: NDArray-or-Symbol; label: NDArray-or-Symbol; margin: float, optional; regularization_coefficient: float, optional; use_linear: boolean, optional

@@ -6,7 +6,7 @@ package org.mxnettpu
 // operator; tensor inputs ride positionally, attributes stringify.
 
 object SymbolGenerated {
-  /** relu/sigmoid/tanh/softrelu (src/operator/activation-inl.h), and */
+  /** relu/sigmoid/tanh/softrelu (src/operator/activation-inl.h), */
   def Activation(args: Map[String, Symbol],
       params: Map[String, String] = Map.empty,
       name: String = null): Symbol =
@@ -41,6 +41,12 @@ object SymbolGenerated {
       params: Map[String, String] = Map.empty,
       name: String = null): Symbol =
     Symbol.create("Cast", args, params, name)
+
+  /** Depthwise convolution along each sequence of ``seq_len`` rows, */
+  def CausalConv1D(args: Map[String, Symbol],
+      params: Map[String, String] = Map.empty,
+      name: String = null): Symbol =
+    Symbol.create("CausalConv1D", args, params, name)
 
   /** Concat */
   def Concat(args: Map[String, Symbol],
@@ -113,6 +119,12 @@ object SymbolGenerated {
       params: Map[String, String] = Map.empty,
       name: String = null): Symbol =
     Symbol.create("FullyConnected", args, params, name)
+
+  /** RMSNorm of ``data * silu(gate)`` over each of ``groups`` equal */
+  def GatedRMSNorm(args: Map[String, Symbol],
+      params: Map[String, String] = Map.empty,
+      name: String = null): Symbol =
+    Symbol.create("GatedRMSNorm", args, params, name)
 
   /** Affine/warp grid generation (src/operator/grid_generator-inl.h). */
   def GridGenerator(args: Map[String, Symbol],
@@ -245,6 +257,12 @@ object SymbolGenerated {
       params: Map[String, String] = Map.empty,
       name: String = null): Symbol =
     Symbol.create("RoPE", args, params, name)
+
+  /** The Mamba-2 selective scan over rows cut into sequences of */
+  def SSD(args: Map[String, Symbol],
+      params: Map[String, String] = Map.empty,
+      name: String = null): Symbol =
+    Symbol.create("SSD", args, params, name)
 
   /** Hinge-loss output layer (src/operator/svm_output-inl.h). */
   def SVMOutput(args: Map[String, Symbol],
