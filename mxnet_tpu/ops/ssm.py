@@ -43,6 +43,59 @@ def _conv_infer(attrs, in_shapes, aux):
     return in_shapes, [tuple(data)], aux
 
 
+def _conv_and_back(K, T, bias_dtype):
+    """conv(x, w, b) over x (S * T, C), and its backward pass:
+
+        dx[t]    = sum_j w[:, j] * dy[t + (K-1) - j]
+        dw[:, j] = sum_{s,t} dy[s, t] * x[s, t - (K-1) + j]
+        db       = sum_{s,t} dy[s, t]
+
+    the mirrored convolution (zeros past a sequence's last row) and one
+    pass of reductions.  Left to jax, the transpose of the forward sum
+    writes the K products ``w[:, j] * dy`` to memory in float32 before
+    it shifts and adds them.  Operands stay in their own type in
+    memory; every product and sum is float32."""
+    import jax
+    import jax.numpy as jnp
+    f32 = jnp.float32
+
+    def shifted(a, before, after):
+        """a (S, T, C) as K float32 views of T rows, the j-th j rows
+        further down the zero-padded sequence: upcast behind the pad
+        and the slice, so no float32 copy of the padded array."""
+        ap = jnp.pad(a, ((0, 0), (before, after), (0, 0)))
+        return [ap[:, j:j + T].astype(f32) for j in range(K)]
+
+    @jax.custom_vjp
+    def conv(x, w, b):
+        with jax.named_scope("mx.ssm.conv"):
+            w = w.astype(f32)
+            xs = shifted(x.reshape(-1, T, x.shape[-1]), K - 1, 0)
+            y = sum(w[:, j] * xs[j] for j in range(K)) + b.astype(f32)
+            return y.reshape(x.shape).astype(x.dtype)
+
+    def conv_fwd(x, w, b):
+        return conv(x, w, b), (x, w)
+
+    def conv_bwd(res, dy):
+        x, w = res
+        with jax.named_scope("mx.ssm.conv"):
+            w32 = w.astype(f32)
+            dy = dy.reshape(-1, T, dy.shape[-1])
+            dys = shifted(dy, 0, K - 1)
+            dx = sum(w32[:, j] * dys[K - 1 - j] for j in range(K))
+            dy32 = dy.astype(f32)
+            xs = shifted(x.reshape(dy.shape), K - 1, 0)
+            dw = jnp.stack([jnp.sum(dy32 * xs[j], axis=(0, 1))
+                            for j in range(K)], axis=1)
+            db = jnp.sum(dy32, axis=(0, 1))
+            return (dx.reshape(x.shape).astype(x.dtype), dw.astype(w.dtype),
+                    db.astype(bias_dtype))
+
+    conv.defvjp(conv_fwd, conv_bwd)
+    return conv
+
+
 @register("CausalConv1D", arg_names=("data", "weight", "bias"),
           attr_types={"kernel": int, "seq_len": int},
           required_attrs=("kernel", "seq_len"), infer_shape=_conv_infer)
@@ -51,18 +104,13 @@ def _causal_conv1d(attrs, ins, octx):
     looking back only: ``y[t] = bias + sum_j weight[:, j] *
     x[t - (kernel - 1) + j]``, with zeros before a sequence's first
     row.  data (rows, channels), weight (channels, kernel), bias
-    (channels,).  Summed in float32."""
-    import jax
-    import jax.numpy as jnp
-    x, w = ins[0], ins[1].astype(jnp.float32)
+    (channels,).  Summed in float32.  Its gradient is written out, not
+    left to jax: data's is the mirrored convolution of the output's,
+    weight's and bias's one pass of float32 sums over the rows."""
+    x, w, b = ins
     K, T = int(attrs["kernel"]), int(attrs["seq_len"])
-    S = _sequences("CausalConv1D", x.shape[0], T)
-    with jax.named_scope("mx.ssm.conv"):
-        xp = jnp.pad(x.astype(jnp.float32).reshape(S, T, -1),
-                     ((0, 0), (K - 1, 0), (0, 0)))
-        y = sum(w[:, j] * xp[:, j:j + T] for j in range(K)) \
-            + ins[2].astype(jnp.float32)
-        return [y.reshape(x.shape).astype(x.dtype)]
+    _sequences("CausalConv1D", x.shape[0], T)
+    return [_conv_and_back(K, T, b.dtype)(x, w, b)]
 
 
 # ---------------------------------------------------------------------------

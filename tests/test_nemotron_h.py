@@ -166,6 +166,113 @@ def test_causal_conv_is_the_plain_loop(S, T):
                                rtol=1e-5)
 
 
+def _plain_conv(x, w, b, S, T, K):
+    """The op's formula as jax would differentiate it, all float32."""
+    xp = jnp.pad(x.reshape(S, T, -1), ((0, 0), (K - 1, 0), (0, 0)))
+    return (sum(w[:, j] * xp[:, j:j + T] for j in range(K)) + b) \
+        .reshape(x.shape)
+
+
+def _conv_through_the_symbol(x, w, b, dy, T, K):
+    """(output, gradients of data, weight, bias) of sym.CausalConv1D
+    bound on the CPU, every array in x's type."""
+    args = {"data": x, "conv_weight": w, "conv_bias": b}
+    nd = lambda a: mx.nd.array(np.asarray(a, np.float32)) \
+        .astype(str(x.dtype))                                  # noqa: E731
+    grads = {k: nd(jnp.zeros(a.shape)) for k, a in args.items()}
+    ex = mx.sym.CausalConv1D(
+        mx.sym.Variable("data"), kernel=K, seq_len=T, name="conv").bind(
+            mx.cpu(), {k: nd(a) for k, a in args.items()}, args_grad=grads)
+    out = ex.forward(is_train=True)[0].asnumpy()
+    ex.backward([nd(dy)])
+    return out, [grads[k].asnumpy() for k in args]
+
+
+def _conv_inputs(S, T, K, dtype, C=5, seed=7):
+    rs = np.random.RandomState(seed)
+    return [jnp.asarray(rs.randn(*shape), jnp.float32).astype(dtype)
+            for shape in ((S * T, C), (C, K), (C,), (S * T, C))]
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("S,T,K", [(2, 7, 4), (1, 3, 4), (2, 7, 2), (1, 1, 2)])
+def test_causal_conv_gradients_are_jaxs_of_the_plain_formula(S, T, K, dtype):
+    """The backward pass the op writes out (the mirrored convolution,
+    one pass of sums for the taps and the bias) against `jax.vjp` of
+    the formula in float32: two sequences longer than the kernel and
+    one shorter than it, a kernel of 2 and of 4."""
+    x, w, b, dy = _conv_inputs(S, T, K, dtype)
+    out, got = _conv_through_the_symbol(x, w, b, dy, T, K)
+    f32 = [a.astype(jnp.float32) for a in (x, w, b)]
+    want_out, back = jax.vjp(lambda *a: _plain_conv(*a, S, T, K), *f32)
+    want = back(dy.astype(jnp.float32))
+    # float32: the same products summed in another order.  bfloat16: the
+    # operands are exact in float32 and every sum is float32, so each
+    # gradient is the float32 value rounded ONCE to 8 bits of mantissa,
+    # half a unit in the last place (2**-9 of the value), and a sum
+    # reordered in float32 may land across a rounding edge: one unit
+    tol = dict(rtol=1e-5, atol=1e-5) if dtype == "float32" \
+        else dict(rtol=2.0 ** -8, atol=1e-6)
+    np.testing.assert_allclose(out.astype(np.float32), want_out, **tol)
+    for name, g, ref in zip(("data", "weight", "bias"), got, want):
+        assert g.dtype == out.dtype and g.shape == ref.shape
+        np.testing.assert_allclose(g.astype(np.float32), ref, err_msg=name,
+                                   **tol)
+
+
+def test_causal_conv_backward_stays_inside_a_sequence():
+    """Backward the convolution looks ahead, and no further than its
+    own sequence's last row: the second sequence's gradient is the same
+    to the bit whatever the first one's cotangent."""
+    S, T, K = 2, 5, 4
+    x, w, b, dy = _conv_inputs(S, T, K, "float32", seed=8)
+    other = dy.at[:T].set(3.0 * dy[:T] + 1.0)
+    (dx, _, _), (dx_other, _, _) = (
+        _conv_through_the_symbol(x, w, b, d, T, K)[1] for d in (dy, other))
+    np.testing.assert_array_equal(dx[T:], dx_other[T:])
+    assert np.abs(dx[:T] - dx_other[:T]).min() > 0
+    # and a row's gradient reads the K - 1 rows after it: change the
+    # sequence's last cotangent and exactly its last K rows move
+    last = dy.at[T - 1].add(1.0)
+    moved = np.abs(_conv_through_the_symbol(x, w, b, last, T, K)[1][0]
+                   - dx).max(axis=1) > 0
+    assert moved.tolist() == [False] * (T - K) + [True] * K + [False] * T
+
+
+def _avals(jaxpr):
+    """Every value a jaxpr makes, the jaxprs in its equations included."""
+    for eqn in jaxpr.eqns:
+        for v in eqn.outvars:
+            yield v.aval
+        for sub in jax.tree_util.tree_leaves(
+                list(eqn.params.values()),
+                is_leaf=lambda p: hasattr(p, "eqns") or hasattr(p, "jaxpr")):
+            sub = getattr(sub, "jaxpr", sub)
+            if hasattr(sub, "eqns"):
+                yield from _avals(sub)
+
+
+def test_causal_conv_backward_makes_no_float32_copy_of_the_padded_input():
+    """bfloat16 operands stay bfloat16 in memory: the pad and the
+    shifted slices come before the upcast, so neither pass holds a
+    float32 array of the padded shape (S, T + K - 1, C); the float32
+    values are (S, T, C) views that XLA fuses into the sums."""
+    from mxnet_tpu.registry import OpContext, get_op
+    S, T, K, C = 2, 16, 4, 8
+    x, w, b, dy = _conv_inputs(S, T, K, "bfloat16", C=C)
+    conv = lambda *ins: get_op("CausalConv1D").fcompute(   # noqa: E731
+        {"kernel": K, "seq_len": T}, list(ins), OpContext(True))[0]
+    back = jax.make_jaxpr(lambda x, w, b, dy: jax.vjp(conv, x, w, b)[1](dy))(
+        x, w, b, dy)
+    assert [v.aval.dtype for v in back.jaxpr.outvars] == [jnp.bfloat16] * 3
+    made = [(a.shape, str(a.dtype)) for a in _avals(back.jaxpr)
+            if hasattr(a, "shape")]
+    assert ((S, T + K - 1, C), "bfloat16") in made      # x's pad, and dy's
+    assert ((S, T, C), "float32") in made
+    assert not [m for m in made if m[0] == (S, T + K - 1, C)
+                and m[1] != "bfloat16"]
+
+
 def test_gated_norm_over_groups_and_relu2():
     rs = np.random.RandomState(2)
     x, z = (rs.randn(6, 16).astype(np.float32) for _ in range(2))

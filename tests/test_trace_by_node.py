@@ -46,12 +46,68 @@ def test_device_time_by_symbol_node_reads_the_traces_hlo(tmp_path):
               ("%fusion.2 = f32[] fusion(...)", 10, 40),
               ("%fusion.3 = f32[] fusion(...)", 50, 60),
               ("%copy.4 = f32[] copy(...)", 100, 130)]
-    by, unnamed = trace_by_node.split_by_group(
+    by, unnamed, by_instruction = trace_by_node.split_by_group(
         events, {"cond.1": "jit(s)/l1_moe/cond", "fusion.2": "jit(s)/l1_moe/a",
                  "fusion.3": "jit(s)/jvp(l0_attn)/b", "copy.4": ""})
-    assert by == {"moe": pytest.approx(90e-9), "attention": pytest.approx(10e-9),
-                  trace_by_node.REST: pytest.approx(30e-9)}
+    assert by == {("moe", "forward"): pytest.approx(90e-9),
+                  ("attention", "forward"): pytest.approx(10e-9),
+                  (trace_by_node.REST, "forward"): pytest.approx(30e-9)}
     assert unnamed == pytest.approx(30e-9)
+    assert by_instruction["cond.1"][:3] == [pytest.approx(60e-9), 1,
+                                            "jit(s)/l1_moe/cond"]
     # the whole trace, on the CPU: no device plane, nothing to reduce
     assert trace_by_node.reduce_by_group(
         trace_reduce.find_xplane(str(tmp_path))) is None
+
+
+STEP = "jit(train_step)/"
+BACK = STEP + "transpose(jvp(jvp()))/checkpoint/"
+
+
+@pytest.mark.parametrize("op_name,group,which", [
+    # a Mamba-2 layer's nodes as the remat step of models/nemotron_h.py
+    # spells them (read from a CPU trace of the tiny model)
+    (STEP + "jvp(l0_conv)/mx.ssm.conv/mul", "conv", "forward"),
+    (BACK + "l0_conv/mx.ssm.conv/reduce_sum", "conv", "backward"),
+    (BACK + "rematted_computation/l2_conv_silu/logistic", "conv",
+     "made again"),
+    (STEP + "jvp(l0_ssd)/mx.ssm.intra/bcgrqs,bcsgrp->bcqgrp/dot_general",
+     "scan.intra", "forward"),
+    (BACK + "l6_ssd/mx.ssm.carry/while/body/closed_call/mul", "scan.carry",
+     "backward"),
+    (BACK + "rematted_computation/l0_ssd/jit(softplus)/log1p", "scan",
+     "made again"),
+    (BACK + "l4_in/dot_general", "mamba_proj", "backward"),
+    (STEP + "jvp(l4_out)/dot_general", "mamba_proj", "forward"),
+    (BACK + "l0_ssm_norm/mx.ssm.norm/rsqrt", "ssm_norm", "backward"),
+    (STEP + "jvp(l1_shared_up)/dot_general", "shared_ffn", "forward"),
+    (BACK + "l7_q/dot_general", "attn_proj", "backward"),
+    # the innermost node wins; a slice of the projection is no group
+    (STEP + "jvp(l0_in)/jvp(l0_conv)/mx.ssm.conv/add", "conv", "forward"),
+    (STEP + "jvp(l0_xbc)/slice", trace_by_node.REST, "forward"),
+    # the update names neither a node nor a pass
+    (STEP + "sgd_update/mul", trace_by_node.REST, "forward"),
+])
+def test_mamba_nodes_and_the_pass_from_the_path(op_name, group, which):
+    assert trace_by_node.group_of(op_name) == group
+    assert trace_by_node.pass_of(op_name) == which
+
+
+def test_the_report_has_a_row_a_group_and_a_column_a_pass():
+    got = {"by_group": {("scan.intra", "forward"): 0.01,
+                        ("scan", "backward"): 0.02,
+                        ("conv", "made again"): 0.003,
+                        (trace_by_node.REST, "forward"): 0.05},
+           "unnamed_s": 0.01, "instructions_named": 5, "instructions": 9,
+           "by_instruction": {
+               "fusion.1": [0.02, 20, "a/l0_conv/b", "%fusion.1 = f32[] x"],
+               "fusion.2": [0.01, 10, "", "%fusion.2 = f32[] y"]}}
+    lines = trace_by_node.report(got, 10, top=1).split("\n")
+    assert lines[0].split() == ["ms", "a", "step", "forward", "made", "again",
+                                "backward", "all"]
+    assert [ln.split()[0] for ln in lines[1:6]] == [
+        "conv", "scan", "scan.intra", "rest", "sum"]
+    assert lines[1].split()[1:] == ["0.000", "0.300", "0.000", "0.300"]
+    assert lines[5].split()[1:] == ["6.000", "0.300", "2.000", "8.300"]
+    assert "fusion.1" in lines[7] and "2.0 runs" in lines[7]
+    assert not any("fusion.2" in ln for ln in lines)

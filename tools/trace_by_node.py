@@ -2,7 +2,7 @@
 """Device time of a traced run by symbol node.
 
     python3 tools/trace_by_node.py <trace dir> [--steps N]
-        [--window bench.window] [--program train_step]
+        [--window bench.window] [--program train_step] [--top N]
 
 The executor runs every node of the symbol under
 `jax.named_scope(node.name)`, so each HLO instruction of the step
@@ -15,8 +15,11 @@ trace keeps each program's HLO proto beside them (plane
 reads the instructions' `op_name` from that proto and sums the events'
 own time (an instruction's time less that of the instructions nested
 in it: a conditional's branch, a loop's body) by the group of nodes
-they name, for the device that was busy longest; what names no group is
-the remainder, printed with the rest, never dropped.
+they name and by the pass the path shows (forward, a checkpointed
+segment made again, backward), for the device that was busy longest;
+what names no group is the remainder, printed with the rest, never
+dropped.  `--top N` lists the N instructions that took longest, each
+with the end of its `op_name`.
 
 The trace is any `jax.profiler.trace(dir)` around `Module.fit`;
 `--window` names a `TraceAnnotation` that brackets the steps to count
@@ -35,10 +38,21 @@ from benchmark import trace_reduce  # noqa: E402
 GROUPS = (
     # (group, regex on a node name): first match wins
     ("attention", re.compile(r"^l\d+_attn$")),
+    ("attn_proj", re.compile(r"^l\d+_(q|k|v|o|gate)$")),
     ("moe", re.compile(r"^l\d+_moe$")),
+    ("shared_ffn", re.compile(r"^l\d+_(shared|mlp)_\w+$")),
     ("lm_head", re.compile(r"^(head|softmax)$")),
+    # a Mamba-2 layer (models/nemotron_h.py): the convolution with its
+    # silu, the scan, the two projections, the gated norm
+    ("conv", re.compile(r"^l\d+_conv(_silu)?$")),
+    ("scan", re.compile(r"^l\d+_ssd$")),
+    ("mamba_proj", re.compile(r"^l\d+_(in|out)$")),
+    ("ssm_norm", re.compile(r"^l\d+_ssm_norm$")),
 )
 REST = "rest"
+SPLIT_BY_SCOPE = "scan"     # further by ops/ssm.py's `mx.ssm.<scope>`
+_SCOPE = re.compile(r"^mx\.ssm\.(\w+)$")
+PASSES = ("forward", "made again", "backward")
 METADATA_PLANE = "/host:metadata"
 HLO_STAT = "hlo proto"
 _WORD = re.compile(r"[A-Za-z0-9_.\-]+")
@@ -48,13 +62,33 @@ _INSTRUCTION = re.compile(r"^%?([^\s=]+) = ")
 def group_of(op_name):
     """The group of the innermost symbol node an `op_name` path names,
     or REST.  A path element is a node's name as it stands, or wrapped
-    by autodiff: `jvp(l0_attn)`, `transpose(jvp(l0_attn))`."""
+    by autodiff: `jvp(l0_attn)`, `transpose(jvp(l0_attn))`.  A scan's
+    instructions are split further by the scope inside the node:
+    `scan.intra` under `l0_ssd/mx.ssm.intra`, `scan` outside them."""
+    scope = ""
     for part in reversed(op_name.split("/")):
         for word in _WORD.findall(part):
+            m = _SCOPE.match(word)
+            if m and not scope:
+                scope = "." + m.group(1)
             for group, pattern in GROUPS:
                 if pattern.match(word):
-                    return group
+                    return group + scope if group == SPLIT_BY_SCOPE \
+                        else group
     return REST
+
+
+def pass_of(op_name):
+    """Which pass of the step an `op_name` path lies in: a segment
+    under `jax.checkpoint` run a second time
+    (`.../checkpoint/rematted_computation/l0_conv/...`) is "made
+    again", any other path under `transpose(...)` "backward", and the
+    rest "forward": `jvp(l0_conv)`, and what names no pass at all (the
+    update, the casts before the first layer)."""
+    forward, made_again, backward = PASSES
+    if "rematted_computation" in op_name:
+        return made_again
+    return backward if "transpose(" in op_name else forward
 
 
 # ------------------------------------------------- protobuf, by hand
@@ -175,25 +209,31 @@ def own_times(events):
 
 
 def split_by_group(events, names):
-    """Seconds by group of events (HLO text, start, end); `names` maps
-    an instruction to its op_name.  An instruction the program's HLO
-    does not know, or one with no op_name, goes to REST; their time is
-    also returned as `unnamed`."""
-    by_group, unnamed = {}, 0.0
+    """Seconds by (group, pass) of events (HLO text, start, end);
+    `names` maps an instruction to its op_name.  An instruction the
+    program's HLO does not know, or one with no op_name, goes to REST,
+    forward; their time is also returned as `unnamed`.  Third, by
+    instruction: {name: [seconds, runs, op_name, HLO text]}."""
+    by_group, unnamed, by_instruction = {}, 0.0, {}
     for text, own in own_times(events):
         m = _INSTRUCTION.match(text)
-        op_name = names.get(m.group(1) if m else text, "")
-        group = group_of(op_name) if op_name else REST
+        instruction = m.group(1) if m else text
+        op_name = names.get(instruction, "")
+        key = (group_of(op_name), pass_of(op_name))
         if not op_name:
             unnamed += own * 1e-9
-        by_group[group] = by_group.get(group, 0.0) + own * 1e-9
-    return by_group, unnamed
+        by_group[key] = by_group.get(key, 0.0) + own * 1e-9
+        rec = by_instruction.setdefault(instruction, [0.0, 0, op_name, text])
+        rec[0] += own * 1e-9
+        rec[1] += 1
+    return by_group, unnamed, by_instruction
 
 
 def reduce_by_group(path, window_name=None, step_module="train_step"):
     """For the device that was busy longest inside the last
-    `window_name` span (None: the whole trace): seconds by group of the
-    instructions that ran inside programs named like `step_module`."""
+    `window_name` span (None: the whole trace): seconds by (group,
+    pass) of the instructions that ran inside programs named like
+    `step_module`, and by instruction."""
     from jax.profiler import ProfileData
     with open(path, "rb") as f:
         raw = f.read()
@@ -234,8 +274,9 @@ def reduce_by_group(path, window_name=None, step_module="train_step"):
                 j += 1
             if j < len(inside) and ev[1] >= inside[j][0]:
                 kept.append(ev)
-        by_group, unnamed = split_by_group(kept, names)
+        by_group, unnamed, by_instruction = split_by_group(kept, names)
         got = {"by_group": by_group, "unnamed_s": unnamed,
+               "by_instruction": by_instruction,
                "ops_s": sum(by_group.values()),
                "instructions_named": sum(1 for v in names.values() if v),
                "instructions": len(names)}
@@ -251,20 +292,38 @@ def main(argv=None):
                     help="steps inside the window: times are per step")
     ap.add_argument("--window", default=None)
     ap.add_argument("--program", default="train_step")
+    ap.add_argument("--top", type=int, default=0,
+                    help="list the N instructions that took longest")
     args = ap.parse_args(argv)
     got = reduce_by_group(trace_reduce.find_xplane(args.trace_dir),
                           args.window, args.program)
     if got is None:
         sys.exit("no device plane, or no span %r, in the trace" % args.window)
-    by = got["by_group"]
-    for group in [g for g, _p in GROUPS] + [REST]:
-        print("%-10s %10.3f ms" % (group, 1e3 * by.get(group, 0.0)
-                                   / args.steps))
-    print("%-10s %10.3f ms (instructions with no node: %.3f; %d of the "
-          "program's %d instructions carry an op_name)" % (
-              "sum", 1e3 * got["ops_s"] / args.steps,
-              1e3 * got["unnamed_s"] / args.steps,
-              got["instructions_named"], got["instructions"]))
+    print(report(got, args.steps, args.top))
+
+
+def report(got, steps, top=0):
+    """The table main() prints: ms a step, a row a group (a split
+    group's parts after it), a column a pass."""
+    by, ms = got["by_group"], 1e3 / steps
+    groups = [g for g, _p in GROUPS] + [REST]
+    rows = sorted({g for g, _p in by},
+                  key=lambda g: (groups.index(g.split(".")[0]), g))
+    lines = ["%-14s" % "ms a step" + "".join("%12s" % p for p in PASSES)
+             + "%12s" % "all"]
+    for label, summed in [(g, [g]) for g in rows] + [("sum", rows)]:
+        cells = [sum(by.get((g, p), 0.0) for g in summed) for p in PASSES]
+        lines.append("%-14s" % label + "".join(
+            "%12.3f" % (ms * c) for c in cells + [sum(cells)]))
+    lines.append("instructions with no node: %.3f ms; %d of the program's %d "
+                 "instructions carry an op_name" % (
+                     ms * got["unnamed_s"], got["instructions_named"],
+                     got["instructions"]))
+    longest = sorted(got["by_instruction"].items(), key=lambda kv: -kv[1][0])
+    for name, (s, runs, op_name, text) in longest[:top]:
+        lines.append("%9.3f ms %5.1f runs  %s  ...%s\n%21s%s" % (
+            ms * s, runs / steps, name, op_name[-110:], "", text[:200]))
+    return "\n".join(lines)
 
 
 if __name__ == "__main__":
