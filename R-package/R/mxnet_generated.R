@@ -63,6 +63,7 @@ mx.symbol.Cast <- function(..., name = NULL) mx.internal.gen.sym("Cast", list(..
 #   weight : NDArray-or-Symbol
 #   bias : NDArray-or-Symbol
 #   kernel : int, required
+#   no_bias : boolean, optional
 #   seq_len : int, required
 mx.nd.CausalConv1D <- function(..., out = NULL) mx.internal.gen.nd("CausalConv1D", list(...), out)
 mx.symbol.CausalConv1D <- function(..., name = NULL) mx.internal.gen.sym("CausalConv1D", list(...), name)
@@ -186,6 +187,21 @@ mx.nd.Flatten <- function(..., out = NULL) mx.internal.gen.nd("Flatten", list(..
 #   num_hidden : int, required
 mx.nd.FullyConnected <- function(..., out = NULL) mx.internal.gen.nd("FullyConnected", list(...), out)
 
+# The gated delta rule (Kimi Delta Attention) over rows cut into
+#   query : NDArray-or-Symbol
+#   key : NDArray-or-Symbol
+#   value : NDArray-or-Symbol
+#   gate : NDArray-or-Symbol
+#   beta : NDArray-or-Symbol
+#   A_log : NDArray-or-Symbol
+#   dt_bias : NDArray-or-Symbol
+#   chunk : int, optional
+#   head_dim : int, required
+#   heads : int, required
+#   seq_len : int, required
+mx.nd.GatedDeltaRule <- function(..., out = NULL) mx.internal.gen.nd("GatedDeltaRule", list(...), out)
+mx.symbol.GatedDeltaRule <- function(..., name = NULL) mx.internal.gen.sym("GatedDeltaRule", list(...), name)
+
 # RMSNorm of ``data * silu(gate)`` over each of ``groups`` equal
 #   data : NDArray-or-Symbol
 #   gate : NDArray-or-Symbol
@@ -213,6 +229,7 @@ mx.symbol.GridGenerator <- function(..., name = NULL) mx.internal.gen.sym("GridG
 #   num_kv_heads : int, required
 #   scale : float, optional
 #   seq_len : int, required
+#   v_head_dim : int, optional
 #   window : int, optional
 mx.nd.GroupedQueryAttention <- function(..., out = NULL) mx.internal.gen.nd("GroupedQueryAttention", list(...), out)
 mx.symbol.GroupedQueryAttention <- function(..., name = NULL) mx.internal.gen.sym("GroupedQueryAttention", list(...), name)
@@ -247,6 +264,15 @@ mx.symbol.L2Normalization <- function(..., name = NULL) mx.internal.gen.sym("L2N
 #   knorm : float, optional
 #   nsize : int, optional
 mx.nd.LRN <- function(..., out = NULL) mx.internal.gen.nd("LRN", list(...), out)
+
+# The per-head keys and values of latent attention from the
+#   data : NDArray-or-Symbol
+#   shared_key : NDArray-or-Symbol
+#   key_dim : int, required
+#   num_heads : int, required
+#   value_dim : int, required
+mx.nd.LatentExpand <- function(..., out = NULL) mx.internal.gen.nd("LatentExpand", list(...), out)
+mx.symbol.LatentExpand <- function(..., name = NULL) mx.internal.gen.sym("LatentExpand", list(...), name)
 
 # leaky/prelu/elu/rrelu (src/operator/leaky_relu-inl.h).
 #   data : NDArray-or-Symbol

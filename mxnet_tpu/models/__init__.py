@@ -18,13 +18,14 @@ from . import inception_resnet_v2
 from . import lstm
 from . import afmoe
 from . import nemotron_h
+from . import kimi_linear
 
 _MODELS = {
     "mlp": mlp, "lenet": lenet, "alexnet": alexnet, "vgg": vgg,
     "inception-bn": inception_bn,
     "inception-v3": inception_v3, "googlenet": googlenet,
     "inception-resnet-v2": inception_resnet_v2,
-    "afmoe": afmoe, "nemotron_h": nemotron_h,
+    "afmoe": afmoe, "nemotron_h": nemotron_h, "kimi_linear": kimi_linear,
 }  # resnet/resnext dispatch via the prefix loop in get_symbol
 
 

@@ -1,6 +1,7 @@
 """State-space layer ops: a causal depthwise convolution along the
-sequence, the Mamba-2 selective scan in its chunked form, and the gated
-norm over groups of channels that follows it.
+sequence, the Mamba-2 selective scan and the gated delta rule in their
+chunked forms, and the gated norm over groups of channels that follows
+the scan.
 
 Rows are tokens, as in :mod:`.transformer`: every op takes
 ``(rows, width)`` activations with ``rows = sequences * seq_len``, and
@@ -18,6 +19,17 @@ dual form): inside a chunk as products over all chunks at once, and
 between chunks by ONE short ``lax.scan`` over the chunk states, so a
 sequence of 8,192 costs 64 sequential steps, not 8,192.  jax
 differentiates it through that form.
+
+The gated delta rule (Kimi Delta Attention), per head, with state ``S``
+(head_dim x head_dim) from 0, a decay per channel and a correction of
+rank one each step::
+
+    S_t = (I - beta_t k_t k_t^T) Diag(alpha_t) S_{t-1} + beta_t k_t v_t^T
+    o_t = S_t^T q_t
+
+multiplies its state by a MATRIX each step, so its chunked form
+(:func:`gated_delta_chunked`) has a unit-triangular solve inside each
+chunk and a carry of two matrix products a chunk.
 """
 from __future__ import annotations
 
@@ -35,11 +47,17 @@ def _sequences(name, rows, seq_len):
 # ---------------------------------------------------------------------------
 # causal depthwise convolution
 # ---------------------------------------------------------------------------
+def _conv_args(attrs):
+    return ("data", "weight") if attrs.get("no_bias", False) \
+        else ("data", "weight", "bias")
+
+
 def _conv_infer(attrs, in_shapes, aux):
     data = in_shapes[0]
     if data is None:
         return in_shapes, None, aux
-    in_shapes[1:] = [(data[-1], int(attrs["kernel"])), (data[-1],)]
+    in_shapes[1:] = [(data[-1], int(attrs["kernel"])), (data[-1],)][
+        :len(in_shapes) - 1]
     return in_shapes, [tuple(data)], aux
 
 
@@ -96,20 +114,28 @@ def _conv_and_back(K, T, bias_dtype):
     return conv
 
 
-@register("CausalConv1D", arg_names=("data", "weight", "bias"),
-          attr_types={"kernel": int, "seq_len": int},
+@register("CausalConv1D", arg_names=_conv_args,
+          attr_types={"kernel": int, "seq_len": int, "no_bias": bool},
           required_attrs=("kernel", "seq_len"), infer_shape=_conv_infer)
 def _causal_conv1d(attrs, ins, octx):
     """Depthwise convolution along each sequence of ``seq_len`` rows,
     looking back only: ``y[t] = bias + sum_j weight[:, j] *
     x[t - (kernel - 1) + j]``, with zeros before a sequence's first
     row.  data (rows, channels), weight (channels, kernel), bias
-    (channels,).  Summed in float32.  Its gradient is written out, not
+    (channels,); with ``no_bias`` there is no bias and no such
+    argument.  Summed in float32.  Its gradient is written out, not
     left to jax: data's is the mirrored convolution of the output's,
     weight's and bias's one pass of float32 sums over the rows."""
-    x, w, b = ins
+    import jax.numpy as jnp
+    x, w = ins[:2]
     K, T = int(attrs["kernel"]), int(attrs["seq_len"])
     _sequences("CausalConv1D", x.shape[0], T)
+    if attrs.get("no_bias", False):
+        # the same two passes with a bias of nought, whose gradient
+        # nobody reads
+        b = jnp.zeros((x.shape[-1],), w.dtype)
+    else:
+        b = ins[2]
     return [_conv_and_back(K, T, b.dtype)(x, w, b)]
 
 
@@ -242,6 +268,220 @@ def _ssd(attrs, ins, octx):
     count_op("ssm.carried_bytes", s_in.size * s_in.dtype.itemsize)
     # dear to make again: a segment's backward pass is handed it
     return [keep(y.reshape(x.shape).astype(x.dtype), SCAN)]
+
+
+# ---------------------------------------------------------------------------
+# the chunked gated delta rule
+# ---------------------------------------------------------------------------
+# Below this many rows a block of decayed inner products is computed
+# pair by pair (every exponent taken on its own); above it a block is
+# halved and the half below the diagonal is one matrix product.
+_DIRECT_ROWS = 16
+# added to the sum of squares under the root of q's and k's lengths
+_L2_EPS = 1e-6
+
+
+def _decayed_products(rows, k, G):
+    """``A[n][r, i] = sum_c rows_n[r, c] k[i, c] exp(G[r, c] - G[i, c])``
+    for ``i <= r`` and 0 above the diagonal, for each array of
+    ``rows``.  rows_n, k, G (..., Q, d) float32; G is a running sum of
+    decays that are never positive, so ``G[r] - G[i] <= 0`` wherever it
+    is taken: no exponent here is ever positive, however strong the
+    decay.
+
+    A block of Q rows is cut in two.  Its half below the diagonal
+    (rows of the second half against keys of the first) is factored
+    around the last row of the first half, ``G_m``:
+    ``exp(G_r - G_i) = exp(G_r - G_m) exp(G_m - G_i)`` with both
+    exponents <= 0, so it is ONE matrix product of decayed rows with
+    decayed keys; the two halves on the diagonal are blocks of their
+    own.  At ``_DIRECT_ROWS`` rows or fewer a block is computed pair by
+    pair, masked before the exponential."""
+    import jax
+    import jax.numpy as jnp
+    hi = jax.lax.Precision.HIGHEST      # float32 operands, as stated
+    Q = k.shape[-2]
+    if Q > _DIRECT_ROWS and Q % 2 == 0:
+        m = Q // 2
+        ref = G[..., m - 1:m, :]
+        k_dec = k[..., :m, :] * jnp.exp(ref - G[..., :m, :])
+        r_dec = jnp.exp(G[..., m:, :] - ref)
+        below = [jnp.einsum("...rc,...ic->...ri", r[..., m:, :] * r_dec,
+                            k_dec, precision=hi) for r in rows]
+        first = _decayed_products([r[..., :m, :] for r in rows],
+                                  k[..., :m, :], G[..., :m, :])
+        second = _decayed_products([r[..., m:, :] for r in rows],
+                                   k[..., m:, :], G[..., m:, :])
+        return [jnp.concatenate([
+            jnp.concatenate([a, jnp.zeros_like(a)], axis=-1),
+            jnp.concatenate([b, c], axis=-1)], axis=-2)
+            for a, b, c in zip(first, below, second)]
+    seen = jnp.tril(jnp.ones((Q, Q), bool))[..., None]
+    decay = jnp.exp(jnp.where(seen, G[..., :, None, :] - G[..., None, :, :],
+                              -jnp.inf))
+    return [jnp.sum(r[..., :, None, :] * k[..., None, :, :] * decay, axis=-1)
+            for r in rows]
+
+
+def _delta_carry(w, u, k_end, decay):
+    """The state at each chunk's start and each chunk's corrected
+    values, from the chunks' solved W (S, chunks, H, Q, d), U
+    (S, chunks, H, Q, dv), keys decayed to the chunk's end and the
+    chunk's whole decay (S, chunks, H, d)::
+
+        delta[c] = U[c] - W[c] S[c]
+        S[c + 1] = Diag(decay[c]) S[c] + k_end[c]^T delta[c]     S[0] = 0
+
+    two matrix products a step.  The state is float32; the products
+    take W's type's operands and sum in float32."""
+    import jax
+    import jax.numpy as jnp
+    f32 = jnp.float32
+    cdt = w.dtype
+
+    def step(state, chunk_c):
+        w_c, u_c, k_c, decay_c = chunk_c
+        delta = u_c - jnp.einsum("bhqd,bhde->bhqe", w_c, state.astype(cdt),
+                                 preferred_element_type=f32)
+        new = decay_c[..., None] * state + jnp.einsum(
+            "bhqd,bhqe->bhde", k_c, delta.astype(cdt),
+            preferred_element_type=f32)
+        return new, (state, delta)
+    S, _nc, H, _Q, d = w.shape
+    start = jnp.zeros((S, H, d, u.shape[-1]), f32)
+    _, (s_in, delta) = jax.lax.scan(
+        step, start, tuple(t.swapaxes(0, 1) for t in (w, u, k_end, decay)))
+    return s_in.swapaxes(0, 1), delta.swapaxes(0, 1)
+
+
+def gated_delta_chunked(q, k, v, g, beta, chunk):
+    """The gated delta rule of the module's head, chunk by chunk.
+
+    q, k (S, T, H, d) float32 (normalised, q scaled); v (S, T, H, dv)
+    in the activation type; g (S, T, H, d) float32, the log of the
+    decay, never positive; beta (S, T, H) float32.  T is a whole number
+    of chunks.  Returns (o (S, T, H, dv) float32; the states at the
+    chunks' starts (S, chunks, H, d, dv), float32).
+
+    With ``G_r`` the running sum of g inside a chunk and ``S_0`` the
+    state at its start::
+
+        Akk[r, i] = sum_c k_r[c] k_i[c] exp(G_r[c] - G_i[c])    i < r
+        Aqk[r, i] likewise with q_r                             i <= r
+        (I + tril(diag(beta) Akk, -1)) [W | U] = diag(beta) [K exp(G) | V]
+        O   = (Q exp(G)) S_0 + Aqk (U - W S_0)
+        S_Q = Diag(exp(G_Q)) S_0 + (K exp(G_Q - G))^T (U - W S_0)
+
+    Running sums, decays, Akk, Aqk, the solve and the state are
+    float32; the four products with the state and with ``U - W S_0``
+    take the activation type's operands and sum in float32."""
+    import jax
+    import jax.numpy as jnp
+    f32 = jnp.float32
+    hi = jax.lax.Precision.HIGHEST
+    S, T, H, d = k.shape
+    Q, nc, cdt = chunk, T // chunk, v.dtype
+
+    def chunks(t):      # (S, T, H, w) -> (S, chunks, H, Q, w)
+        return t.reshape(S, nc, Q, H, t.shape[-1]).transpose(0, 1, 3, 2, 4)
+
+    qs, ks, vs = chunks(q), chunks(k), chunks(v).astype(f32)
+    bs = chunks(beta[..., None])                         # (S, nc, H, Q, 1)
+    with jax.named_scope("mx.kda.gate"):
+        # the running sum as a product with a triangle of ones
+        # (`cumsum` over an inner axis is a window reduction on a TPU)
+        G = jnp.einsum("rq,bnhqc->bnhrc", jnp.tril(jnp.ones((Q, Q), f32)),
+                       chunks(g), precision=hi)
+        last = G[..., -1:, :]
+    with jax.named_scope("mx.kda.intra"):
+        akk, aqk = _decayed_products([ks, qs], ks, G)
+    with jax.named_scope("mx.kda.solve"):
+        below = jnp.tril(jnp.ones((Q, Q), bool), -1)
+        system = jnp.where(below, bs * akk, 0.0) + jnp.eye(Q, dtype=f32)
+        wu = jax.lax.linalg.triangular_solve(
+            system, bs * jnp.concatenate([ks * jnp.exp(G), vs], axis=-1),
+            left_side=True, lower=True, unit_diagonal=True)
+        w, u = wu[..., :d].astype(cdt), wu[..., d:]
+    with jax.named_scope("mx.kda.carry"):
+        # the chunk-boundary states: what the backward pass of the
+        # carry and of the products below reads
+        s_in, delta = _delta_carry(
+            w, u, (ks * jnp.exp(last - G)).astype(cdt),
+            jnp.exp(last[..., 0, :]))
+        s_in = keep(s_in, SCAN)
+    with jax.named_scope("mx.kda.inter"):
+        o = jnp.einsum("bnhqd,bnhde->bnhqe", (qs * jnp.exp(G)).astype(cdt),
+                       s_in.astype(cdt), preferred_element_type=f32) \
+            + jnp.einsum("bnhri,bnhie->bnhre", aqk.astype(cdt),
+                         delta.astype(cdt), preferred_element_type=f32)
+    return o.transpose(0, 1, 3, 2, 4).reshape(S, T, H, -1), s_in
+
+
+def _delta_infer(attrs, in_shapes, aux):
+    q = in_shapes[0]
+    if q is None:
+        return in_shapes, None, aux
+    H = int(attrs["heads"])
+    rows, width = q[0], H * int(attrs["head_dim"])
+    in_shapes[:] = [(rows, width)] * 4 + [(rows, H), (H,), (width,)]
+    return in_shapes, [(rows, width)], aux
+
+
+@register("GatedDeltaRule",
+          arg_names=("query", "key", "value", "gate", "beta", "A_log",
+                     "dt_bias"),
+          attr_types={"heads": int, "head_dim": int, "chunk": int,
+                      "seq_len": int},
+          required_attrs=("heads", "head_dim", "seq_len"),
+          infer_shape=_delta_infer,
+          counters=("kda.chunks", "kda.carried_bytes"))
+def _gated_delta_rule(attrs, ins, octx):
+    """The gated delta rule (Kimi Delta Attention) over rows cut into
+    sequences of ``seq_len``.  query, key, value and gate
+    (rows, heads * head_dim); beta (rows, heads); A_log (heads,),
+    dt_bias (heads * head_dim,).  With q and k normalised to length 1
+    over each head (``x / sqrt(sum x^2 + 1e-6)``), q scaled by
+    ``head_dim ** -0.5``, ``g = -exp(A_log) * softplus(gate + dt_bias)``,
+    ``alpha = exp(g)`` and ``beta = sigmoid(beta)``, per head and from a
+    zero state at each sequence's start:
+    ``S_t = (I - beta_t k_t k_t^T) Diag(alpha_t) S_{t-1}
+    + beta_t k_t v_t^T``, ``o_t = S_t^T q_t``.  Computed ``chunk``
+    tokens at a time (default 64; ``seq_len`` a whole number of them)
+    with one unit-triangular solve a chunk and one carry of
+    ``seq_len / chunk`` steps between the chunks; gates, decays, the
+    solve and the state in float32.  Counts ``kda.chunks`` and
+    ``kda.carried_bytes`` (the chunk-boundary states the carry
+    passes)."""
+    import jax
+    import jax.numpy as jnp
+    f32 = jnp.float32
+    q, k, v, gate, beta, A_log, dt_bias = ins
+    H, D = int(attrs["heads"]), int(attrs["head_dim"])
+    T = int(attrs["seq_len"])
+    Q = min(int(attrs.get("chunk", 64)), T)
+    S = _sequences("GatedDeltaRule", q.shape[0], T)
+    if T % Q:
+        raise ValueError("GatedDeltaRule: seq_len %d is no whole number of "
+                         "chunks of %d" % (T, Q))
+
+    def heads(t):
+        return t.reshape(S, T, H, -1)
+
+    with jax.named_scope("mx.kda.norm"):
+        def unit(t):
+            t = heads(t).astype(f32)
+            return t * jax.lax.rsqrt(
+                jnp.sum(jnp.square(t), axis=-1, keepdims=True) + _L2_EPS)
+        qn, kn = unit(q) * D ** -0.5, unit(k)
+    with jax.named_scope("mx.kda.gate"):
+        g = -jnp.exp(A_log.astype(f32))[:, None] * heads(
+            jax.nn.softplus(gate.astype(f32) + dt_bias.astype(f32)))
+        b = jax.nn.sigmoid(beta.astype(f32)).reshape(S, T, H)
+    o, s_in = gated_delta_chunked(qn, kn, heads(v), g, b, Q)
+    count_op("kda.chunks", S * (T // Q))
+    count_op("kda.carried_bytes", s_in.size * s_in.dtype.itemsize)
+    # dear to make again: a segment's backward pass is handed it
+    return [keep(o.reshape(v.shape).astype(v.dtype), SCAN)]
 
 
 # ---------------------------------------------------------------------------

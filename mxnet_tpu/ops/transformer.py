@@ -22,7 +22,7 @@ import functools
 import math
 
 from ..precision.policy import ATTENTION, keep, note_kept
-from ..registry import register
+from ..registry import register, count as count_op
 
 
 def _jnp():
@@ -82,7 +82,7 @@ def _blockwise(q, k, v, causal, window, scale, block):
             window=window, scale=scale))
         outs.append(fn(qg[:, :, :, q_lo:q_hi], k[:, :, k_lo:k_hi],
                        v[:, :, k_lo:k_hi]))
-    return keep(jnp.concatenate(outs, axis=3).reshape(B, H, T, D),
+    return keep(jnp.concatenate(outs, axis=3).reshape(B, H, T, v.shape[-1]),
                 ATTENTION)
 
 
@@ -91,10 +91,13 @@ def _blockwise(q, k, v, causal, window, scale, block):
 _INTERPRET = False
 
 
-def _splash_kernel(T, R, causal, window, block):
+def _splash_kernel(T, R, causal, window, block, block_dkv=None):
     """The library kernel for one key-value head and its R query heads
     (multi-query form).  Built anew in every trace: it holds its mask's
-    block tables as arrays of the trace that made it."""
+    block tables as arrays of the trace that made it.  ``block_dkv``
+    (default ``block``) is the backward kernel's block of keys: it
+    writes the queries' gradient once a block of keys, T / block_dkv
+    partial copies."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as sk, splash_attention_mask as sm)
     if window:
@@ -105,8 +108,8 @@ def _splash_kernel(T, R, causal, window, block):
         mask = sm.FullMask((T, T))
     sizes = sk.BlockSizes(
         block_q=block, block_kv=block, block_kv_compute=block,
-        block_q_dkv=block, block_kv_dkv=block, block_kv_dkv_compute=block,
-        use_fused_bwd_kernel=True)
+        block_q_dkv=block, block_kv_dkv=block_dkv or block,
+        block_kv_dkv_compute=block, use_fused_bwd_kernel=True)
     # the kernel names its output and its log-sum-exp inside its
     # custom_vjp: a remat policy that keeps the name hands them to the
     # backward kernels and the forward one is not run again
@@ -117,26 +120,45 @@ def _splash_kernel(T, R, causal, window, block):
                               interpret=_INTERPRET)
 
 
+def _whole_lanes(t):
+    """t with zero columns up to the next whole number of the kernel's
+    128 lanes: a zero column of a query or of a key adds nothing to a
+    score."""
+    short = -t.shape[-1] % 128
+    if not short:
+        return t
+    return _jnp().pad(t, [(0, 0)] * (t.ndim - 1) + [(0, short)])
+
+
 def _splash(q, k, v, causal, window, scale, block):
     import jax
     B, H, T, D = q.shape
     G = k.shape[1]
-    kernel = _splash_kernel(T, H // G, causal, window, block)
-    qg = (q * scale).astype(q.dtype).reshape(B, G, H // G, T, D)
+    qg = _whole_lanes((q * scale).astype(q.dtype)
+                      .reshape(B, G, H // G, T, D))
+    # queries wider than 128 lanes: as many fewer partial copies of
+    # their gradient, so that they take what a 128-wide head's do
+    block_dkv = block * (qg.shape[-1] // 128)
+    kernel = _splash_kernel(T, H // G, causal, window, block,
+                            block_dkv if T % block_dkv == 0 else block)
     with jax.named_scope("splash_attention"):
-        out = jax.vmap(jax.vmap(kernel))(qg, k, v)
+        out = jax.vmap(jax.vmap(kernel))(qg, _whole_lanes(k), v)
     # what the kernel named: its output and a float32 log-sum-exp a query
     note_kept(ATTENTION, out.size * out.dtype.itemsize + B * H * T * 4)
-    return out.reshape(B, H, T, D).astype(q.dtype)
+    return out.reshape(B, H, T, v.shape[-1]).astype(q.dtype)
 
 
 def attention(q, k, v, causal=False, window=0, scale=None):
-    """Softmax attention of q (B, H, T, D) over k, v (B, G, S, D) with
-    H a multiple of G (H // G query heads share a key-value head).
+    """Softmax attention of q (B, H, T, D) over k (B, G, S, D) and
+    v (B, G, S, Dv) with H a multiple of G (H // G query heads share a
+    key-value head); the values may be of another width than the keys,
+    and the result is (B, H, T, Dv).
 
     ``causal`` drops keys after the query; ``window`` (0: none) also
     drops keys ``window`` or more positions before it.  On a TPU, where
-    the shapes allow, the library kernel; elsewhere the blockwise path.
+    the shapes allow, the library kernel (a key width that is no whole
+    number of its 128 lanes reaches it with zero columns added);
+    elsewhere the blockwise path.
     """
     import jax
     B, H, T, D = q.shape
@@ -146,7 +168,7 @@ def attention(q, k, v, causal=False, window=0, scale=None):
     if window >= max(T, S):
         window = 0
     block = next((b for b in (512, 256, 128) if T % b == 0), None)
-    fits = block is not None and T == S and D % 128 == 0
+    fits = block is not None and T == S and v.shape[-1] % 128 == 0
     if fits and (jax.default_backend() == "tpu" or _INTERPRET):
         return _splash(q, k, v, causal, window, scale, block)
     return _blockwise(q, k, v, causal, window, scale, block or 512)
@@ -216,41 +238,96 @@ def _gqa_infer(attrs, in_shapes, aux):
     q = in_shapes[0]
     if q is None:
         return in_shapes, None, aux
-    return in_shapes, [tuple(q)], aux
+    out = tuple(q)
+    if attrs.get("v_head_dim"):
+        out = (q[0], int(attrs["num_heads"]) * int(attrs["v_head_dim"]))
+    return in_shapes, [out], aux
 
 
 @register("GroupedQueryAttention", arg_names=_gqa_args,
           attr_types={"num_heads": int, "num_kv_heads": int,
-                      "head_dim": int, "seq_len": int, "window": int,
-                      "causal": bool, "gated": bool, "scale": float},
+                      "head_dim": int, "v_head_dim": int, "seq_len": int,
+                      "window": int, "causal": bool, "gated": bool,
+                      "scale": float},
           required_attrs=("num_heads", "num_kv_heads", "head_dim",
                           "seq_len"),
           infer_shape=_gqa_infer)
 def _grouped_query_attention(attrs, ins, octx):
     """Causal attention of rows cut into sequences of ``seq_len``:
-    query (rows, num_heads * head_dim), key and value
-    (rows, num_kv_heads * head_dim).  ``window`` > 0 also drops keys
-    that many or more positions back; with ``gated`` the output is
-    multiplied by sigmoid(gate), gate shaped like query."""
+    query (rows, num_heads * head_dim), key
+    (rows, num_kv_heads * head_dim), value
+    (rows, num_kv_heads * v_head_dim); ``v_head_dim`` defaults to
+    ``head_dim``, and the output is (rows, num_heads * v_head_dim).
+    ``window`` > 0 also drops keys that many or more positions back;
+    with ``gated`` the output is multiplied by sigmoid(gate), gate
+    shaped like the output."""
     import jax
     jnp = _jnp()
     H, G = int(attrs["num_heads"]), int(attrs["num_kv_heads"])
     D, T = int(attrs["head_dim"]), int(attrs["seq_len"])
+    Dv = int(attrs.get("v_head_dim") or D)
     q, k, v = ins[:3]
     rows = q.shape[0]
     if rows % T:
         raise ValueError("GroupedQueryAttention: %d rows are no whole "
                          "number of sequences of %d" % (rows, T))
 
-    def heads(t, n):
-        return t.reshape(rows // T, T, n, D).transpose(0, 2, 1, 3)
+    def heads(t, n, width=D):
+        return t.reshape(rows // T, T, n, width).transpose(0, 2, 1, 3)
 
-    o = attention(heads(q, H), heads(k, G), heads(v, G),
+    o = attention(heads(q, H), heads(k, G), heads(v, G, Dv),
                   causal=bool(attrs.get("causal", True)),
                   window=int(attrs.get("window", 0) or 0),
                   scale=attrs.get("scale"))
-    o = o.transpose(0, 2, 1, 3).reshape(rows, H * D)
+    o = o.transpose(0, 2, 1, 3).reshape(rows, H * Dv)
     if attrs.get("gated", False):
         gate = ins[3].astype(jnp.float32)
         o = (o.astype(jnp.float32) * jax.nn.sigmoid(gate)).astype(q.dtype)
     return [o]
+
+
+def _latent_infer(attrs, in_shapes, aux):
+    data, shared = in_shapes
+    if data is None or shared is None:
+        return in_shapes, None, aux
+    H = int(attrs["num_heads"])
+    kd, vd = int(attrs["key_dim"]), int(attrs["value_dim"])
+    in_shapes[0] = (data[0], H * (kd + vd))
+    return in_shapes, [(data[0], H * (kd + shared[-1])),
+                       (data[0], H * vd)], aux
+
+
+@register("LatentExpand", arg_names=("data", "shared_key"),
+          attr_types={"num_heads": int, "key_dim": int, "value_dim": int},
+          required_attrs=("num_heads", "key_dim", "value_dim"),
+          infer_shape=_latent_infer, num_outputs=2,
+          out_names=("key", "value"), counters=("mla.expanded_kv_bytes",))
+def _latent_expand(attrs, ins, octx):
+    """The per-head keys and values of latent attention from the
+    latent's up-projection and the part of a key all heads share.
+    data (rows, num_heads * (key_dim + value_dim)), a head's
+    ``key_dim`` values of its key followed by its ``value_dim`` of its
+    value; shared_key (rows, R).  Outputs key
+    (rows, num_heads * (key_dim + R)), every head's own part followed
+    by the shared one, and value (rows, num_heads * value_dim): what
+    ``sym.GroupedQueryAttention(head_dim=key_dim + R,
+    v_head_dim=value_dim)`` takes.  Counts ``mla.expanded_kv_bytes``,
+    the bytes of both: what a kernel that reads the latent itself would
+    not write."""
+    import jax
+    jnp = _jnp()
+    data, shared = ins
+    H = int(attrs["num_heads"])
+    kd, vd = int(attrs["key_dim"]), int(attrs["value_dim"])
+    rows = data.shape[0]
+    with jax.named_scope("mx.mla.expand"):
+        per_head = data.reshape(rows, H, kd + vd)
+        key = jnp.concatenate(
+            [per_head[..., :kd],
+             jnp.broadcast_to(shared[:, None, :].astype(data.dtype),
+                              (rows, H, shared.shape[-1]))], axis=-1)
+        value = per_head[..., kd:]
+    key, value = key.reshape(rows, -1), value.reshape(rows, -1)
+    count_op("mla.expanded_kv_bytes",
+             (key.size + value.size) * key.dtype.itemsize)
+    return [key, value]

@@ -17,8 +17,9 @@ Module/Updater/executor stack applies it at the existing seams:
   carry it.
 * ``remat=...`` — a named ``jax.checkpoint`` policy for the segmented
   rematerialization evaluator: ``"none"``, ``"full"`` (a segment's
-  backward pass is handed ``FullyConnected``'s, attention's and the
-  selective scan's outputs and makes the rest again),
+  backward pass is handed ``FullyConnected``'s, attention's, the
+  selective scan's and the gated delta rule's outputs and makes the
+  rest again),
   ``"dots_saveable"`` (keep matmul/conv outputs, and attention's),
   ``"offload_bn_stats"`` (dots_saveable + keep the tagged per-channel
   BatchNorm statistics), or a raw jax policy callable
@@ -352,7 +353,8 @@ def fake_cast(jnp, v, kind):
 PRODUCT = "product"        # FullyConnected's output, in the activation type
 ATTENTION = "attention"    # attention's output (and the kernel's log-sum-exp)
 BN_STATS = "bn_stats"      # BatchNorm's per-channel batch statistics
-SCAN = "scan"              # a selective scan's output and boundary states
+SCAN = "scan"              # a selective scan's or a delta rule's output
+#                            and chunk-boundary states
 
 _KEPT_NAMES = {"full": (PRODUCT, ATTENTION, SCAN),
                "dots": (ATTENTION,),
@@ -407,10 +409,11 @@ def remat_checkpoint_policy(remat):
     """The ``jax.checkpoint`` policy object for a canonical remat spec
     (:func:`canon_remat` output).  Every named spec keeps the values
     named in :func:`remat_kept_names`: ``"full"`` those alone (the
-    matrix products', attention's and the selective scan's outputs;
-    norms, rotations, casts and activations are made again),
-    ``"dots"`` and ``"bn_stats"`` beside what ``dots_saveable`` sees (attention is named because on a
-    TPU it is a Pallas call, no ``dot_general``).  A callable passes
+    matrix products', attention's, the selective scan's and the gated
+    delta rule's outputs; norms, rotations, casts and activations are
+    made again), ``"dots"`` and ``"bn_stats"`` beside what
+    ``dots_saveable`` sees (attention is named because on a TPU it is a
+    Pallas call, no ``dot_general``).  A callable passes
     through: ``jax.checkpoint_policies.nothing_saveable`` is the strict
     "a segment keeps nothing"."""
     import jax

@@ -61,7 +61,7 @@ object NDArrayGenerated {
     NDArray.invoke("Cast", inputs, params, outputs)
 
   /** Depthwise convolution along each sequence of ``seq_len`` rows,
-    * args: data: NDArray-or-Symbol; weight: NDArray-or-Symbol; bias: NDArray-or-Symbol; kernel: int, required; seq_len: int, required
+    * args: data: NDArray-or-Symbol; weight: NDArray-or-Symbol; bias: NDArray-or-Symbol; kernel: int, required; no_bias: boolean, optional; seq_len: int, required
     */
   def CausalConv1D(inputs: Seq[NDArray],
       params: Map[String, String] = Map.empty,
@@ -177,6 +177,15 @@ object NDArrayGenerated {
       : IndexedSeq[NDArray] =
     NDArray.invoke("FullyConnected", inputs, params, outputs)
 
+  /** The gated delta rule (Kimi Delta Attention) over rows cut into
+    * args: query: NDArray-or-Symbol; key: NDArray-or-Symbol; value: NDArray-or-Symbol; gate: NDArray-or-Symbol; beta: NDArray-or-Symbol; A_log: NDArray-or-Symbol; dt_bias: NDArray-or-Symbol; chunk: int, optional; head_dim: int, required; heads: int, required; seq_len: int, required
+    */
+  def GatedDeltaRule(inputs: Seq[NDArray],
+      params: Map[String, String] = Map.empty,
+      outputs: Seq[NDArray] = null)
+      : IndexedSeq[NDArray] =
+    NDArray.invoke("GatedDeltaRule", inputs, params, outputs)
+
   /** RMSNorm of ``data * silu(gate)`` over each of ``groups`` equal
     * args: data: NDArray-or-Symbol; gate: NDArray-or-Symbol; gamma: NDArray-or-Symbol; eps: float, optional; groups: int, optional
     */
@@ -196,7 +205,7 @@ object NDArrayGenerated {
     NDArray.invoke("GridGenerator", inputs, params, outputs)
 
   /** Causal attention of rows cut into sequences of ``seq_len``:
-    * args: query: NDArray-or-Symbol; key: NDArray-or-Symbol; value: NDArray-or-Symbol; causal: boolean, optional; gated: boolean, optional; head_dim: int, required; num_heads: int, required; num_kv_heads: int, required; scale: float, optional; seq_len: int, required; window: int, optional
+    * args: query: NDArray-or-Symbol; key: NDArray-or-Symbol; value: NDArray-or-Symbol; causal: boolean, optional; gated: boolean, optional; head_dim: int, required; num_heads: int, required; num_kv_heads: int, required; scale: float, optional; seq_len: int, required; v_head_dim: int, optional; window: int, optional
     */
   def GroupedQueryAttention(inputs: Seq[NDArray],
       params: Map[String, String] = Map.empty,
@@ -239,6 +248,15 @@ object NDArrayGenerated {
       outputs: Seq[NDArray] = null)
       : IndexedSeq[NDArray] =
     NDArray.invoke("LRN", inputs, params, outputs)
+
+  /** The per-head keys and values of latent attention from the
+    * args: data: NDArray-or-Symbol; shared_key: NDArray-or-Symbol; key_dim: int, required; num_heads: int, required; value_dim: int, required
+    */
+  def LatentExpand(inputs: Seq[NDArray],
+      params: Map[String, String] = Map.empty,
+      outputs: Seq[NDArray] = null)
+      : IndexedSeq[NDArray] =
+    NDArray.invoke("LatentExpand", inputs, params, outputs)
 
   /** leaky/prelu/elu/rrelu (src/operator/leaky_relu-inl.h).
     * args: data: NDArray-or-Symbol; act_type: string, optional; lower_bound: float, optional; slope: float, optional; upper_bound: float, optional

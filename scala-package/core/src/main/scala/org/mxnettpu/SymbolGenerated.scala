@@ -120,6 +120,12 @@ object SymbolGenerated {
       name: String = null): Symbol =
     Symbol.create("FullyConnected", args, params, name)
 
+  /** The gated delta rule (Kimi Delta Attention) over rows cut into */
+  def GatedDeltaRule(args: Map[String, Symbol],
+      params: Map[String, String] = Map.empty,
+      name: String = null): Symbol =
+    Symbol.create("GatedDeltaRule", args, params, name)
+
   /** RMSNorm of ``data * silu(gate)`` over each of ``groups`` equal */
   def GatedRMSNorm(args: Map[String, Symbol],
       params: Map[String, String] = Map.empty,
@@ -161,6 +167,12 @@ object SymbolGenerated {
       params: Map[String, String] = Map.empty,
       name: String = null): Symbol =
     Symbol.create("LRN", args, params, name)
+
+  /** The per-head keys and values of latent attention from the */
+  def LatentExpand(args: Map[String, Symbol],
+      params: Map[String, String] = Map.empty,
+      name: String = null): Symbol =
+    Symbol.create("LatentExpand", args, params, name)
 
   /** leaky/prelu/elu/rrelu (src/operator/leaky_relu-inl.h). */
   def LeakyReLU(args: Map[String, Symbol],

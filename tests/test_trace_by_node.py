@@ -93,6 +93,48 @@ def test_mamba_nodes_and_the_pass_from_the_path(op_name, group, which):
     assert trace_by_node.pass_of(op_name) == which
 
 
+@pytest.mark.parametrize("op_name,group,which", [
+    # a delta-rule and a latent-attention layer's nodes as the remat
+    # step of models/kimi_linear.py spells them
+    (STEP + "jvp(l1_kda)/mx.kda.intra/...rc,...ic->...ri/dot_general",
+     "kda.intra", "forward"),
+    (BACK + "l2_kda/mx.kda.solve/triangular_solve", "kda.solve", "backward"),
+    (BACK + "rematted_computation/l3_kda/mx.kda.carry/while/body/add",
+     "kda.carry", "made again"),
+    (STEP + "jvp(l4_kda)/mx.kda.gate/jit(softplus)/log1p", "kda.gate",
+     "forward"),
+    (STEP + "jvp(l4_kda)/mx.kda.inter/bnhri,bnhie->bnhre/dot_general",
+     "kda.inter", "forward"),
+    (BACK + "l1_kda/mx.kda.norm/rsqrt", "kda.norm", "backward"),
+    (BACK + "l1_kda/convert_element_type", "kda", "backward"),
+    (STEP + "jvp(l2_kda_q)/dot_general", "kda_proj", "forward"),
+    (BACK + "l2_kda_f_down/dot_general", "kda_proj", "backward"),
+    (BACK + "l2_kda_g_up/dot_general", "kda_proj", "backward"),
+    (BACK + "l2_kda_beta/dot_general", "kda_proj", "backward"),
+    (STEP + "jvp(l3_kda_o)/dot_general", "kda_proj", "forward"),
+    (BACK + "l3_kda_k_conv/mx.ssm.conv/reduce_sum", "conv", "backward"),
+    (BACK + "rematted_computation/l3_kda_v_conv_silu/logistic", "conv",
+     "made again"),
+    (BACK + "l4_kda_norm/rsqrt", "ssm_norm", "backward"),
+    (STEP + "jvp(l4_kda_norm_gate)/logistic", "ssm_norm", "forward"),
+    (STEP + "jvp(l5_mla)/splash_attention/pallas_call", "mla", "forward"),
+    (STEP + "jvp(l5_mla_kv)/mx.mla.expand/concatenate", "mla.expand",
+     "forward"),
+    (BACK + "l5_mla_kv_up/dot_general", "mla_proj", "backward"),
+    (STEP + "jvp(l5_mla_q)/dot_general", "mla_proj", "forward"),
+    (BACK + "l5_mla_o/dot_general", "mla_proj", "backward"),
+    # the latent's norm and its slices are no group, as other norms
+    (STEP + "jvp(l5_mla_kv_norm)/rsqrt", trace_by_node.REST, "forward"),
+    (STEP + "jvp(l5_mla_shared_key)/slice", trace_by_node.REST, "forward"),
+    # and the older nodes read as they did
+    (BACK + "l7_q/dot_general", "attn_proj", "backward"),
+    (BACK + "l0_ssd/mx.ssm.carry/while/body/mul", "scan.carry", "backward"),
+])
+def test_delta_rule_and_latent_attention_nodes(op_name, group, which):
+    assert trace_by_node.group_of(op_name) == group
+    assert trace_by_node.pass_of(op_name) == which
+
+
 def test_the_report_has_a_row_a_group_and_a_column_a_pass():
     got = {"by_group": {("scan.intra", "forward"): 0.01,
                         ("scan", "backward"): 0.02,

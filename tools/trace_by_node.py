@@ -43,15 +43,27 @@ GROUPS = (
     ("shared_ffn", re.compile(r"^l\d+_(shared|mlp)_\w+$")),
     ("lm_head", re.compile(r"^(head|softmax)$")),
     # a Mamba-2 layer (models/nemotron_h.py): the convolution with its
-    # silu, the scan, the two projections, the gated norm
-    ("conv", re.compile(r"^l\d+_conv(_silu)?$")),
+    # silu, the scan, the two projections, the gated norm; a delta-rule
+    # layer's (models/kimi_linear.py) three convolutions and its norm
+    # per head with that norm's sigmoid gate beside them
+    ("conv", re.compile(r"^l\d+_(kda_[qkv]_)?conv(_silu)?$")),
     ("scan", re.compile(r"^l\d+_ssd$")),
     ("mamba_proj", re.compile(r"^l\d+_(in|out)$")),
-    ("ssm_norm", re.compile(r"^l\d+_ssm_norm$")),
+    ("ssm_norm", re.compile(r"^l\d+_(ssm_norm|kda_norm(_gate)?)$")),
+    # a delta-rule layer: the recurrence and its nine projections; a
+    # latent-attention layer: the kernels with the latent's expansion,
+    # and its four projections
+    ("kda", re.compile(r"^l\d+_kda$")),
+    ("kda_proj", re.compile(r"^l\d+_kda_(q|k|v|o|beta|[fg]_(down|up))$")),
+    ("mla", re.compile(r"^l\d+_mla(_kv)?$")),
+    ("mla_proj", re.compile(r"^l\d+_mla_(q|kv_down|kv_up|o)$")),
 )
 REST = "rest"
-SPLIT_BY_SCOPE = "scan"     # further by ops/ssm.py's `mx.ssm.<scope>`
-_SCOPE = re.compile(r"^mx\.ssm\.(\w+)$")
+# groups split further by the scope inside the node: ops/ssm.py's
+# `mx.ssm.<scope>` and `mx.kda.<scope>`, ops/transformer.py's
+# `mx.mla.<scope>`
+SPLIT_BY_SCOPE = ("scan", "kda", "mla")
+_SCOPE = re.compile(r"^mx\.(?:ssm|kda|mla)\.(\w+)$")
 PASSES = ("forward", "made again", "backward")
 METADATA_PLANE = "/host:metadata"
 HLO_STAT = "hlo proto"
@@ -73,7 +85,7 @@ def group_of(op_name):
                 scope = "." + m.group(1)
             for group, pattern in GROUPS:
                 if pattern.match(word):
-                    return group + scope if group == SPLIT_BY_SCOPE \
+                    return group + scope if group in SPLIT_BY_SCOPE \
                         else group
     return REST
 
