@@ -29,9 +29,15 @@ rank one each step::
 
 multiplies its state by a MATRIX each step, so its chunked form
 (:func:`gated_delta_chunked`) has a unit-triangular solve inside each
-chunk and a carry of two matrix products a chunk.
+chunk and a carry of two matrix products a chunk.  The decayed inner
+products of a chunk's rows and keys, and their gradient, are on a TPU
+two Pallas kernels of this module (``_intra_forward_kernel``,
+``_intra_backward_kernel``) and elsewhere ``_decayed_products``: one
+arithmetic, two carriers.
 """
 from __future__ import annotations
+
+import functools
 
 from ..precision.policy import SCAN, keep
 from ..registry import register, count as count_op
@@ -323,6 +329,404 @@ def _decayed_products(rows, k, G):
             for r in rows]
 
 
+# True runs the TPU kernels off the TPU, under the Pallas interpreter:
+# the tests patch it, nothing else sets it
+_INTERPRET = False
+# a register's lanes; a grid step of the kernels takes that many blocks
+# of ``_DIRECT_ROWS`` tokens, one to a lane
+_LANES = 128
+# rows between the j-th and the (j + 1)-th tokens' planes of a scratch
+# that is read back a block at a time: 128 and a register more, so that
+# the strided read splits in two and not in eight
+_PLANE = 136
+# chunks a turn of the kernels' loop over chunks takes: the products of
+# one are too short to fill the matrix unit's pipeline
+_CHUNKS = 4
+
+
+def _transposed(x):
+    """x (128, w) as (w, 128) by way of a square of 128: a width short
+    of it is made up with zeros first."""
+    import jax.numpy as jnp
+    w = x.shape[1]
+    if w < _LANES:
+        x = jnp.concatenate(
+            [x, jnp.zeros((_LANES, _LANES - w), x.dtype)], axis=1)
+    return x.T[:w]
+
+
+def _untransposed(xt):
+    """The way back: xt (w, 128) as (128, w)."""
+    import jax.numpy as jnp
+    w = xt.shape[0]
+    if w < _LANES:
+        xt = jnp.concatenate(
+            [xt, jnp.zeros((_LANES - w, _LANES), xt.dtype)], axis=0)
+    return xt.T[:, :w]
+
+
+def _second_halves(x, size):
+    """The rows of x (Q, w) that lie in the second half of their block
+    of ``size``, in order: (Q / 2, w)."""
+    import jax.numpy as jnp
+    return jnp.concatenate([x[s + size // 2:s + size]
+                            for s in range(0, x.shape[0], size)], axis=0)
+
+
+def _to_second_halves(x, size):
+    """The way back: rows (Q / 2, w) to their places in (Q, w), zeros
+    in the first halves."""
+    import jax.numpy as jnp
+    m = size // 2
+    nought = jnp.zeros((m, x.shape[1]), x.dtype)
+    return jnp.concatenate(
+        [t for s in range(0, x.shape[0], m) for t in (nought, x[s:s + m])],
+        axis=0)
+
+
+def _halves(g, size):
+    """For the blocks of ``size`` rows of g (Q, d), each cut in two:
+    the decay of every row of a first half to its block's cut, and from
+    the cut to every row of a second half, (Q, d) each and 0 in the
+    other half.  The cut is the first half's last row, so no exponent
+    is positive."""
+    import jax
+    import jax.numpy as jnp
+    m = size // 2
+    cut = jnp.concatenate([
+        jnp.broadcast_to(g[s + m - 1:s + m], (size, g.shape[1]))
+        for s in range(0, g.shape[0], size)], axis=0)
+    second = jax.lax.broadcasted_iota(jnp.int32, g.shape, 0) % size >= m
+    e = jnp.exp(jnp.where(second, g - cut, cut - g))
+    return jnp.where(second, 0.0, e), jnp.where(second, e, 0.0)
+
+
+def _level(k, q, g, size):
+    """A chunk's operands at one level of halving: the two decays of
+    ``_halves``, the decayed keys (Q, d), and the second halves'
+    decayed rows, k's over q's (Q, d)."""
+    import jax.numpy as jnp
+    first, second = _halves(g, size)
+    rows = jnp.concatenate([_second_halves(k * second, size),
+                            _second_halves(q * second, size)], axis=0)
+    return first, second, k * first, rows
+
+
+def _same_block(Q, size):
+    """(Q, Q) mask over the rows ``_second_halves`` picks of k's and of
+    q's (Q / 2 each) against a chunk's Q keys: row and key in the same
+    block of ``size``."""
+    import jax
+    import jax.numpy as jnp
+    r = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0) % (Q // 2)
+    i = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
+    return r // (size // 2) == i // size
+
+
+def _own_columns(p, Q):
+    """A block's pairs p (_DIRECT_ROWS keys, 128 blocks) at their place
+    among the block's chunk's Q columns, 0 elsewhere: (Q, 128)."""
+    import jax
+    import jax.numpy as jnp
+    B = _DIRECT_ROWS
+    in_chunk = jax.lax.broadcasted_iota(jnp.int32, p.shape, 1) % (Q // B)
+    return jnp.concatenate([jnp.where(in_chunk == b, p, 0.0)
+                            for b in range(Q // B)], axis=0)
+
+
+def _from_own_columns(wide, Q):
+    """The way back: (Q, 128) to (_DIRECT_ROWS, 128)."""
+    import jax
+    import jax.numpy as jnp
+    B = _DIRECT_ROWS
+    in_chunk = jax.lax.broadcasted_iota(jnp.int32, (B, _LANES), 1) % (Q // B)
+    own = wide[:B]
+    for b in range(1, Q // B):
+        own = jnp.where(in_chunk == b, wide[b * B:(b + 1) * B], own)
+    return own
+
+
+def _to_lanes(refs, planes):
+    """Each of ``refs`` (2048, d) into its scratch of ``planes``
+    (_DIRECT_ROWS, d, 128): the j-th tokens of all 128 blocks, channels
+    down and a block to a lane."""
+    from jax.experimental import pallas as pl
+    for j in range(_DIRECT_ROWS):
+        for ref, plane in zip(refs, planes):
+            plane[j] = _transposed(
+                ref[pl.ds(j, _LANES, stride=_DIRECT_ROWS), :])
+
+
+def _blocks_rows(ref, n, Q):
+    """Rows of chunk n, (Q, w), from a scratch that holds the j-th rows
+    of all 128 blocks at ``ref[_PLANE * j:_PLANE * j + 128]``."""
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    B = _DIRECT_ROWS
+    return jnp.concatenate([
+        ref[pl.ds(n * (Q // B) + b, B, stride=_PLANE), :]
+        for b in range(Q // B)], axis=0)
+
+
+def _intra_forward_kernel(Q, k_ref, q_ref, g_ref, akk_ref, aqk_ref,
+                          kt_ref, qt_ref, gt_ref, pkk_ref, pqk_ref,
+                          okk_ref, oqk_ref):
+    """``_decayed_products([k, q], k, G)`` for 128 blocks of
+    ``_DIRECT_ROWS`` tokens (whole chunks of Q): the refs hold
+    (2048, d) and (2048, Q); the last seven are scratch.  The same
+    arithmetic as the function above, carried otherwise.
+
+    The blocks on the diagonal, pair by pair: the j-th tokens of all
+    128 blocks lie a block to a lane with the channels down
+    (``kt_ref[j]`` (d, 128)), so a row j and a key i <= j of every
+    block meet register by register with no shift, no exponent is
+    taken above the diagonal, and the sum over the channels is a sum
+    of registers.
+
+    The halves below the diagonal, a chunk and a level of halving at a
+    time: one product of the second halves' decayed rows (k's over
+    q's) with the chunk's decayed keys, masked to the blocks."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    f32 = jnp.float32
+    hi = jax.lax.Precision.HIGHEST
+    B = _DIRECT_ROWS
+    d = k_ref.shape[1]
+
+    _to_lanes((k_ref, q_ref, g_ref), (kt_ref, qt_ref, gt_ref))
+    pkk_ref[...] = jnp.zeros(pkk_ref.shape, f32)
+    pqk_ref[...] = jnp.zeros(pqk_ref.shape, f32)
+
+    def pair(n, carry):
+        # ONE loop over rows and keys, the pairs above the diagonal
+        # passed over: sixteen loops of their own cost the trace sixteen
+        # bodies
+        j, i = n // B, n % B
+
+        @pl.when(i <= j)        # so the exponent is never positive
+        def _():
+            skk = sqk = jnp.zeros((8, _LANES), f32)
+            for c in range(0, d, 8):        # a register of 8 channels
+                ke = kt_ref[i, c:c + 8] * jnp.exp(
+                    gt_ref[j, c:c + 8] - gt_ref[i, c:c + 8])
+                skk = skk + kt_ref[j, c:c + 8] * ke
+                sqk = sqk + qt_ref[j, c:c + 8] * ke
+            pkk_ref[j, pl.ds(i, 1), :] = jnp.sum(skk, axis=0, keepdims=True)
+            pqk_ref[j, pl.ds(i, 1), :] = jnp.sum(sqk, axis=0, keepdims=True)
+        return carry
+    jax.lax.fori_loop(0, B * B, pair, 0)
+    for j in range(B):
+        plane = pl.ds(_PLANE * j, _LANES)
+        okk_ref[plane, :] = _untransposed(_own_columns(pkk_ref[j], Q))
+        oqk_ref[plane, :] = _untransposed(_own_columns(pqk_ref[j], Q))
+
+    def chunks(turn, carry):
+        for n in [turn * _CHUNKS + i for i in range(_CHUNKS)]:
+            at = pl.ds(pl.multiple_of(n * Q, Q), Q)
+            k, q, g = k_ref[at, :], q_ref[at, :], g_ref[at, :]
+            akk, aqk = _blocks_rows(okk_ref, n, Q), _blocks_rows(oqk_ref, n, Q)
+            size = Q
+            while size > B:
+                _, _, keys, rows = _level(k, q, g, size)
+                p = jax.lax.dot_general(
+                    rows, keys, (((1,), (1,)), ((), ())), precision=hi,
+                    preferred_element_type=f32)
+                if size < Q:
+                    p = jnp.where(_same_block(Q, size), p, 0.0)
+                akk = akk + _to_second_halves(p[:Q // 2], size)
+                aqk = aqk + _to_second_halves(p[Q // 2:], size)
+                size //= 2
+            akk_ref[at, :] = akk
+            aqk_ref[at, :] = aqk
+        return carry
+    jax.lax.fori_loop(0, B * _LANES // Q // _CHUNKS, chunks, 0)
+
+
+def _intra_backward_kernel(Q, k_ref, q_ref, g_ref, dakk_ref, daqk_ref,
+                           dk_ref, dq_ref, dg_ref, kt_ref, qt_ref, gt_ref,
+                           ckk_ref, cqk_ref, rk_ref, rq_ref, key_ref,
+                           ork_ref, orq_ref, okey_ref):
+    """The backward pass of the kernel above from its inputs and the two
+    cotangents, the decays made again (the last eleven refs are
+    scratch).  For a pair
+    ``A[r, i] = sum_c R[r, c] K[i, c] E[r, i, c]``::
+
+        dR[r, c] = sum_i dA[r, i] K[i, c] E[r, i, c]
+        dK[i, c] = sum_r dA[r, i] R[r, c] E[r, i, c]
+        dG = R * dR - K * dK
+
+    summed over the two pairs (k's rows and q's rows against k's keys);
+    the factored halves the same through their one product, whose
+    operands are the decayed rows and keys."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    f32 = jnp.float32
+    hi = jax.lax.Precision.HIGHEST
+    B = _DIRECT_ROWS
+    d = k_ref.shape[1]
+
+    _to_lanes((k_ref, q_ref, g_ref), (kt_ref, qt_ref, gt_ref))
+    for j in range(B):
+        every = pl.ds(j, _LANES, stride=B)      # the j-th of each block
+        ckk_ref[j] = _from_own_columns(_transposed(dakk_ref[every, :]), Q)
+        cqk_ref[j] = _from_own_columns(_transposed(daqk_ref[every, :]), Q)
+
+    def channels(n, carry):
+        # a register of 8 channels at a time, every pair inside it: the
+        # keys' sums stay in registers
+        c = pl.ds(pl.multiple_of(n * 8, 8), 8)
+        key = [jnp.zeros((8, _LANES), f32)] * B
+        for j in range(B):
+            k_j, q_j, g_j = kt_ref[j, c], qt_ref[j, c], gt_ref[j, c]
+            rk = rq = jnp.zeros((8, _LANES), f32)
+            for i in range(j + 1):
+                ckk = ckk_ref[j, i:i + 1, :]
+                cqk = cqk_ref[j, i:i + 1, :]
+                # i <= j: the exponent is never positive
+                e = jnp.exp(g_j - gt_ref[i, c])
+                ke = kt_ref[i, c] * e
+                rk, rq = rk + ckk * ke, rq + cqk * ke
+                key[i] = key[i] + (ckk * k_j + cqk * q_j) * e
+            rk_ref[j, c], rq_ref[j, c] = rk, rq
+        for i in range(B):
+            key_ref[i, c] = key[i]
+        return carry
+    jax.lax.fori_loop(0, d // 8, channels, 0)
+    for j in range(B):
+        plane = pl.ds(_PLANE * j, _LANES)
+        ork_ref[plane, :] = _untransposed(rk_ref[j])
+        orq_ref[plane, :] = _untransposed(rq_ref[j])
+        okey_ref[plane, :] = _untransposed(key_ref[j])
+
+    def chunks(turn, carry):
+        for n in [turn * _CHUNKS + i for i in range(_CHUNKS)]:
+            at = pl.ds(pl.multiple_of(n * Q, Q), Q)
+            k, q, g = k_ref[at, :], q_ref[at, :], g_ref[at, :]
+            dakk, daqk = dakk_ref[at, :], daqk_ref[at, :]
+            # k's gradient as rows, q's, and k's as keys
+            drk, drq, dkey = (_blocks_rows(ork_ref, n, Q),
+                              _blocks_rows(orq_ref, n, Q),
+                              _blocks_rows(okey_ref, n, Q))
+            size = Q
+            while size > B:
+                first, second, keys, rows = _level(k, q, g, size)
+                dp = jnp.concatenate([_second_halves(dakk, size),
+                                      _second_halves(daqk, size)], axis=0)
+                if size < Q:
+                    dp = jnp.where(_same_block(Q, size), dp, 0.0)
+                d_rows = jax.lax.dot_general(
+                    dp, keys, (((1,), (0,)), ((), ())), precision=hi,
+                    preferred_element_type=f32)
+                d_keys = jax.lax.dot_general(
+                    dp, rows, (((0,), (0,)), ((), ())), precision=hi,
+                    preferred_element_type=f32)
+                drk = drk + second * _to_second_halves(d_rows[:Q // 2], size)
+                drq = drq + second * _to_second_halves(d_rows[Q // 2:], size)
+                dkey = dkey + first * d_keys
+                size //= 2
+            dk_ref[at, :] = drk + dkey
+            dq_ref[at, :] = drq
+            dg_ref[at, :] = k * drk + q * drq - k * dkey
+        return carry
+    jax.lax.fori_loop(0, B * _LANES // Q // _CHUNKS, chunks, 0)
+
+
+def _intra_call(kernel, Q, ins, widths, scratch, interpret):
+    """``kernel`` over arrays (tokens, width) cut into grid steps of 128
+    blocks of ``_DIRECT_ROWS`` tokens; outputs (tokens, w) float32 for w
+    in ``widths``, scratch float32 of the shapes in ``scratch``.
+    Tokens short of a whole step are made up with zeros, which give
+    zeros."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    step = _DIRECT_ROWS * _LANES
+    tokens = ins[0].shape[0]
+    short = -tokens % step
+    if short:
+        ins = [jnp.pad(t, ((0, short), (0, 0))) for t in ins]
+
+    def block(width):
+        return pl.BlockSpec((step, width), lambda i: (i, 0))
+    outs = pl.pallas_call(
+        functools.partial(kernel, Q),
+        out_shape=[jax.ShapeDtypeStruct((tokens + short, w), jnp.float32)
+                   for w in widths],
+        grid=((tokens + short) // step,),
+        in_specs=[block(t.shape[1]) for t in ins],
+        out_specs=[block(w) for w in widths],
+        scratch_shapes=[pltpu.VMEM(shape, jnp.float32) for shape in scratch],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=64 * 1024 * 1024),
+        interpret=interpret)(*ins)
+    return [o[:tokens] for o in outs] if short else outs
+
+
+def _kernel_fits(k, q, G):
+    """Whether Akk and Aqk of these chunks go through the kernels: on a
+    TPU (or under the interpreter, for the tests), float32 operands, a
+    head of a register's 128 lanes (the kernels read the j-th rows of
+    128 blocks in one strided access, which the compiler takes of
+    arrays 128 wide only) and a chunk of 16, 32, 64 or 128 rows (a
+    block of ``_DIRECT_ROWS`` halved back from it, and a whole number
+    of chunks to 128 blocks)."""
+    import jax
+    import jax.numpy as jnp
+    Q, d = k.shape[-2:]
+    return ((jax.default_backend() == "tpu" or _INTERPRET)
+            and all(t.dtype == jnp.float32 for t in (k, q, G))
+            and d == _LANES and Q in (_DIRECT_ROWS << n for n in range(4)))
+
+
+@functools.lru_cache(maxsize=None)
+def _intra_kernels(Q, d, interpret=False):
+    """(k, q, G) (..., Q, d) -> (Akk, Aqk) (..., Q, Q) by the two
+    kernels, under the scope the function above runs under (a
+    ``custom_vjp``'s backward function loses the scope unless it enters
+    it again).  One object a shape for the whole process, and its two
+    calls under ``jax.jit(inline=True)``: a kernel's ten thousand
+    instructions are traced once, not once a layer and pass (which cost
+    a step of four layers a minute and a half of set-up on every run),
+    while each call still lowers where it stands, under its own node's
+    and pass's name."""
+    import jax
+    B = _DIRECT_ROWS
+    lanes, pairs = [(B, d, _LANES)] * 3, [(B, B, _LANES)] * 2
+
+    @functools.partial(jax.jit, inline=True)
+    def made(k, q, G):
+        flat = [t.reshape(-1, d) for t in (k, q, G)]
+        return tuple(a.reshape(k.shape[:-1] + (Q,)) for a in _intra_call(
+            _intra_forward_kernel, Q, flat, (Q, Q),
+            lanes + pairs + [(B * _PLANE, Q)] * 2, interpret))
+
+    @functools.partial(jax.jit, inline=True)
+    def given(k, q, G, dakk, daqk):
+        flat = [t.reshape(-1, d) for t in (k, q, G)] \
+            + [t.reshape(-1, Q) for t in (dakk, daqk)]
+        return tuple(t.reshape(k.shape) for t in _intra_call(
+            _intra_backward_kernel, Q, flat, (d, d, d),
+            lanes + pairs + lanes + [(B * _PLANE, d)] * 3, interpret))
+
+    @jax.custom_vjp
+    def products(k, q, G):
+        with jax.named_scope("mx.kda.intra"):
+            return made(k, q, G)
+
+    def forward(k, q, G):
+        return products(k, q, G), (k, q, G)
+
+    def backward(res, cts):
+        with jax.named_scope("mx.kda.intra"):
+            return given(*res, *cts)
+
+    products.defvjp(forward, backward)
+    return products
+
+
 def _delta_carry(w, u, k_end, decay):
     """The state at each chunk's start and each chunk's corrected
     values, from the chunks' solved W (S, chunks, H, Q, d), U
@@ -361,7 +765,8 @@ def gated_delta_chunked(q, k, v, g, beta, chunk):
     in the activation type; g (S, T, H, d) float32, the log of the
     decay, never positive; beta (S, T, H) float32.  T is a whole number
     of chunks.  Returns (o (S, T, H, dv) float32; the states at the
-    chunks' starts (S, chunks, H, d, dv), float32).
+    chunks' starts (S, chunks, H, d, dv), float32; the chunks whose Akk
+    and Aqk the kernels made: all where ``_kernel_fits``, else 0).
 
     With ``G_r`` the running sum of g inside a chunk and ``S_0`` the
     state at its start::
@@ -393,8 +798,12 @@ def gated_delta_chunked(q, k, v, g, beta, chunk):
         G = jnp.einsum("rq,bnhqc->bnhrc", jnp.tril(jnp.ones((Q, Q), f32)),
                        chunks(g), precision=hi)
         last = G[..., -1:, :]
-    with jax.named_scope("mx.kda.intra"):
-        akk, aqk = _decayed_products([ks, qs], ks, G)
+    kernels = _kernel_fits(ks, qs, G)
+    if kernels:
+        akk, aqk = _intra_kernels(Q, d, _INTERPRET)(ks, qs, G)
+    else:
+        with jax.named_scope("mx.kda.intra"):
+            akk, aqk = _decayed_products([ks, qs], ks, G)
     with jax.named_scope("mx.kda.solve"):
         below = jnp.tril(jnp.ones((Q, Q), bool), -1)
         system = jnp.where(below, bs * akk, 0.0) + jnp.eye(Q, dtype=f32)
@@ -414,7 +823,8 @@ def gated_delta_chunked(q, k, v, g, beta, chunk):
                        s_in.astype(cdt), preferred_element_type=f32) \
             + jnp.einsum("bnhri,bnhie->bnhre", aqk.astype(cdt),
                          delta.astype(cdt), preferred_element_type=f32)
-    return o.transpose(0, 1, 3, 2, 4).reshape(S, T, H, -1), s_in
+    return (o.transpose(0, 1, 3, 2, 4).reshape(S, T, H, -1), s_in,
+            S * nc if kernels else 0)
 
 
 def _delta_infer(attrs, in_shapes, aux):
@@ -434,7 +844,7 @@ def _delta_infer(attrs, in_shapes, aux):
                       "seq_len": int},
           required_attrs=("heads", "head_dim", "seq_len"),
           infer_shape=_delta_infer,
-          counters=("kda.chunks", "kda.carried_bytes"))
+          counters=("kda.chunks", "kda.carried_bytes", "kda.kernel_chunks"))
 def _gated_delta_rule(attrs, ins, octx):
     """The gated delta rule (Kimi Delta Attention) over rows cut into
     sequences of ``seq_len``.  query, key, value and gate
@@ -449,9 +859,10 @@ def _gated_delta_rule(attrs, ins, octx):
     tokens at a time (default 64; ``seq_len`` a whole number of them)
     with one unit-triangular solve a chunk and one carry of
     ``seq_len / chunk`` steps between the chunks; gates, decays, the
-    solve and the state in float32.  Counts ``kda.chunks`` and
-    ``kda.carried_bytes`` (the chunk-boundary states the carry
-    passes)."""
+    solve and the state in float32.  Counts ``kda.chunks``,
+    ``kda.carried_bytes`` (the chunk-boundary states the carry passes)
+    and ``kda.kernel_chunks`` (the chunks whose decayed inner products
+    the kernels made: all of them or none)."""
     import jax
     import jax.numpy as jnp
     f32 = jnp.float32
@@ -477,8 +888,9 @@ def _gated_delta_rule(attrs, ins, octx):
         g = -jnp.exp(A_log.astype(f32))[:, None] * heads(
             jax.nn.softplus(gate.astype(f32) + dt_bias.astype(f32)))
         b = jax.nn.sigmoid(beta.astype(f32)).reshape(S, T, H)
-    o, s_in = gated_delta_chunked(qn, kn, heads(v), g, b, Q)
+    o, s_in, by_kernel = gated_delta_chunked(qn, kn, heads(v), g, b, Q)
     count_op("kda.chunks", S * (T // Q))
+    count_op("kda.kernel_chunks", by_kernel)
     count_op("kda.carried_bytes", s_in.size * s_in.dtype.itemsize)
     # dear to make again: a segment's backward pass is handed it
     return [keep(o.reshape(v.shape).astype(v.dtype), SCAN)]

@@ -1,0 +1,70 @@
+"""The repo's own TPU kernels compiled for a described v5e, without the
+chip: what the Pallas interpreter cannot refuse (a slice off the
+tiling, a strided read Mosaic does not take, too much fast memory) the
+chip's compiler refuses here, in seconds.  Nothing runs, so nothing
+here says a word about results or times.
+
+The topology is described inside a fixture, never while a module is
+imported (one process at a time may load the TPU's library; see the
+`on-chip-measurement` guide), and every such test lives in this one
+file."""
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from mxnet_tpu.ops import ssm
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:1x1",
+            chip_config_name="default", chips_per_host_bounds=(1, 1, 1),
+            num_slices=1)
+    except Exception as e:
+        pytest.skip("no v5e topology can be described here: %s" % e)
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def no_compile_cache():
+    """A compile for a described chip is written to jax's persistent
+    cache and cannot be read back without one."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("chunk", [64, 16, 128])
+def test_the_delta_rules_kernels_compile_at_the_cells_width(
+        one_chip, no_compile_cache, chunk):
+    """Akk and Aqk and their gradient for one layer of the Kimi cell
+    (4,096 chunks of 64 tokens, a head of 128), and the smallest and
+    the largest chunk the kernels take."""
+    cells, dim = 4096 * 64 // chunk, 128
+
+    def shape(*dims):
+        return jax.ShapeDtypeStruct(dims, jnp.float32, sharding=one_chip)
+    operands = [shape(cells, chunk, dim)] * 3
+    products = ssm._intra_kernels(chunk, dim)
+    text = jax.jit(products).lower(*operands).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+
+    def gradient(k, q, G, dakk, daqk):
+        return jax.vjp(products, k, q, G)[1]((dakk, daqk))
+    text = jax.jit(gradient).lower(
+        *operands, *[shape(cells, chunk, chunk)] * 2).compile().as_text()
+    # the backward kernel alone: nothing it is handed comes from the
+    # forward one
+    assert text.count("tpu_custom_call") == 1

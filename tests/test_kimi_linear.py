@@ -15,6 +15,7 @@ import jax.numpy as jnp
 
 import mxnet_tpu as mx
 from mxnet_tpu.ops import ssm
+from mxnet_tpu import registry
 from mxnet_tpu.registry import OpContext, get_op
 
 H, D, Q = 2, 8, 8                       # heads, head_dim, chunk
@@ -22,7 +23,7 @@ NAMES = ("q", "k", "v", "gate", "beta", "A_log", "dt_bias")
 
 
 # ----------------------------------------------------------- delta rule
-def _delta_inputs(S, T, seed=0, a=(0.05, 1.0), gate_shift=-2.0):
+def _delta_inputs(S, T, seed=0, a=(0.05, 1.0), gate_shift=-2.0, H=H, D=D):
     """Inputs of one delta rule; A = exp(A_log) spread over `a` and the
     gate's mean at `gate_shift`, so that some channels forget within a
     chunk and others carry across several."""
@@ -39,6 +40,9 @@ def _delta_inputs(S, T, seed=0, a=(0.05, 1.0), gate_shift=-2.0):
 
 def _gates(v, S, T):
     """(q, k normalised and q scaled; g; beta) as the op makes them."""
+    H = v["A_log"].shape[0]
+    D = v["dt_bias"].shape[0] // H
+
     def unit(t):
         t = t.reshape(S, T, H, D)
         return t / jnp.sqrt(jnp.sum(t * t, -1, keepdims=True) + 1e-6)
@@ -53,6 +57,7 @@ def _token_by_token(v, S, T):
     S_{t-1} + beta k v^T, o_t = S_t^T q_t, a Python loop over tokens, a
     zero state at each sequence's start."""
     q, k, g, beta = _gates(v, S, T)
+    H, D = q.shape[2:]
     val = v["v"].reshape(S, T, H, D)
     state, out = jnp.zeros((S, H, D, D)), []
     for t in range(T):
@@ -67,17 +72,19 @@ def _token_by_token(v, S, T):
 
 
 def _delta(v, T, chunk=Q):
+    H = v["A_log"].shape[0]
     return get_op("GatedDeltaRule").fcompute(
-        {"heads": H, "head_dim": D, "chunk": chunk, "seq_len": T},
-        [v[n] for n in NAMES], OpContext(True))[0]
+        {"heads": H, "head_dim": v["dt_bias"].shape[0] // H, "chunk": chunk,
+         "seq_len": T}, [v[n] for n in NAMES], OpContext(True))[0]
 
 
 def _delta_through_the_symbol(v, T, head_grad=None, chunk=Q):
     """(output, gradients of every input) of sym.GatedDeltaRule bound
     on the CPU."""
+    H = v["A_log"].shape[0]
     net = mx.sym.GatedDeltaRule(*(mx.sym.Variable(n) for n in NAMES),
-                                heads=H, head_dim=D, chunk=chunk, seq_len=T,
-                                name="kda")
+                                heads=H, head_dim=v["dt_bias"].shape[0] // H,
+                                chunk=chunk, seq_len=T, name="kda")
     grads = {n: mx.nd.zeros(v[n].shape) for n in NAMES}
     ex = net.bind(mx.cpu(), {n: mx.nd.array(np.asarray(v[n])) for n in NAMES},
                   args_grad=grads)
@@ -185,14 +192,21 @@ def test_delta_rule_refuses_rows_that_are_no_whole_sequences_or_chunks():
         _delta_through_the_symbol(v, 12)        # one sequence, chunks of 8
 
 
-@pytest.mark.parametrize("chunk", [8, 64])
-def test_a_strong_decay_gives_no_inf_or_nan_in_either_pass(chunk):
+@pytest.mark.parametrize("chunk,heads,dim,kernels", [
+    (8, H, D, False), (64, H, D, False), (64, 1, 128, True)])
+def test_a_strong_decay_gives_no_inf_or_nan_in_either_pass(
+        monkeypatch, chunk, heads, dim, kernels):
     """g of -30 a step and more: over a chunk of 64 the running sum
     passes -1,900, and exp of its negation is far past float32; no
-    exponent the chunked form takes is positive."""
+    exponent the chunked form takes is positive, on XLA's path and
+    (a head of 128, under the Pallas interpreter) on the kernels'."""
+    monkeypatch.setattr(ssm, "_INTERPRET", kernels)
     T = 128
-    v = _delta_inputs(2, T, seed=3)
-    v["A_log"] = jnp.full((H,), math.log(30.0))
+    v = _delta_inputs(2, T, seed=3, H=heads, D=dim)
+    with registry.counting() as counted:
+        _delta(v, T, chunk)
+    assert float(counted["kda.kernel_chunks"]) == (2 * T // chunk) * kernels
+    v["A_log"] = jnp.full((heads,), math.log(30.0))
     v["gate"] = 2.0 + jnp.abs(v["gate"])            # softplus(.) > 1
     _, _, g, _ = _gates(v, 2, T)
     assert float(jnp.max(g)) < -30.0
@@ -203,6 +217,127 @@ def test_a_strong_decay_gives_no_inf_or_nan_in_either_pass(chunk):
     grads = jax.grad(lambda v: jnp.sum(jnp.sin(_delta(v, T, chunk))))(v)
     for name, g in grads.items():
         assert bool(jnp.isfinite(g).all()), name
+
+
+# ------------------------------------------- the delta rule's kernels
+def _chunks_of(cells, chunk, dim, seed=0):
+    """k, q and the running sum G of `cells` chunks (cells, chunk, dim)
+    float32, with cotangents for Akk and Aqk."""
+    rs = np.random.RandomState(seed)
+    k, q = (rs.randn(cells, chunk, dim) / math.sqrt(dim) for _ in range(2))
+    G = np.cumsum(-0.3 * np.abs(rs.randn(cells, chunk, dim)), axis=1)
+    cts = [rs.randn(cells, chunk, chunk) for _ in range(2)]
+    return [jnp.asarray(t, jnp.float32) for t in (k, q, G)], \
+        [jnp.asarray(t, jnp.float32) for t in cts]
+
+
+@pytest.mark.parametrize("steps", [1, 2])
+@pytest.mark.parametrize("chunk,dim", [(64, 128), (32, 128), (16, 128)])
+def test_kernels_under_the_interpreter_are_todays_products(
+        monkeypatch, chunk, dim, steps):
+    """Akk, Aqk and the three gradients by the two Pallas kernels
+    against `_decayed_products` and jax's gradient of it: one chunk (a
+    grid step made up with zeros) and a grid step of 128 blocks and one
+    more chunk (two steps, several chunks each); float32 to 1e-5 of the
+    largest entry."""
+    monkeypatch.setattr(ssm, "_INTERPRET", True)
+    cells = 1 if steps == 1 else 2048 // chunk + 1
+    (k, q, G), cts = _chunks_of(cells, chunk, dim, seed=chunk + steps)
+    assert ssm._kernel_fits(k, q, G)
+
+    def old(k, q, G):
+        return ssm._decayed_products([k, q], k, G)
+
+    def weighed(f):
+        return lambda *a: sum(jnp.sum(c * x) for c, x in zip(cts, f(*a)))
+    new = ssm._intra_kernels(chunk, dim, True)
+    for want, got in zip(old(k, q, G), new(k, q, G)):
+        assert got.shape == want.shape
+        np.testing.assert_allclose(got, want, rtol=0,
+                                   atol=1e-5 * float(jnp.abs(want).max()))
+    for name, want, got in zip(
+            "kqG", jax.grad(weighed(old), (0, 1, 2))(k, q, G),
+            jax.grad(weighed(new), (0, 1, 2))(k, q, G)):
+        np.testing.assert_allclose(got, want, rtol=0, err_msg=name,
+                                   atol=1e-5 * float(jnp.abs(want).max()))
+
+
+def test_the_decays_gradient_is_rows_times_theirs_less_keys_times_theirs():
+    """What the backward kernel computes, against jax's gradient of a
+    block taken pair by pair alone: with the rows and the keys as
+    arguments of their own, dG = R * dR - K * dK summed over the two
+    pairs."""
+    (k, q, G), cts = _chunks_of(3, ssm._DIRECT_ROWS, 24, seed=5)
+
+    def weighed(rk, rq, keys, G):
+        return sum(jnp.sum(c * a) for c, a in zip(
+            cts, ssm._decayed_products([rk, rq], keys, G)))
+    drk, drq, dkeys, dG = jax.grad(weighed, (0, 1, 2, 3))(k, q, k, G)
+    np.testing.assert_allclose(dG, k * drk + q * drq - k * dkeys, rtol=0,
+                               atol=1e-5 * float(jnp.abs(dG).max()))
+
+
+@pytest.mark.parametrize("chunk,dim,dtype,fits", [
+    (64, 128, "float32", True), (128, 128, "float32", True),
+    (16, 128, "float32", True), (20, 128, "float32", False),
+    (64, 12, "float32", False), (64, 256, "float32", False),
+    (64, 128, "bfloat16", False), (256, 128, "float32", False)])
+def test_shapes_the_kernels_take_and_turn_away(monkeypatch, chunk, dim,
+                                               dtype, fits):
+    """The kernels take float32 operands, a head of 128 and a chunk of
+    16, 32, 64 or 128 rows, under the interpreter (on the CPU with no
+    interpreter, nothing)."""
+    k, q, G = (t.astype(dtype) for t in _chunks_of(2, chunk, dim)[0])
+    assert not ssm._kernel_fits(k, q, G)
+    monkeypatch.setattr(ssm, "_INTERPRET", True)
+    assert ssm._kernel_fits(k, q, G) == fits
+
+
+@pytest.mark.parametrize("chunk,dim", [(20, 128), (8, 12)])
+def test_a_chunk_or_a_head_the_kernels_turn_away_takes_the_old_path(
+        monkeypatch, chunk, dim):
+    """Chunks of 20 and heads of 12 go through `_decayed_products`,
+    and the op counts no chunk as the kernels'; off the TPU and with no
+    interpreter so does every shape."""
+    monkeypatch.setattr(ssm, "_INTERPRET", True)
+    monkeypatch.setattr(ssm, "_intra_kernels", None)    # would raise
+    T = 2 * chunk
+    v = _delta_inputs(1, T, seed=6, H=1, D=dim)
+    with registry.counting() as counted:
+        out = _delta(v, T, chunk)
+    assert float(counted["kda.kernel_chunks"]) == 0
+    assert float(counted["kda.chunks"]) == 2
+    np.testing.assert_allclose(out, _token_by_token(v, 1, T), rtol=2e-5,
+                               atol=2e-5)
+    monkeypatch.setattr(ssm, "_INTERPRET", False)
+    v = _delta_inputs(1, 128, seed=6, H=1, D=128)
+    with registry.counting() as counted:
+        _delta(v, 128, 64)
+    assert float(counted["kda.kernel_chunks"]) == 0
+
+
+def test_the_symbol_on_the_kernel_path_is_the_recurrence(monkeypatch):
+    """`sym.GatedDeltaRule` with a head of 128 and chunks of 64 under
+    the Pallas interpreter: values and the gradient of every input
+    against the recurrence token by token, and every chunk counted as
+    the kernels'."""
+    monkeypatch.setattr(ssm, "_INTERPRET", True)
+    S, T = 2, 128
+    v = _delta_inputs(S, T, seed=8, H=2, D=128)
+    want = _token_by_token(v, S, T)
+    weight = jnp.asarray(np.random.RandomState(9).randn(*want.shape),
+                         jnp.float32)
+    got, g_got = _delta_through_the_symbol(v, T, head_grad=weight, chunk=64)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    g_want = jax.grad(lambda v: jnp.sum(weight * _token_by_token(v, S, T)))(v)
+    for name in v:
+        scale = float(jnp.max(jnp.abs(g_want[name]))) + 1e-6
+        np.testing.assert_allclose(g_got[name] / scale, g_want[name] / scale,
+                                   atol=1e-5, err_msg=name)
+    with registry.counting() as counted:
+        _delta(v, T, 64)
+    assert float(counted["kda.kernel_chunks"]) \
+        == float(counted["kda.chunks"]) == S * T // 64
 
 
 # ------------------------------------------------------------ attention

@@ -98,6 +98,13 @@ def test_mamba_nodes_and_the_pass_from_the_path(op_name, group, which):
     # step of models/kimi_linear.py spells them
     (STEP + "jvp(l1_kda)/mx.kda.intra/...rc,...ic->...ri/dot_general",
      "kda.intra", "forward"),
+    # Akk and Aqk by the op's own kernels (PR 35): ONE custom-call a
+    # pass, named by the scope it was called under, as a traced chip run
+    # of the cell spells them
+    (STEP + "jvp(l1_kda)/mx.kda.intra/pallas_call", "kda.intra", "forward"),
+    (BACK + "rematted_computation/l1_kda/mx.kda.intra/pallas_call",
+     "kda.intra", "made again"),
+    (BACK + "l1_kda/mx.kda.intra/pallas_call", "kda.intra", "backward"),
     (BACK + "l2_kda/mx.kda.solve/triangular_solve", "kda.solve", "backward"),
     (BACK + "rematted_computation/l3_kda/mx.kda.carry/while/body/add",
      "kda.carry", "made again"),
@@ -133,6 +140,30 @@ def test_mamba_nodes_and_the_pass_from_the_path(op_name, group, which):
 def test_delta_rule_and_latent_attention_nodes(op_name, group, which):
     assert trace_by_node.group_of(op_name) == group
     assert trace_by_node.pass_of(op_name) == which
+
+
+def test_a_kernels_custom_call_counts_under_its_scope_by_pass():
+    """The delta rule's kernels on the device's line of a trace: one
+    `custom-call` an instruction, named after the scope (the compiler
+    numbers it), its time under `kda.intra` in the pass its `op_name`
+    shows."""
+    text = ("%%mx.kda.intra.%d = (f32[262144,64]{1,0:T(8,128)}, "
+            "f32[262144,64]{1,0:T(8,128)}) custom-call(f32[262144,128]{1,0} "
+            "%%bitcast.1), custom_call_target=\"tpu_custom_call\"")
+    names = {"mx.kda.intra.3": STEP + "jvp(l1_kda)/mx.kda.intra/pallas_call",
+             "mx.kda.intra.9": BACK + "rematted_computation/l1_kda/"
+                               "mx.kda.intra/pallas_call",
+             "mx.kda.intra.23": BACK + "l1_kda/mx.kda.intra/pallas_call"}
+    events = [(text % 3, 0, 2200), (text % 9, 3000, 5100),
+              (text % 23, 6000, 8650), (text % 23, 9000, 11650)]
+    by_group, unnamed, by_instruction = trace_by_node.split_by_group(
+        events, names)
+    assert unnamed == 0
+    assert by_group == {
+        ("kda.intra", "forward"): pytest.approx(2200e-9),
+        ("kda.intra", "made again"): pytest.approx(2100e-9),
+        ("kda.intra", "backward"): pytest.approx(5300e-9)}
+    assert by_instruction["mx.kda.intra.23"][1] == 2
 
 
 def test_the_report_has_a_row_a_group_and_a_column_a_pass():

@@ -18,7 +18,11 @@ in it: a conditional's branch, a loop's body) by the group of nodes
 they name and by the pass the path shows (forward, a checkpointed
 segment made again, backward), for the device that was busy longest;
 what names no group is the remainder, printed with the rest, never
-dropped.  `--top N` lists the N instructions that took longest, each
+dropped.  A Pallas kernel is one instruction (a `custom-call` whose
+`op_name` ends in `<scope>/pallas_call`): it counts under the node and
+the scope it was called under, in the pass its path shows, as the
+instructions it replaced did (`l1_kda/mx.kda.intra/pallas_call` under
+`kda.intra`).  `--top N` lists the N instructions that took longest, each
 with the end of its `op_name`.
 
 The trace is any `jax.profiler.trace(dir)` around `Module.fit`;
