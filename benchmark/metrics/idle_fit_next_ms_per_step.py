@@ -1,0 +1,8 @@
+"""input layer: device-idle milliseconds a step while the innermost
+span open on `fit`'s thread was `fit.next`: the device waited for the
+iterator (`benchmark/idle_by_phase.py`)."""
+from benchmark import idle_by_phase
+
+
+def read(run):
+    return idle_by_phase.ms_per_step(run, "fit_next")

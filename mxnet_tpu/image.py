@@ -13,6 +13,7 @@ import logging
 import os
 import random
 import threading
+import time
 import weakref
 from concurrent.futures import ThreadPoolExecutor
 
@@ -348,7 +349,8 @@ def _proc_decode_one(args):
 
 
 def _stack_chunk(decoded):
-    """What a pool task hands back: its images as ONE contiguous uint8
+    """What a pool task hands back (the task adds its own start and end
+    on ``time.time_ns()``'s clock): its images as ONE contiguous uint8
     block and its labels as one.  cv2 gives BGR, and RGB is a view that
     reads it backwards, a strided copy a pixel when it is stacked; made
     here the copies run on the pool's threads, and the thread that
@@ -361,7 +363,9 @@ def _stack_chunk(decoded):
 
 
 def _proc_decode_chunk(chunk):
-    return _stack_chunk([_proc_decode_one(args) for args in chunk])
+    t0 = time.time_ns()
+    return _stack_chunk([_proc_decode_one(args) for args in chunk]) + (
+        t0, time.time_ns())
 
 
 _DECODE_CHUNK = 8    # records a pool task: 32 tasks a batch of 256
@@ -377,13 +381,13 @@ class _Slot(object):
     each stage took."""
 
     __slots__ = ("gen", "before", "after", "rng", "idxs", "pad", "work",
-                 "done", "batch", "error", "stage_ns", "h2d")
+                 "done", "batch", "error", "stage_ns", "h2d", "decode_wall")
 
     def __init__(self, gen, before, after, rng, idxs, pad):
         self.gen, self.before, self.after, self.rng = gen, before, after, rng
         self.idxs, self.pad = idxs, pad
         self.work, self.done, self.batch, self.error = (), False, None, None
-        self.stage_ns, self.h2d = {}, 0
+        self.stage_ns, self.h2d, self.decode_wall = {}, 0, 0
 
     def cancel(self):
         for fut in self.work:
@@ -785,6 +789,8 @@ class ImageRecordIter(DataIter):
         if slot.error is not None:
             raise slot.error
         telemetry.count("input.h2d_bytes", slot.h2d)
+        if slot.decode_wall:
+            telemetry.count("input.decode_wall_ns", slot.decode_wall)
         return slot.batch
 
     def _begin(self, state, gen):
@@ -832,7 +838,9 @@ class ImageRecordIter(DataIter):
         return slot
 
     def _decode_chunk(self, idxs):
-        return _stack_chunk([self._decode_one(i) for i in idxs])
+        t0 = time.time_ns()
+        return _stack_chunk([self._decode_one(i) for i in idxs]) + (
+            t0, time.time_ns())
 
     def _produce(self, state):
         """One turn of the producer: finish the oldest batch begun and
@@ -874,6 +882,11 @@ class ImageRecordIter(DataIter):
             else:
                 blocks = [fut.result() for fut in slot.work]
         slot.stage_ns["input.decode"] = clock.ns
+        if not self.cache_decoded:
+            # the decode stage's own wall: its first task's start to
+            # its last task's end, whoever waited for them
+            slot.decode_wall = max(b[3] for b in blocks) - \
+                min(b[2] for b in blocks)
         mirror = None
         if self.rand_mirror and not self._defer:
             with state.cond:

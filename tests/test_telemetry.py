@@ -12,6 +12,7 @@ retrace and stays at 0 post-warmup for a steady loop, disabled mode is
 a no-op, and the retrofitted ServingStats/PipelineStats keep their
 exact snapshot surface while living in the shared registry.
 """
+import collections
 import json
 import logging
 import os
@@ -62,6 +63,12 @@ def _fit(mod_net, X, y, seed=11, **kw):
     mod.fit(it, num_epoch=2, optimizer_params={"learning_rate": 0.1},
             initializer=mx.init.Uniform(0.07), **kw)
     return mod
+
+
+def _own(counters):
+    """A report's counters but the collector's (`py.gc_*`), which a pass
+    of the oldest generation adds to whatever fit it falls in."""
+    return {k: v for k, v in counters.items() if not k.startswith("py.gc_")}
 
 
 def _params_bytes(mod):
@@ -634,7 +641,7 @@ def test_last_fit_is_the_latest_and_this_threads():
     second = tel.last_fit()
     assert done.is_set()
     assert second["steps"] == 4 and second["epochs"] == 1
-    assert "elsewhere" not in second["spans"] and not second["counters"]
+    assert "elsewhere" not in second["spans"] and not _own(second["counters"])
     assert "elsewhere" in {e["name"] for e in tel.trace_events()}
     assert tel.last_fit() == second and second is not tel.last_fit()
 
@@ -697,9 +704,14 @@ def test_image_record_iter_stages_under_fit_next(tmp_path, device_augment):
         per_step = pixels + mirror_draws + labels
     else:
         per_step = 4 * pixels + labels
-    counters = dict(rep["counters"])
+    counters = _own(rep["counters"])
     assert counters.pop("input.ready", 0) + counters.pop("input.waited", 0) \
         == steps
+    # the decode stage's own wall, first task's start to last task's
+    # end, a batch: at most the producer's whole time over the batches
+    wall = counters.pop("input.decode_wall_ns")
+    first, last = rep["start_ns"], rep["end_ns"]
+    assert 0 < wall <= steps * (last - first)
     assert counters == {"input.h2d_bytes": per_step * steps}
 
 
@@ -740,3 +752,131 @@ def test_symbol_node_names_reach_the_lowered_program():
         # `.../transpose(jvp(fc1))/dot_general`
         assert "/jvp(%s)/" % node in text, node
     assert "/transpose(jvp(fc1))/" in text
+
+
+# ----------------------------------------------------------------------
+# The report's intervals and the collector's passes
+# ----------------------------------------------------------------------
+GC = "py.gc"
+
+
+def _profile_start_ns(trace_dir):
+    """The session's start on the host's real-time clock: the `Task
+    Environment` plane's stat, from which every event counts."""
+    sys.path.insert(0, ROOT)
+    from benchmark import trace_reduce
+    from jax.profiler import ProfileData
+    data = ProfileData.from_file(trace_reduce.find_xplane(str(trace_dir)))
+    (start,) = [v for p in data.planes if p.name == "Task Environment"
+                for k, v in p.stats if k == "profile_start_time"]
+    return int(start)
+
+
+def test_fit_intervals_lie_on_the_profilers_clock(tmp_path):
+    """Each span of `fit`'s thread leaves `(name, start_ns, end_ns)` in
+    the report on `time.time_ns()`'s clock, which the profiler's trace
+    keeps shifted by the session's start: every `mx.fit.update` event,
+    moved by that start, lies within 100 us of its interval."""
+    X, y = _data()
+    spans = _traced(tmp_path, lambda: _fit(_mlp(), X, y))
+    start = _profile_start_ns(tmp_path)
+    rep = tel.last_fit()
+    mine = [(s, e) for n, s, e in rep["intervals"] if n == "fit.update"]
+    theirs = [(s + start, e + start) for n, s, e in spans
+              if n == "fit.update"]
+    assert len(mine) == len(theirs) == rep["steps"] == 8
+    for (s, e), (ts, te) in zip(mine, sorted(theirs)):
+        assert abs(s - ts) <= 100e3 and abs(e - te) <= 100e3
+
+
+def test_the_report_keeps_its_threads_intervals():
+    """One interval per span closed on `fit`'s thread, in the order
+    they closed, each inside the root's; the root's is `start_ns` and
+    `end_ns`; a span credited from another thread leaves none."""
+    X, y = _data()
+    _fit(_mlp(), X, y)
+    rep = tel.last_fit()
+    names = [n for n, _s, _e in rep["intervals"] if n != GC]
+    assert collections.Counter(names) == {
+        n: r["count"] for n, r in rep["spans"].items()}
+    assert rep["intervals_dropped"] == 0
+    (root,) = [iv for iv in rep["intervals"] if iv[0] == "fit"]
+    assert root == ("fit", rep["start_ns"], rep["end_ns"])
+    assert rep["end_ns"] - rep["start_ns"] == rep["wall_ns"]
+    ends = [e for n, _s, e in rep["intervals"] if n != GC]
+    assert ends == sorted(ends)
+    for n, s, e in rep["intervals"]:
+        assert rep["start_ns"] <= s <= e <= rep["end_ns"], n
+
+    report = tel.FitReport()
+    tel.tracing._tls.report, outer = report, tel.tracing._tls.report
+    try:
+        tel.credit("input.decode", 5)
+    finally:
+        tel.tracing._tls.report = outer
+    assert report.intervals == [] and report.as_dict()["spans"][
+        "input.decode"]["total_ns"] == 5
+
+
+def test_intervals_past_the_bound_are_counted_as_dropped(monkeypatch):
+    monkeypatch.setattr(tel.tracing, "_INTERVALS", 10)
+    X, y = _data()
+    _fit(_mlp(), X, y)
+    rep = tel.last_fit()
+    assert len(rep["intervals"]) == 10
+    assert rep["intervals_dropped"] == \
+        sum(r["count"] for r in rep["spans"].values()) - 10 > 0
+
+
+def test_a_clock_that_steps_back_gives_no_negative_span(monkeypatch):
+    """The real-time clock may be stepped: a span whose end reads
+    before its start lasts 0."""
+    reads = iter([2_000_000, 1_000_000])
+    monkeypatch.setattr(tel.tracing.time, "time_ns", lambda: next(reads))
+    with tel.span("stepped") as sp:
+        pass
+    assert sp.ns == 0 and sp.start_ns == 2_000_000
+
+
+def test_collector_passes_land_in_the_open_fit(monkeypatch):
+    """A pass of the oldest generation while a `fit` is open, on its
+    thread or on another, is a `py.gc` interval of its report and a
+    pass of `py.gc_ns` / `py.gc_passes`, and no span row: the self
+    times still tile the call.  One after the fit, like the harness's
+    own after the window, lands nowhere."""
+    import gc
+    X, y = _data()
+
+    def collect(param):
+        if param.nbatch == 1:
+            gc.collect()
+            t = threading.Thread(target=gc.collect)
+            t.start()
+            t.join(10)
+
+    _fit(_mlp(), X, y, batch_end_callback=collect)
+    rep = tel.last_fit()
+    gcs = [(s, e) for n, s, e in rep["intervals"] if n == GC]
+    assert len(gcs) == rep["counters"]["py.gc_passes"] >= 4   # 2 epochs
+    assert rep["counters"]["py.gc_ns"] == sum(e - s for s, e in gcs) > 0
+    assert GC not in rep["spans"]
+    assert sum(r["self_ns"] for r in rep["spans"].values()) \
+        == rep["wall_ns"]
+    gc.collect()
+    assert tel.last_fit() == rep
+
+
+def test_a_young_generations_pass_records_nothing():
+    import gc
+    report = tel.FitReport()
+    outer = tel.tracing._open_fit
+    tel.tracing._open_fit = report
+    try:
+        gc.collect(0)
+        gc.collect(1)
+        assert report.intervals == [] and report.counters == {}
+        gc.collect(2)
+        assert [n for n, _s, _e in report.intervals] == [GC]
+        assert report.counters["py.gc_passes"] == 1
+    finally:
+        tel.tracing._open_fit = outer
