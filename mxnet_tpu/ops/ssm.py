@@ -29,7 +29,11 @@ rank one each step::
 
 multiplies its state by a MATRIX each step, so its chunked form
 (:func:`gated_delta_chunked`) has a unit-triangular solve inside each
-chunk and a carry of two matrix products a chunk.  The decayed inner
+chunk and a carry of two matrix products a chunk.  The solve makes each
+chunk's inverse by halving down to blocks of ``_DIRECT_ROWS`` rows,
+solved by substitution with the chunks across the lanes and merged by
+products, and multiplies; its backward pass reads that inverse and
+inverts nothing (:func:`_unit_lower_solver`).  The decayed inner
 products of a chunk's rows and keys, and their gradient, are on a TPU
 two Pallas kernels of this module (``_intra_forward_kernel``,
 ``_intra_backward_kernel``) and elsewhere ``_decayed_products``: one
@@ -727,6 +731,93 @@ def _intra_kernels(Q, d, interpret=False):
     return products
 
 
+def _unit_lower_inverse(L):
+    """The inverses of unit lower-triangular systems L (n, n, ...),
+    read below the diagonal only, a system to each place of the
+    trailing axes (so that the systems lie across the lanes, and a row
+    of one is a row of every one).
+
+    A system of more than ``_DIRECT_ROWS`` rows, and an even count, is
+    halved: its two halves on the diagonal are inverted at once as
+    systems of their own, and merged by
+    ``T21 = -T22 L21 T11`` under ``T11`` and ``T22``.  At
+    ``_DIRECT_ROWS`` rows or fewer, or an odd count, forward
+    substitution: row i of the inverse is ``e_i`` less the rows above
+    it weighed by ``L[i]``.  Every product is of float32 elements."""
+    import jax.numpy as jnp
+    n = L.shape[0]
+    if n > _DIRECT_ROWS and n % 2 == 0:
+        m = n // 2
+        both = _unit_lower_inverse(jnp.stack([L[:m, :m], L[m:, m:]], axis=2))
+        t11, t22 = both[:, :, 0], both[:, :, 1]
+        t22_l21 = jnp.sum(t22[:, :, None] * L[m:, :m][None], axis=1)
+        t21 = -jnp.sum(t22_l21[:, :, None] * t11[None], axis=1)
+        return jnp.concatenate([
+            jnp.concatenate([t11, jnp.zeros_like(t11)], axis=1),
+            jnp.concatenate([t21, t22], axis=1)], axis=0)
+    eye = jnp.eye(n, dtype=L.dtype).reshape((n, n) + (1,) * (L.ndim - 2))
+    t = jnp.broadcast_to(eye[:1], (1, n) + L.shape[2:])
+    for i in range(1, n):
+        row = eye[i] - jnp.sum(L[i, :i, None] * t, axis=0)
+        t = jnp.concatenate([t, row[None]], axis=0)
+    return t
+
+
+@functools.lru_cache(maxsize=None)
+def _unit_lower_solver():
+    """``X = T rhs`` with ``T`` the inverse of the unit lower-triangular
+    ``system`` (..., n, n), read below the diagonal only; rhs
+    (..., n, w).  ``T`` is made by ``_unit_lower_inverse`` with the
+    systems moved across the lanes, and moved back for the product.
+    The backward pass reads ``T`` and ``X`` and inverts nothing::
+
+        g_rhs    = T^T g
+        g_system = -tril(g_rhs X^T, -1)
+
+    The products are float32 at ``HIGHEST``.  The backward function
+    enters the solve's scope again: a ``custom_vjp``'s backward function
+    loses the scope it was called under."""
+    import jax
+    import jax.numpy as jnp
+    hi = jax.lax.Precision.HIGHEST
+
+    def inverse_and_product(system, rhs):
+        lanes = jnp.moveaxis(system, (-2, -1), (0, 1))
+        # the barrier keeps XLA from laying the inverse out as the product
+        # reads it, which puts a system's 16 and 32 columns across 128
+        # lanes (a v5e: 3.3 ms where 2.0 do for 4,096 systems of 64)
+        t = jax.lax.optimization_barrier(_unit_lower_inverse(lanes))
+        t = jnp.moveaxis(t, (0, 1), (-2, -1))
+        return t, jnp.matmul(t, rhs, precision=hi)
+
+    @jax.custom_vjp
+    def solve(system, rhs):
+        return inverse_and_product(system, rhs)[1]
+
+    def forward(system, rhs):
+        t, x = inverse_and_product(system, rhs)
+        return x, (t, x)
+
+    def backward(res, g):
+        t, x = res
+        with jax.named_scope("mx.kda.solve"):
+            g_rhs = jnp.einsum("...ji,...jc->...ic", t, g, precision=hi)
+            g_system = jnp.einsum("...ic,...jc->...ij", g_rhs, x,
+                                  precision=hi)
+            n = t.shape[-1]
+            below = jnp.tril(jnp.ones((n, n), bool), -1)
+            return jnp.where(below, -g_system, 0.0), g_rhs
+
+    solve.defvjp(forward, backward)
+    return solve
+
+
+def _unit_lower_solve(system, rhs):
+    """``system^-1 rhs`` for unit lower-triangular systems (..., n, n),
+    read below the diagonal only, and rhs (..., n, w); float32."""
+    return _unit_lower_solver()(system, rhs)
+
+
 def _delta_carry(w, u, k_end, decay):
     """The state at each chunk's start and each chunk's corrected
     values, from the chunks' solved W (S, chunks, H, Q, d), U
@@ -807,9 +898,8 @@ def gated_delta_chunked(q, k, v, g, beta, chunk):
     with jax.named_scope("mx.kda.solve"):
         below = jnp.tril(jnp.ones((Q, Q), bool), -1)
         system = jnp.where(below, bs * akk, 0.0) + jnp.eye(Q, dtype=f32)
-        wu = jax.lax.linalg.triangular_solve(
-            system, bs * jnp.concatenate([ks * jnp.exp(G), vs], axis=-1),
-            left_side=True, lower=True, unit_diagonal=True)
+        wu = _unit_lower_solve(
+            system, bs * jnp.concatenate([ks * jnp.exp(G), vs], axis=-1))
         w, u = wu[..., :d].astype(cdt), wu[..., d:]
     with jax.named_scope("mx.kda.carry"):
         # the chunk-boundary states: what the backward pass of the
@@ -859,7 +949,11 @@ def _gated_delta_rule(attrs, ins, octx):
     tokens at a time (default 64; ``seq_len`` a whole number of them)
     with one unit-triangular solve a chunk and one carry of
     ``seq_len / chunk`` steps between the chunks; gates, decays, the
-    solve and the state in float32.  Counts ``kda.chunks``,
+    solve and the state in float32.  The solve inverts each chunk's
+    system by halving it down to blocks of at most 16 rows, solved by
+    substitution and merged by products, then multiplies; its backward
+    pass takes two products with that inverse and inverts nothing.
+    Counts ``kda.chunks``,
     ``kda.carried_bytes`` (the chunk-boundary states the carry passes)
     and ``kda.kernel_chunks`` (the chunks whose decayed inner products
     the kernels made: all of them or none)."""

@@ -68,3 +68,24 @@ def test_the_delta_rules_kernels_compile_at_the_cells_width(
     # the backward kernel alone: nothing it is handed comes from the
     # forward one
     assert text.count("tpu_custom_call") == 1
+
+
+def test_the_delta_rules_solve_compiles_to_no_block_inversion(
+        one_chip, no_compile_cache):
+    """One layer's solve of the Kimi cell (4,096 systems of 64 and a
+    right-hand side of 256) and its gradient: XLA's triangular solve
+    compiles to a `custom-call` that inverts the diagonal blocks row by
+    row, the solve by halving to none."""
+    def shape(*dims):
+        return jax.ShapeDtypeStruct(dims, jnp.float32, sharding=one_chip)
+    operands = shape(1, 128, 32, 64, 64), shape(1, 128, 32, 64, 256)
+
+    def xla(system, rhs):
+        return jax.lax.linalg.triangular_solve(
+            system, rhs, left_side=True, lower=True, unit_diagonal=True)
+
+    def both(solve):
+        return jax.grad(lambda s, b: jnp.sum(jnp.sin(solve(s, b))), (0, 1))
+    for f, calls in ((xla, True), (ssm._unit_lower_solve, False)):
+        text = jax.jit(both(f)).lower(*operands).compile().as_text()
+        assert ("InvertDiagBlocksLowerTriangular" in text) == calls

@@ -219,6 +219,105 @@ def test_a_strong_decay_gives_no_inf_or_nan_in_either_pass(
         assert bool(jnp.isfinite(g).all()), name
 
 
+# ------------------------------------------------------------ the solve
+def _xla_solve(system, rhs):
+    return jax.lax.linalg.triangular_solve(system, rhs, left_side=True,
+                                           lower=True, unit_diagonal=True)
+
+
+def _systems(chunk, width, seed):
+    """Chunk systems as the delta rule builds them, I + tril(beta Akk,
+    -1), from keys of length 1 and decays, with noise above the
+    diagonal and on it (neither solve reads there); and a right-hand
+    side (2, 3, chunk, width)."""
+    rs = np.random.RandomState(seed)
+    k = rs.randn(2, 3, chunk, 16)
+    k /= np.linalg.norm(k, axis=-1, keepdims=True)
+    G = np.cumsum(-0.1 * np.abs(rs.randn(2, 3, chunk, 16)), axis=2)
+    akk, = ssm._decayed_products([jnp.asarray(k, jnp.float32)],
+                                 jnp.asarray(k, jnp.float32),
+                                 jnp.asarray(G, jnp.float32))
+    beta = 1 / (1 + np.exp(-rs.randn(2, 3, chunk, 1)))
+    system = np.tril(beta * np.asarray(akk), -1) \
+        + np.triu(rs.randn(2, 3, chunk, chunk), 1) + 3 * np.eye(chunk)
+    return (jnp.asarray(system, jnp.float32),
+            jnp.asarray(rs.randn(2, 3, chunk, width), jnp.float32))
+
+
+def _float64_solve(system, rhs):
+    unit = np.tril(np.asarray(system, np.float64), -1) \
+        + np.eye(system.shape[-1])
+    return np.linalg.solve(unit, np.asarray(rhs, np.float64))
+
+
+@pytest.mark.parametrize("width", [256, 24])
+@pytest.mark.parametrize("chunk", [64, 32, 20, 16, 8])
+def test_the_solve_by_halving_is_xlas_and_float64s(chunk, width):
+    """The inverse by halving (64: 16 -> 32 -> 64; 32; 20: 10 -> 20;
+    16 and 8 by substitution alone) times the right-hand side, against
+    XLA's triangular solve and float64, and both gradients against
+    jax's gradient of XLA's solve; float32 to 1e-5 of the largest
+    entry."""
+    system, rhs = _systems(chunk, width, seed=chunk + width)
+    got = ssm._unit_lower_solve(system, rhs)
+    for want in (_xla_solve(system, rhs), _float64_solve(system, rhs)):
+        np.testing.assert_allclose(got, want, rtol=0,
+                                   atol=1e-5 * float(np.abs(want).max()))
+    weight = jnp.asarray(np.random.RandomState(1).randn(*rhs.shape),
+                         jnp.float32)
+    g_got = jax.grad(lambda s, b: jnp.sum(weight * ssm._unit_lower_solve(
+        s, b)), (0, 1))(system, rhs)
+    g_want = jax.grad(lambda s, b: jnp.sum(weight * _xla_solve(s, b)),
+                      (0, 1))(system, rhs)
+    for name, a, b in zip(("system", "rhs"), g_got, g_want):
+        np.testing.assert_allclose(a, b, rtol=0, err_msg=name,
+                                   atol=1e-5 * float(jnp.abs(b).max()))
+
+
+def test_the_solve_at_the_bound_of_its_entries():
+    """beta near 1, one key for every row and no decay: every entry
+    below the diagonal is 1, the largest the delta rule can make, and
+    the inverse's rows cancel nearly to two entries.  No inf or NaN in
+    either pass, and float64's answer to 1e-4."""
+    Q = 64
+    k = np.ones((1, 1, Q, 128)) / math.sqrt(128)
+    akk, = ssm._decayed_products([jnp.asarray(k, jnp.float32)],
+                                 jnp.asarray(k, jnp.float32),
+                                 jnp.zeros(k.shape, jnp.float32))
+    beta = float(jax.nn.sigmoid(12.0))
+    system = jnp.where(jnp.tril(jnp.ones((Q, Q), bool), -1), beta * akk,
+                       0.0) + jnp.eye(Q)
+    rhs = jnp.asarray(np.random.RandomState(3).randn(1, 1, Q, 256),
+                      jnp.float32)
+    got = ssm._unit_lower_solve(system, rhs)
+    assert bool(jnp.isfinite(got).all())
+    want = _float64_solve(system, rhs)
+    np.testing.assert_allclose(got, want, rtol=0,
+                               atol=1e-4 * float(np.abs(want).max()))
+    for g in jax.grad(lambda s, b: jnp.sum(jnp.sin(ssm._unit_lower_solve(
+            s, b))), (0, 1))(system, rhs):
+        assert bool(jnp.isfinite(g).all())
+
+
+def test_a_training_step_of_the_delta_rule_lowers_without_a_triangular_solve():
+    """The gradient of the op at the cell's chunk of 64 and head of 128,
+    lowered on the CPU: no triangular solve in any pass (on the CPU XLA's
+    is LAPACK's `trsm`)."""
+    def lowered(f, *args):
+        return jax.jit(f).lower(*args).as_text()
+
+    def old_path_shows(text):
+        return "triangular_solve" in text or "trsm" in text
+    s = jnp.eye(64)[None] + jnp.tril(jnp.ones((1, 64, 64)), -1)
+    assert old_path_shows(lowered(jax.grad(lambda s: jnp.sum(_xla_solve(
+        s, s))), s))
+    T = 128
+    v = _delta_inputs(1, T, seed=7, H=2, D=128)
+    text = lowered(jax.grad(lambda v: jnp.sum(jnp.sin(_delta(v, T, 64)))), v)
+    assert not old_path_shows(text)
+    assert "dot_general" in text
+
+
 # ------------------------------------------- the delta rule's kernels
 def _chunks_of(cells, chunk, dim, seed=0):
     """k, q and the running sum G of `cells` chunks (cells, chunk, dim)

@@ -106,6 +106,13 @@ def test_mamba_nodes_and_the_pass_from_the_path(op_name, group, which):
      "kda.intra", "made again"),
     (BACK + "l1_kda/mx.kda.intra/pallas_call", "kda.intra", "backward"),
     (BACK + "l2_kda/mx.kda.solve/triangular_solve", "kda.solve", "backward"),
+    # the solve's own products, its backward function's scope
+    # entered again inside the node's
+    (STEP + "jvp(l2_kda)/mx.kda.solve/dot_general", "kda.solve", "forward"),
+    (BACK + "rematted_computation/l2_kda/mx.kda.solve/dot_general",
+     "kda.solve", "made again"),
+    (BACK + "l2_kda/mx.kda.solve/mx.kda.solve/...ji,...jc->...ic/dot_general",
+     "kda.solve", "backward"),
     (BACK + "rematted_computation/l3_kda/mx.kda.carry/while/body/add",
      "kda.carry", "made again"),
     (STEP + "jvp(l4_kda)/mx.kda.gate/jit(softplus)/log1p", "kda.gate",
