@@ -37,7 +37,10 @@ inverts nothing (:func:`_unit_lower_solver`).  The decayed inner
 products of a chunk's rows and keys, and their gradient, are on a TPU
 two Pallas kernels of this module (``_intra_forward_kernel``,
 ``_intra_backward_kernel``) and elsewhere ``_decayed_products``: one
-arithmetic, two carriers.
+arithmetic, two carriers.  So is the carry (``_delta_steps``, the same
+recurrence run forward and, as its own backward pass, reversed): on a
+TPU ``_carry_forward_kernel`` and ``_carry_backward_kernel`` hold the
+state in fast memory across the chunks.
 """
 from __future__ import annotations
 
@@ -346,6 +349,9 @@ _PLANE = 136
 # chunks a turn of the kernels' loop over chunks takes: the products of
 # one are too short to fill the matrix unit's pipeline
 _CHUNKS = 4
+# heads a grid step of the carry's kernel takes: their states (64 KiB a
+# head of 128 by 128) stay in fast memory from chunk to chunk
+_CARRY_HEADS = 16
 
 
 def _transposed(x):
@@ -818,6 +824,264 @@ def _unit_lower_solve(system, rhs):
     return _unit_lower_solver()(system, rhs)
 
 
+def _delta_steps(w, u, k, decay, extra=None, reverse=False, sign=1,
+                 want_x=True):
+    """The carry's recurrence by ``lax.scan``, a chunk a step, in the
+    chunks' order or (``reverse``) against it; R float32 from zero::
+
+        x[c] = u[c] - sign * w[c] bf16(R)
+        R   <- Diag(decay[c]) R + sign * k[c]^T bf16(x[c]) + extra[c]
+
+    w, k (S, chunks, H, Q, d) in the activation type (bf16 above);
+    u (S, chunks, H, Q, dv) and extra (S, chunks, H, d, dv) float32,
+    extra None for zero; decay (S, chunks, H, d) float32.  Returns R at
+    each chunk's start (before its step) and x, at the chunk's place;
+    x None where not ``want_x``.  Both products take the activation
+    type's operands and sum in float32.  This is the carry off the TPU
+    and at any shape ``_carry_kernel_fits`` turns away, and the tests'
+    oracle for the carry's kernels."""
+    import jax
+    import jax.numpy as jnp
+    f32 = jnp.float32
+    cdt = w.dtype
+
+    def step(state, chunk_c):
+        w_c, u_c, k_c, decay_c, extra_c = chunk_c
+        p = jnp.einsum("bhqd,bhde->bhqe", w_c, state.astype(cdt),
+                       preferred_element_type=f32)
+        x = u_c - p if sign > 0 else u_c + p
+        p = jnp.einsum("bhqd,bhqe->bhde", k_c, x.astype(cdt),
+                       preferred_element_type=f32)
+        new = decay_c[..., None] * state + (p if sign > 0 else -p)
+        if extra_c is not None:
+            new = new + extra_c
+        return new, (state, x) if want_x else state
+    S, _nc, H, _Q, d = w.shape
+    _, out = jax.lax.scan(
+        step, jnp.zeros((S, H, d, u.shape[-1]), f32),
+        tuple(None if t is None else t.swapaxes(0, 1)
+              for t in (w, u, k, decay, extra)), reverse=reverse)
+    r, x = out if want_x else (out, None)
+    return r.swapaxes(0, 1), None if x is None else x.swapaxes(0, 1)
+
+
+def _carry_forward_kernel(heads, w_ref, u_ref, k_ref, decay_ref, s_ref,
+                          state_ref):
+    """One grid step of the carry: ``heads`` heads of one chunk, the
+    recurrence of ``_delta_steps`` with the state of those heads in
+    ``state_ref`` (fast memory, heads x d x dv float32) from chunk to
+    chunk, zero at the chunk axis's first step; the step writes the
+    state it started from, ``s_in``.  The decays come as (d, heads), a
+    head's a column, so that a row of the state is scaled by its
+    channel's decay with no transpose."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    f32 = jnp.float32
+    cdt = w_ref.dtype
+    tn = (((0,), (0,)), ((), ()))           # a^T with b
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        state_ref[...] = jnp.zeros(state_ref.shape, f32)
+    decay = decay_ref[...]
+    for j in range(heads):
+        state = state_ref[j]
+        s_ref[j] = state
+        x = u_ref[j] - jnp.dot(w_ref[j], state.astype(cdt),
+                               preferred_element_type=f32)
+        p = jax.lax.dot_general(k_ref[j], x.astype(cdt), tn,
+                                preferred_element_type=f32)
+        state_ref[j] = decay[:, j:j + 1] * state + p
+
+
+def _carry_backward_kernel(heads, w_ref, u_ref, k_ref, decay_ref, s_ref,
+                           gd_ref, gs_ref, gw_ref, gu_ref, gk_ref,
+                           gdecay_ref, state_ref):
+    """The forward kernel's backward pass, a grid step of it: the
+    recurrence run against the chunks' order with ``w' = -k``,
+    ``u' = g_delta``, ``k' = -w`` and ``extra = g_s`` (gd_ref and
+    gs_ref, the cotangents of delta and of s_in), so that the state it
+    starts a chunk with is ``H``, the cotangent of the state after the
+    chunk, and its x is ``g_u = k H + g_delta``; the chunk's other
+    gradients are taken while both are in fast memory::
+
+        g_w     = -bf16(g_u) bf16(s_in)^T
+        g_k     = bf16(u - w bf16(s_in)) bf16(H)^T
+        g_decay = sum over the values' axis of s_in * H
+
+    g_decay is written as the decays come, (d, heads)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    f32 = jnp.float32
+    cdt = w_ref.dtype
+    nt = (((1,), (1,)), ((), ()))           # a product with b^T
+    tn = (((0,), (0,)), ((), ()))           # a^T with b
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        state_ref[...] = jnp.zeros(state_ref.shape, f32)
+    decay = decay_ref[...]
+    sums = []
+    for j in range(heads):
+        h = state_ref[j]
+        hb = h.astype(cdt)
+        gu = gd_ref[j] + jnp.dot(k_ref[j], hb, preferred_element_type=f32)
+        gu_ref[j] = gu
+        gub = gu.astype(cdt)
+        s = s_ref[j]
+        sb = s.astype(cdt)
+        gw_ref[j] = (-jax.lax.dot_general(
+            gub, sb, nt, preferred_element_type=f32)).astype(gw_ref.dtype)
+        delta = u_ref[j] - jnp.dot(w_ref[j], sb, preferred_element_type=f32)
+        gk_ref[j] = jax.lax.dot_general(
+            delta.astype(cdt), hb, nt,
+            preferred_element_type=f32).astype(gk_ref.dtype)
+        sums.append(jnp.sum(s * h, axis=1, keepdims=True))
+        p = jax.lax.dot_general(w_ref[j], gub, tn,
+                                preferred_element_type=f32)
+        state_ref[j] = decay[:, j:j + 1] * h - p + gs_ref[j]
+    gdecay_ref[...] = jnp.concatenate(sums, axis=1)
+
+
+def _carry_call(w, u, k, decay, grads=None, interpret=False):
+    """``_carry_forward_kernel`` over a grid of (sequence, block of
+    ``_CARRY_HEADS`` heads, chunk), the chunk axis walked in order, or
+    ``_carry_backward_kernel`` with it walked backwards where ``grads``
+    (s_in, g_delta, g_s) asks for the backward pass.  Each grid step
+    reads one chunk's operands and writes its results.  Forward: s_in;
+    backward: (g_w, g_u, g_k, g_decay)."""
+    import math
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    f32 = jnp.float32
+    S, nc, H, Q, d = w.shape
+    dv = u.shape[-1]
+    hb = math.gcd(H, _CARRY_HEADS)
+    backward = grads is not None
+
+    def at(s, h, i):
+        return (s, nc - 1 - i if backward else i, h, 0, 0)
+
+    def rows(width):
+        return pl.BlockSpec((None, None, hb, Q, width), at)
+    states = pl.BlockSpec((None, None, hb, d, dv), at)
+    # (S, nc, H / hb, d, hb): a head's decays a column of its block
+    cols = pl.BlockSpec((None, None, None, d, hb), at)
+    ins = [w, u, k, decay.reshape(S, nc, H // hb, hb, d).swapaxes(-1, -2)]
+    specs = [rows(d), rows(dv), rows(d), cols]
+    if backward:
+        ins += list(grads)
+        specs += [states, rows(dv), states]
+        out_shape = [jax.ShapeDtypeStruct(t.shape, t.dtype)
+                     for t in (w, u, k)] \
+            + [jax.ShapeDtypeStruct((S, nc, H // hb, d, hb), f32)]
+        out_specs = [rows(d), rows(dv), rows(d), cols]
+    else:
+        out_shape = jax.ShapeDtypeStruct((S, nc, H, d, dv), f32)
+        out_specs = states
+    out = pl.pallas_call(
+        functools.partial(_carry_backward_kernel if backward
+                          else _carry_forward_kernel, hb),
+        out_shape=out_shape, grid=(S, H // hb, nc), in_specs=specs,
+        out_specs=out_specs,
+        scratch_shapes=[pltpu.VMEM((hb, d, dv), f32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=32 * 1024 * 1024),
+        interpret=interpret)(*ins)
+    if not backward:
+        return out
+    gw, gu, gk, gdecay = out
+    return gw, gu, gk, gdecay.swapaxes(-1, -2).reshape(decay.shape)
+
+
+def _carry_kernel_fits(w, u):
+    """Whether the carry goes through its kernels: on a TPU (or
+    under the interpreter, for the tests), a head of whole registers'
+    lanes for keys and values alike, and a chunk of 16 to 128 rows in
+    whole tiles of bfloat16 (16 rows)."""
+    import jax
+    Q, d = w.shape[-2:]
+    return ((jax.default_backend() == "tpu" or _INTERPRET)
+            and d % _LANES == 0 and u.shape[-1] % _LANES == 0
+            and Q % 16 == 0 and 16 <= Q <= 128)
+
+
+@functools.lru_cache(maxsize=None)
+def _delta_carrier(kernel, interpret=False):
+    """``(w, u, k_end, decay) -> (s_in, delta)`` under ``jax.custom_vjp``:
+    the states by ``_carry_call`` where ``kernel``, else by
+    ``_delta_steps``, and ``delta = u - w bf16(s_in)`` from them for
+    all chunks at once.  It keeps its inputs and s_in, which it names
+    for the remat policies where it makes it: a segment's backward pass
+    makes only the batched delta again, and runs no carry.
+
+    The backward pass, given the cotangents of delta and of s_in, is
+    the recurrence run against the chunks' order with ``w' = -k``,
+    ``u' = g_delta``, ``k' = -w`` and ``extra = g_s``: the state it
+    hands back at chunk c is ``H``, the cotangent of the state after
+    the chunk, and its x is ``g_u = k H + g_delta``; then::
+
+        g_w     = -bf16(g_u) bf16(s_in)^T
+        g_k     = bf16(delta) bf16(H)^T
+        g_decay = sum over the values' axis of s_in * H
+
+    for all chunks at once on the scan, a chunk at a time inside the
+    reversed kernel.  One object a carrier, its two passes under
+    ``jax.jit(inline=True)`` (traced once a shape); the backward
+    function enters the carry's scope again."""
+    import jax
+    import jax.numpy as jnp
+    from jax.ad_checkpoint import checkpoint_name
+    f32 = jnp.float32
+
+    def delta_of(w, u, s_in):
+        return u - jnp.einsum("bnhqd,bnhde->bnhqe", w, s_in.astype(w.dtype),
+                              preferred_element_type=f32)
+
+    @functools.partial(jax.jit, inline=True)
+    def made(w, u, k, decay):
+        if kernel:
+            return _carry_call(w, u, k, decay, interpret=interpret)
+        return _delta_steps(w, u, k, decay, want_x=False)[0]
+
+    @functools.partial(jax.jit, inline=True)
+    def given(w, u, k, decay, s_in, g_delta, g_s):
+        if kernel:
+            return _carry_call(w, u, k, decay, (s_in, g_delta, g_s),
+                               interpret=interpret)
+        h, gu = _delta_steps(k, g_delta, w, decay, g_s, reverse=True,
+                             sign=-1)
+        cdt = w.dtype
+        gw = -jnp.einsum("bnhqe,bnhde->bnhqd", gu.astype(cdt),
+                         s_in.astype(cdt), preferred_element_type=f32)
+        gk = jnp.einsum("bnhqe,bnhde->bnhqd", delta_of(w, u, s_in).astype(
+            cdt), h.astype(cdt), preferred_element_type=f32)
+        return (gw.astype(w.dtype), gu, gk.astype(k.dtype),
+                jnp.sum(s_in * h, axis=-1))
+
+    @jax.custom_vjp
+    def carry(w, u, k, decay):
+        s_in = made(w, u, k, decay)
+        return s_in, delta_of(w, u, s_in)
+
+    def forward(w, u, k, decay):
+        s_in = checkpoint_name(made(w, u, k, decay), SCAN)
+        return (s_in, delta_of(w, u, s_in)), (w, u, k, decay, s_in)
+
+    def backward(res, cts):
+        g_s, g_delta = cts
+        with jax.named_scope("mx.kda.carry"):
+            return given(*res, g_delta, g_s)
+
+    carry.defvjp(forward, backward)
+    return carry
+
+
 def _delta_carry(w, u, k_end, decay):
     """The state at each chunk's start and each chunk's corrected
     values, from the chunks' solved W (S, chunks, H, Q, d), U
@@ -827,26 +1091,13 @@ def _delta_carry(w, u, k_end, decay):
         delta[c] = U[c] - W[c] S[c]
         S[c + 1] = Diag(decay[c]) S[c] + k_end[c]^T delta[c]     S[0] = 0
 
-    two matrix products a step.  The state is float32; the products
-    take W's type's operands and sum in float32."""
-    import jax
-    import jax.numpy as jnp
-    f32 = jnp.float32
-    cdt = w.dtype
-
-    def step(state, chunk_c):
-        w_c, u_c, k_c, decay_c = chunk_c
-        delta = u_c - jnp.einsum("bhqd,bhde->bhqe", w_c, state.astype(cdt),
-                                 preferred_element_type=f32)
-        new = decay_c[..., None] * state + jnp.einsum(
-            "bhqd,bhqe->bhde", k_c, delta.astype(cdt),
-            preferred_element_type=f32)
-        return new, (state, delta)
-    S, _nc, H, _Q, d = w.shape
-    start = jnp.zeros((S, H, d, u.shape[-1]), f32)
-    _, (s_in, delta) = jax.lax.scan(
-        step, start, tuple(t.swapaxes(0, 1) for t in (w, u, k_end, decay)))
-    return s_in.swapaxes(0, 1), delta.swapaxes(0, 1)
+    two matrix products a step: by ``_carry_forward_kernel``, the state
+    in fast memory, where ``_carry_kernel_fits``, else by ``lax.scan``;
+    delta from the states afterwards, for all chunks at once.  The state
+    is float32; the products take W's type's operands and sum in
+    float32."""
+    return _delta_carrier(_carry_kernel_fits(w, u), _INTERPRET)(
+        w, u, k_end, decay)
 
 
 def gated_delta_chunked(q, k, v, g, beta, chunk):
@@ -857,7 +1108,9 @@ def gated_delta_chunked(q, k, v, g, beta, chunk):
     decay, never positive; beta (S, T, H) float32.  T is a whole number
     of chunks.  Returns (o (S, T, H, dv) float32; the states at the
     chunks' starts (S, chunks, H, d, dv), float32; the chunks whose Akk
-    and Aqk the kernels made: all where ``_kernel_fits``, else 0).
+    and Aqk the kernels made: all where ``_kernel_fits``, else 0; the
+    chunks the carry's kernel went through: all where
+    ``_carry_kernel_fits``, else 0).
 
     With ``G_r`` the running sum of g inside a chunk and ``S_0`` the
     state at its start::
@@ -904,6 +1157,7 @@ def gated_delta_chunked(q, k, v, g, beta, chunk):
     with jax.named_scope("mx.kda.carry"):
         # the chunk-boundary states: what the backward pass of the
         # carry and of the products below reads
+        carried = _carry_kernel_fits(w, u)
         s_in, delta = _delta_carry(
             w, u, (ks * jnp.exp(last - G)).astype(cdt),
             jnp.exp(last[..., 0, :]))
@@ -914,7 +1168,7 @@ def gated_delta_chunked(q, k, v, g, beta, chunk):
             + jnp.einsum("bnhri,bnhie->bnhre", aqk.astype(cdt),
                          delta.astype(cdt), preferred_element_type=f32)
     return (o.transpose(0, 1, 3, 2, 4).reshape(S, T, H, -1), s_in,
-            S * nc if kernels else 0)
+            S * nc if kernels else 0, S * nc if carried else 0)
 
 
 def _delta_infer(attrs, in_shapes, aux):
@@ -934,7 +1188,8 @@ def _delta_infer(attrs, in_shapes, aux):
                       "seq_len": int},
           required_attrs=("heads", "head_dim", "seq_len"),
           infer_shape=_delta_infer,
-          counters=("kda.chunks", "kda.carried_bytes", "kda.kernel_chunks"))
+          counters=("kda.chunks", "kda.carried_bytes", "kda.kernel_chunks",
+                    "kda.carry_kernel_chunks"))
 def _gated_delta_rule(attrs, ins, octx):
     """The gated delta rule (Kimi Delta Attention) over rows cut into
     sequences of ``seq_len``.  query, key, value and gate
@@ -953,10 +1208,20 @@ def _gated_delta_rule(attrs, ins, octx):
     system by halving it down to blocks of at most 16 rows, solved by
     substitution and merged by products, then multiplies; its backward
     pass takes two products with that inverse and inverts nothing.
-    Counts ``kda.chunks``,
-    ``kda.carried_bytes`` (the chunk-boundary states the carry passes)
-    and ``kda.kernel_chunks`` (the chunks whose decayed inner products
-    the kernels made: all of them or none)."""
+    On a TPU the carry is a Pallas kernel whose grid walks the chunks
+    with a block of heads' state held in fast memory, and elsewhere (or
+    at a head that is not whole lanes wide, or a chunk that is not 16
+    to 128 rows in tiles of 16) a ``lax.scan`` of the same arithmetic.
+    Its backward pass is the same recurrence run against the chunks'
+    order (a second kernel on a TPU), which hands back the state's
+    cotangent chunk by chunk and the chunk's gradients with it; it
+    reads the boundary states the forward pass kept, so no pass makes
+    the carry again.  Counts ``kda.chunks``,
+    ``kda.carried_bytes`` (the chunk-boundary states the carry passes),
+    ``kda.kernel_chunks`` (the chunks whose decayed inner products
+    the kernels made: all of them or none) and
+    ``kda.carry_kernel_chunks`` (the chunks the carry's kernel went
+    through: all of them or none)."""
     import jax
     import jax.numpy as jnp
     f32 = jnp.float32
@@ -982,9 +1247,11 @@ def _gated_delta_rule(attrs, ins, octx):
         g = -jnp.exp(A_log.astype(f32))[:, None] * heads(
             jax.nn.softplus(gate.astype(f32) + dt_bias.astype(f32)))
         b = jax.nn.sigmoid(beta.astype(f32)).reshape(S, T, H)
-    o, s_in, by_kernel = gated_delta_chunked(qn, kn, heads(v), g, b, Q)
+    o, s_in, by_kernel, carried = gated_delta_chunked(
+        qn, kn, heads(v), g, b, Q)
     count_op("kda.chunks", S * (T // Q))
     count_op("kda.kernel_chunks", by_kernel)
+    count_op("kda.carry_kernel_chunks", carried)
     count_op("kda.carried_bytes", s_in.size * s_in.dtype.itemsize)
     # dear to make again: a segment's backward pass is handed it
     return [keep(o.reshape(v.shape).astype(v.dtype), SCAN)]

@@ -89,3 +89,33 @@ def test_the_delta_rules_solve_compiles_to_no_block_inversion(
     for f, calls in ((xla, True), (ssm._unit_lower_solve, False)):
         text = jax.jit(both(f)).lower(*operands).compile().as_text()
         assert ("InvertDiagBlocksLowerTriangular" in text) == calls
+
+
+@pytest.mark.parametrize("chunk", [64, 16, 128])
+def test_the_delta_rules_carry_compiles_to_one_kernel_a_pass(
+        one_chip, no_compile_cache, chunk):
+    """The carry of one layer of the Kimi cell (8,192 tokens as chunks
+    of 64, 32 heads of 128, bfloat16 operands), and the smallest and
+    the largest chunk the kernel takes: the states and delta, and their
+    gradient, one kernel forward and one backward, and no loop of
+    XLA's (the scan's carrier compiles to a `while` a pass)."""
+    nc, heads, dim = 8192 // chunk, 32, 128
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+    w = k = shape(1, nc, heads, chunk, dim)
+    u = shape(1, nc, heads, chunk, dim, dtype=jnp.float32)
+    decay = shape(1, nc, heads, dim, dtype=jnp.float32)
+    g_s = shape(1, nc, heads, dim, dim, dtype=jnp.float32)
+    for kernel, calls, loops in ((True, 1, 0), (False, 0, 1)):
+        carry = ssm._delta_carrier(kernel)
+        text = jax.jit(carry).lower(w, u, k, decay).compile().as_text()
+        assert (text.count("tpu_custom_call"), text.count(" while(")) \
+            == (calls, loops)
+
+        def gradient(w, u, k, decay, g_s, g_delta):
+            return jax.vjp(carry, w, u, k, decay)[1]((g_s, g_delta))
+        text = jax.jit(gradient).lower(w, u, k, decay, g_s, u).compile(
+        ).as_text()
+        assert (text.count("tpu_custom_call"), text.count(" while(")) \
+            == (2 * calls, 2 * loops)

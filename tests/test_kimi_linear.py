@@ -439,6 +439,144 @@ def test_the_symbol_on_the_kernel_path_is_the_recurrence(monkeypatch):
         == float(counted["kda.chunks"]) == S * T // 64
 
 
+
+# -------------------------------------------- the delta rule's carry
+def _scan_carry(w, u, k_end, decay):
+    """The carry as a plain `lax.scan` that makes delta in its loop
+    (the form before the carry had a backward pass of its own), for
+    jax's gradient of it: the oracle of both carriers."""
+    f32, cdt = jnp.float32, w.dtype
+
+    def step(state, chunk_c):
+        w_c, u_c, k_c, decay_c = chunk_c
+        delta = u_c - jnp.einsum("bhqd,bhde->bhqe", w_c, state.astype(cdt),
+                                 preferred_element_type=f32)
+        new = decay_c[..., None] * state + jnp.einsum(
+            "bhqd,bhqe->bhde", k_c, delta.astype(cdt),
+            preferred_element_type=f32)
+        return new, (state, delta)
+    S, _nc, H, _Q, d = w.shape
+    _, (s_in, delta) = jax.lax.scan(
+        step, jnp.zeros((S, H, d, u.shape[-1]), jnp.float32),
+        tuple(t.swapaxes(0, 1) for t in (w, u, k_end, decay)))
+    return s_in.swapaxes(0, 1), delta.swapaxes(0, 1)
+
+
+def _carry_operands(chunk, dtype, seed, S=1, chunks=3, heads=2, dim=128):
+    """w, u, k_end, decay of `chunks` chunks as the delta rule hands
+    them to its carry, and cotangents for s_in and delta."""
+    rs = np.random.RandomState(seed)
+    rows = (S, chunks, heads, chunk, dim)
+    w, k = (jnp.asarray(rs.randn(*rows) / math.sqrt(dim), dtype)
+            for _ in range(2))
+    u = jnp.asarray(rs.randn(*rows), jnp.float32)
+    decay = jnp.asarray(rs.uniform(0.3, 1.0, rows[:3] + (dim,)),
+                        jnp.float32)
+    g_s = jnp.asarray(rs.randn(*rows[:3], dim, dim), jnp.float32)
+    g_delta = jnp.asarray(rs.randn(*rows), jnp.float32)
+    return (w, u, k, decay), (g_s, g_delta)
+
+
+@pytest.mark.parametrize("chunk", [64, 16])
+@pytest.mark.parametrize("cts", ["forward", "both cotangents",
+                                 "none for the states", "none for delta"])
+def test_the_carry_kernel_under_the_interpreter_is_the_scan(chunk, cts):
+    """The carry by its Pallas kernel under the interpreter against the
+    scan that carries it elsewhere: forward (s_in and delta), and
+    backward, the recurrence run reversed with the cotangent of s_in as
+    its extra term and that of delta as its u, each of them zero or
+    not.  bfloat16 operands as in the cell: float32 results within
+    1e-5 of their largest entry, bfloat16 ones within a rounding
+    (2e-3); and in float32 both carriers' gradients with respect to w,
+    u, k and decay are jax's gradient of the plain scan within 1e-5."""
+    kernel = ssm._delta_carrier(True, True)
+    scan = ssm._delta_carrier(False)
+
+    def close(got, want, err_msg=""):
+        got, want = (np.asarray(t.astype(jnp.float32)) for t in (got, want))
+        tol = 2e-3 if got.dtype != want.dtype else 1e-5
+        np.testing.assert_allclose(got, want, rtol=0, err_msg=err_msg,
+                                   atol=tol * float(np.abs(want).max()))
+    for dtype in (jnp.bfloat16, jnp.float32):
+        args, (g_s, g_delta) = _carry_operands(chunk, dtype, seed=chunk)
+        if cts == "none for the states":
+            g_s = jnp.zeros_like(g_s)
+        if cts == "none for delta":
+            g_delta = jnp.zeros_like(g_delta)
+        if cts == "forward":
+            for got, want, oracle, name in zip(
+                    kernel(*args), scan(*args), _scan_carry(*args),
+                    ("s_in", "delta")):
+                close(got, want, name)
+                close(got, oracle, name)
+            continue
+        got = jax.vjp(kernel, *args)[1]((g_s, g_delta))
+        want = jax.vjp(scan, *args)[1]((g_s, g_delta))
+        for name, a, b in zip(("w", "u", "k", "decay"), got, want):
+            assert a.dtype == b.dtype, name
+            tol = 2e-3 if a.dtype == jnp.bfloat16 else 1e-5
+            np.testing.assert_allclose(
+                np.asarray(a.astype(jnp.float32)),
+                np.asarray(b.astype(jnp.float32)), rtol=0, err_msg=name,
+                atol=tol * float(jnp.abs(b.astype(jnp.float32)).max()))
+        if dtype == jnp.float32:
+            oracle = jax.vjp(_scan_carry, *args)[1]((g_s, g_delta))
+            for name, a, b in zip(("w", "u", "k", "decay"), got, oracle):
+                np.testing.assert_allclose(
+                    a, b, rtol=0, err_msg=name,
+                    atol=1e-5 * float(jnp.abs(b).max()))
+
+
+@pytest.mark.parametrize("chunk,dim,fits", [
+    (64, 128, True), (16, 128, True), (128, 128, True), (32, 256, True),
+    (20, 128, False), (8, 128, False), (256, 128, False), (64, 12, False),
+    (16, 8, False)])
+def test_shapes_the_carry_kernel_takes_and_turns_away(monkeypatch, chunk,
+                                                       dim, fits):
+    """The carry's kernel takes heads of whole lanes and a chunk of 16
+    to 128 rows in whole tiles of 16, under the interpreter (on the
+    CPU with no interpreter, nothing); any operand type."""
+    (w, u, _, _), _ = _carry_operands(chunk, jnp.bfloat16, 0, chunks=1,
+                                      heads=1, dim=dim)
+    assert not ssm._carry_kernel_fits(w, u)
+    monkeypatch.setattr(ssm, "_INTERPRET", True)
+    assert ssm._carry_kernel_fits(w, u) == fits
+    assert ssm._carry_kernel_fits(w.astype(jnp.float32), u) == fits
+
+
+@pytest.mark.parametrize("chunk,dim", [(20, 128), (16, 12), (64, 128)])
+def test_the_carry_counts_the_chunks_its_kernel_went_through(
+        monkeypatch, chunk, dim):
+    """A head that is not 128 wide or a chunk of 20 takes the scan, and
+    the op counts no chunk as the carry kernel's; a head of 128 and a
+    chunk of 64 count every chunk.  Either way the op is the recurrence
+    token by token."""
+    monkeypatch.setattr(ssm, "_INTERPRET", True)
+    fits = dim == 128 and chunk % 16 == 0
+    if not fits:
+        monkeypatch.setattr(ssm, "_carry_call", None)   # would raise
+    T = 2 * chunk
+    v = _delta_inputs(1, T, seed=6, H=1, D=dim)
+    with registry.counting() as counted:
+        out = _delta(v, T, chunk)
+    assert float(counted["kda.chunks"]) == 2
+    assert float(counted["kda.carry_kernel_chunks"]) == 2 * fits
+    np.testing.assert_allclose(out, _token_by_token(v, 1, T), rtol=2e-5,
+                               atol=2e-5)
+
+
+def test_a_remat_step_runs_the_carry_once_a_pass_and_never_again():
+    """The lowered training step of the tiny model under `remat="full"`
+    on the CPU, where the scan carries: the carry's loop stands twice a
+    delta-rule layer (forward, and backward reversed), not three times
+    as when jax differentiated the scan and a segment's backward pass
+    ran it again.  The step has no other loop."""
+    mod = _fit("full")
+    fn, skeleton = mod._exec_group._last_step
+    text = fn.lower(*skeleton).as_text()
+    assert text.count("stablehlo.while") == 2 * 3
+
+
 # ------------------------------------------------------------ attention
 def _plain_attention(q, k, v, causal):
     """softmax(q k^T / sqrt(D)) v, every score at once; q (B, H, T, D),

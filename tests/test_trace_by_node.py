@@ -115,6 +115,13 @@ def test_mamba_nodes_and_the_pass_from_the_path(op_name, group, which):
      "kda.solve", "backward"),
     (BACK + "rematted_computation/l3_kda/mx.kda.carry/while/body/add",
      "kda.carry", "made again"),
+    # the carry's kernel, forward and in its own backward pass, and the
+    # batched delta made again
+    (STEP + "jvp(l1_kda)/mx.kda.carry/pallas_call", "kda.carry", "forward"),
+    (BACK + "l1_kda/mx.kda.carry/mx.kda.carry/pallas_call", "kda.carry",
+     "backward"),
+    (BACK + "rematted_computation/l1_kda/mx.kda.carry/"
+     "bnhqd,bnhde->bnhqe/dot_general", "kda.carry", "made again"),
     (STEP + "jvp(l4_kda)/mx.kda.gate/jit(softplus)/log1p", "kda.gate",
      "forward"),
     (STEP + "jvp(l4_kda)/mx.kda.inter/bnhri,bnhie->bnhre/dot_general",
